@@ -66,6 +66,9 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--out", default="BENCH_spmv.json")
     args = ap.parse_args()
+    from repro.compat import use_compile_cache
+    use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
     sweep = run(quick=args.quick)
 

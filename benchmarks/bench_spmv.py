@@ -555,7 +555,7 @@ def bench_moe_dispatch(quick: bool) -> dict:
     import jax
     import jax.numpy as jnp
     import repro.api as nap_api
-    from repro.compat import make_mesh, set_mesh
+    from repro.compat import make_mesh
     from repro.configs import get_reduced
     from repro.core.hlo_analysis import analyze_hlo
     from repro.models.moe import EPInfo, moe_apply_sharded, moe_init
@@ -576,7 +576,7 @@ def bench_moe_dispatch(quick: bool) -> dict:
     iters = 3 if quick else 10
 
     walls, measured = {}, {}
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for mode, wd in (("flat", "f32"), ("nap", "f32"),
                          ("nap", "bf16"), ("nap", "fp8_e4m3")):
             cfg = cfg0.replace(moe_dispatch=mode, wire_dtype=wd)
@@ -653,6 +653,9 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--out", default="BENCH_spmv.json")
     args = ap.parse_args()
+    from repro.compat import use_compile_cache
+    use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
     t0 = time.time()
     result = {

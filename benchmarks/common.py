@@ -55,7 +55,8 @@ def measured_sweep(config: Dict) -> Dict:
     the XLA host device count for the ladder's largest shape before jax
     initialises, and the figure driver's jax is already up on one
     device.  Any inherited forced count is dropped so the child sizes
-    its own platform.
+    its own platform.  CPU-only: a parent whose backend is up holds a
+    chip, so this must not run on a TPU machine.
     """
     import json
     import os

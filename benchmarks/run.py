@@ -2,6 +2,11 @@
 
     PYTHONPATH=src python -m benchmarks.run [--quick]
 
+CPU-only until the chip benchmark exists: every wall here comes from
+XLA's CPU backend and the Pallas interpreter on forced host devices, and
+the parent starts its JAX children with no backend of its own.  On a
+machine with a TPU, use ``python chip_smoke.py`` (one process per chip).
+
 The run fails (non-zero exit) if the freshly measured BENCH_spmv.json
 regresses plan-compile or local-compute wall time by more than
 ``REGRESSION_FACTOR`` versus the committed baseline — keep it green

@@ -28,7 +28,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.compat import make_mesh, set_mesh
+from repro.compat import make_mesh
 from repro.configs import get_reduced
 from repro.core.hlo_analysis import analyze_hlo
 from repro.models.moe import EPInfo, moe_apply_local, moe_apply_sharded, moe_init
@@ -50,7 +50,7 @@ def main() -> None:
 
     def run(mcfg):
         fn = jax.jit(lambda p, xx: moe_apply_sharded(p, mcfg, xx, ep, mesh))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = fn.lower(params, x).compile()
             got = np.asarray(fn(params, x))
         # pod_boundary=4: devices 0-3 are pod 0, 4-7 pod 1 on the (2,4) mesh

@@ -12,7 +12,7 @@ communication win.  The whole flow is five lines:
     z  = op.T @ v      # transpose SpMV, same compiled plan reversed
     op.stats(); op.cost(BLUE_WATERS); op.autotune_report()
 
-    PYTHONPATH=src python examples/quickstart.py
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/quickstart.py
 """
 import os
 
@@ -65,13 +65,17 @@ def main() -> None:
           f"({ts / tn:.2f}x)")
 
     # -- the same plan compiled to shard_map SPMD ------------------------------
-    if jax.device_count() >= topo.n_procs:
-        op = nap.operator(a, topo=topo, method="nap", backend="shardmap")
-        np.testing.assert_allclose(op @ v, want, rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(op.T @ v, want_t, rtol=1e-4, atol=1e-5)
-        print(f"\nautotuned local compute: {op.local_compute} "
-              f"(see op.autotune_report())")
-        print("SPMD shard_map NAPSpMV matches on a 16-device host mesh")
+    if jax.device_count() < topo.n_procs:
+        raise SystemExit(
+            f"the shard_map phase needs {topo.n_procs} devices, found "
+            f"{jax.device_count()} ({jax.default_backend()}); run on the CPU "
+            f"with JAX_PLATFORMS=cpu so the forced 16-device host mesh applies")
+    op = nap.operator(a, topo=topo, method="nap", backend="shardmap")
+    np.testing.assert_allclose(op @ v, want, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(op.T @ v, want_t, rtol=1e-4, atol=1e-5)
+    print(f"\nautotuned local compute: {op.local_compute} "
+          f"(see op.autotune_report())")
+    print("SPMD shard_map NAPSpMV matches on a 16-device host mesh")
 
 
 if __name__ == "__main__":

@@ -72,7 +72,7 @@ def operator(a, topo: Optional[Topology] = None,
              local_compute: str = "auto", mesh=None,
              pairing: str = "aligned",
              block_shape: Tuple[int, int] = (8, 128), nv_block: int = 128,
-             interpret: bool = True, cache: bool = True,
+             cache: bool = True,
              tuner: LocalComputeParams = TPU_V5E_LOCAL,
              integrity: str = "off",
              wire_dtype: str = "f32") -> "NapOperator":
@@ -122,7 +122,10 @@ def operator(a, topo: Optional[Topology] = None,
     threshold : duplication threshold for the multistep strategy
         (``"auto"`` or an int >= 1; ``d < threshold`` columns go direct).
     local_compute : shardmap local kernel — ``"auto"`` | ``"bsr"`` |
-        ``"ell"`` | ``"coo"`` (see kernels/README.md).  The transpose
+        ``"ell"`` | ``"coo"`` (see kernels/README.md).  The Pallas kernel
+        behind ``"bsr"`` compiles on a TPU and runs in interpret mode
+        only when jax's default backend is the CPU
+        (:func:`repro.compat.interpret_mode`).  The transpose
         direction autotunes independently over ell/coo (no transposed
         Pallas BSR kernel yet); see ``op.autotune_report()``.
     mesh : optional pre-built jax mesh with axes ("node", "proc");
@@ -212,7 +215,7 @@ def operator(a, topo: Optional[Topology] = None,
     spec = OperatorSpec(method=method, backend=backend,
                         local_compute=local_compute, pairing=pairing,
                         block_shape=tuple(block_shape), nv_block=nv_block,
-                        interpret=interpret, cache=cache, tuner=tuner,
+                        cache=cache, tuner=tuner,
                         integrity=integrity, threshold=threshold,
                         wire_dtype=wire_dtype)
     exec_ = bind_executor(backend, method, a, row_part, col_part, topo, spec,
@@ -593,8 +596,7 @@ class ComposedOperator:
                         backend=spec.backend,
                         local_compute=spec.local_compute, mesh=mesh,
                         pairing=spec.pairing, block_shape=spec.block_shape,
-                        nv_block=spec.nv_block, interpret=spec.interpret,
-                        cache=spec.cache, tuner=spec.tuner,
+                        nv_block=spec.nv_block, cache=spec.cache, tuner=spec.tuner,
                         integrity=spec.integrity, threshold=spec.threshold,
                         wire_dtype=spec.wire_dtype)
 

@@ -1,83 +1,52 @@
-"""Version-compatibility shims over the installed jax (pinned 0.4.x here).
+"""Platform policies for the installed jax (0.9).
 
-The repo targets the current jax API surface (``jax.shard_map``,
-``jax.sharding.AxisType``, ``pltpu.CompilerParams``, dict-valued
-``Compiled.cost_analysis``); the container pins jax 0.4.37 where those
-spell differently.  Every call site routes through this module so the
-difference lives in exactly one place.
+Call sites use the jax 0.9 API directly (``jax.shard_map``,
+``jax.lax.axis_size``, ``jax.set_mesh``, ``pltpu.CompilerParams``,
+dict-valued ``Compiled.cost_analysis()``).  Three policies stay here:
+
+* :func:`make_mesh` — ``jax.make_mesh`` defaults to Explicit axes; every
+  program in this repo runs manual shard_map collectives over Auto axes.
+* :func:`interpret_mode` — Pallas kernels run in interpret mode only when
+  the default backend is the CPU; on a TPU they always compile.
+* :func:`use_compile_cache` — where entry points keep jax's persistent
+  compilation cache.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Sequence
+import os
+from typing import Optional, Sequence
 
 import jax
 
-# -- shard_map: jax.shard_map (>=0.5) vs jax.experimental.shard_map ----------
 
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=None):
-    """``jax.shard_map`` with the new kwarg spelling, on either API.
+def make_mesh(axis_shapes: Sequence[int],
+              axis_names: Sequence[str]) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
-    ``axis_names`` (manual axes) maps to the old ``auto`` complement;
-    ``check_vma`` maps to the old ``check_rep``.
+
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """Resolve a kernel's ``interpret`` argument: an explicit bool wins
+    (compile rehearsals pass ``False`` on a CPU host); ``None`` follows
+    the platform — interpret only on the CPU backend."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() == "cpu"
+
+
+def use_compile_cache(root: str) -> str:
+    """Keep jax's persistent compilation cache under ``<root>/.jax_cache``.
+
+    ``root`` is the checkout, derived by the caller from its own file
+    location, so every run of that checkout hits the same directory.
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and
+    nothing is set here.  Returns the directory in use.
     """
-    if hasattr(jax, "shard_map"):
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    kwargs = {}
-    if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-# -- Pallas TPU compiler params: CompilerParams vs TPUCompilerParams ---------
-from jax.experimental.pallas import tpu as _pltpu
-
-if hasattr(_pltpu, "CompilerParams"):
-    tpu_compiler_params = _pltpu.CompilerParams
-else:
-    tpu_compiler_params = _pltpu.TPUCompilerParams
-
-
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
-              explicit: bool = False) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with ``axis_types`` only where the API has it."""
-    if hasattr(jax.sharding, "AxisType"):
-        kind = (jax.sharding.AxisType.Explicit if explicit
-                else jax.sharding.AxisType.Auto)
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=(kind,) * len(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-
-
-def axis_size(name) -> int:
-    """``lax.axis_size`` (new) or the classic ``psum(1, name)`` spelling."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
-
-
-def set_mesh(mesh: jax.sharding.Mesh):
-    """``jax.set_mesh`` context where available, else the Mesh context."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def cost_analysis(compiled) -> Dict[str, Any]:
-    """``Compiled.cost_analysis()`` normalised to a flat dict.
-
-    jax 0.4.x returns a one-element list of dicts (per partition); newer
-    versions return the dict directly, and some backends return None.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.abspath(root), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -305,8 +305,10 @@ class LocalComputeParams:
     """Effective unit rates for the local-compute roofline (f32, TPU-ish).
 
     Absolute values matter less than ratios: MXU >> VPU >> scatter, and
-    everything can be HBM-bound.  ``vmem_x_budget`` bounds the packed x
-    operand the ELL kernel holds resident per nv tile.
+    everything can be HBM-bound.  ``ell_hbm_budget`` bounds the device
+    bytes the ELL product may hold (:func:`ell_resident_bytes`): half of
+    a v5e chip's 16 GiB, leaving the rest to the exchange buffers, the
+    operand and the other direction's format arrays.
     """
 
     name: str = "tpu_v5e_local"
@@ -314,7 +316,7 @@ class LocalComputeParams:
     vpu_flops: float = 2.0e12     # vectorised gather+FMA rate
     scatter_flops: float = 4.0e9  # segment_sum / scalar scatter-add rate
     hbm_bw: float = 8.1e11        # HBM bandwidth
-    vmem_x_budget: int = 8 * 2**20  # max packed-x bytes per ELL nv tile
+    ell_hbm_budget: int = 8 * 2**30  # max device bytes of one ELL product
 
     def signature(self) -> tuple:
         return dataclasses.astuple(self)
@@ -323,6 +325,34 @@ class LocalComputeParams:
 TPU_V5E_LOCAL = LocalComputeParams()
 
 LOCAL_FORMATS = ("bsr", "ell", "coo")
+
+
+def _tiled_bytes(n: int, width: int) -> int:
+    """Device bytes of a 4-byte ``[n, width]`` working array on a TPU.
+
+    The compiler lays the long axis on the 128 lanes and the short one on
+    the sublanes: a width of 1 packs densely (tile (1, 128)), any other
+    width pads to the 8-row sublane tile.
+    """
+    sub = 1 if width == 1 else -(-width // 8) * 8
+    return 4 * sub * (-(-n // 128) * 128)
+
+
+def ell_resident_bytes(rows: int, kmax: int, n_x: int, nv: int) -> int:
+    """Device bytes the ELL product holds inside the shard_map program,
+    tile padding included.
+
+    cols + vals ``[rows, kmax]`` twice: as staged (tile (1, 128), kmax
+    unpadded) and as the slot loop's sublane-padded working copy, which
+    the compiler makes by relayout; the x segments and their packed
+    concat (``[n_x, nv]`` each); the ``[rows, nv]`` result.  The slot loop
+    keeps no gather temporary (see ``kernels/ell_spmv/kernel.py``).
+    ``tests/test_tpu_compile.py`` checks this against the compiler's own
+    memory analysis at 2^20 rows.
+    """
+    staged = 4 * kmax * (-(-rows // 128) * 128)
+    return (2 * staged + 2 * _tiled_bytes(rows, kmax)
+            + 2 * _tiled_bytes(n_x, nv) + _tiled_bytes(rows, nv))
 
 
 def local_format_times(stats: Dict[str, float],
@@ -352,9 +382,8 @@ def local_format_times(stats: Dict[str, float],
     kmax = stats["ell_kmax"]
     ell_flops = 2.0 * rows * kmax * nv
     ell_bytes = rows * kmax * 8 + n_x * 4 * nv + out_b
-    ell_x_resident = n_x * 4 * min(nv, 128)
-    if ell_x_resident > params.vmem_x_budget:
-        times["ell"] = float("inf")  # packed x cannot stay VMEM-resident
+    if ell_resident_bytes(rows, kmax, n_x, nv) > params.ell_hbm_budget:
+        times["ell"] = float("inf")  # the product does not fit the device
     else:
         times["ell"] = max(ell_flops / params.vpu_flops,
                            ell_bytes / params.hbm_bw)

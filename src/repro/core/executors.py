@@ -81,7 +81,6 @@ class OperatorSpec:
     pairing: str = "aligned"
     block_shape: Tuple[int, int] = (8, 128)
     nv_block: int = 128
-    interpret: bool = True
     cache: bool = True
     tuner: LocalComputeParams = TPU_V5E_LOCAL
     integrity: str = "off"          # "off" | "detect" | "recover"
@@ -269,7 +268,7 @@ class _ShardmapExecutor:
         compiled plan rebuilds its value arrays in place and every
         already-built direction program picks them up on the next call
         WITHOUT retracing — value arrays are per-call jit arguments
-        (see :data:`repro.core.spmv_jax.VALUE_ARRAY_NAMES`)."""
+        (see :func:`repro.core.spmv_jax._plan_arg_fetch`)."""
         self.compiled.swap_values(a_new)
         self.a = a_new
 
@@ -313,7 +312,7 @@ class NapShardmapExecutor(_ShardmapExecutor):
         from repro.core.spmv_jax import (nap_forward_shardmap,
                                          nap_transpose_shardmap)
         kw = dict(local_compute=self.spec.local_compute,
-                  nv_block=self.spec.nv_block, interpret=self.spec.interpret)
+                  nv_block=self.spec.nv_block)
         if self._integrity is not None:
             kw.update(integrity=True, fault_fetch=self._integrity.fetch_spec)
         if direction == "forward":
@@ -355,7 +354,7 @@ class MultistepShardmapExecutor(_ShardmapExecutor):
         from repro.core.spmv_jax import (nap_forward_shardmap,
                                          nap_transpose_shardmap)
         kw = dict(local_compute=self.spec.local_compute,
-                  nv_block=self.spec.nv_block, interpret=self.spec.interpret)
+                  nv_block=self.spec.nv_block)
         if self._integrity is not None:
             kw.update(integrity=True, fault_fetch=self._integrity.fetch_spec)
         if direction == "forward":
@@ -391,7 +390,7 @@ class StandardShardmapExecutor(_ShardmapExecutor):
         from repro.core.spmv_jax import (standard_forward_shardmap,
                                          standard_transpose_shardmap)
         kw = dict(local_compute=self.spec.local_compute,
-                  nv_block=self.spec.nv_block, interpret=self.spec.interpret)
+                  nv_block=self.spec.nv_block)
         if self._integrity is not None:
             kw.update(integrity=True, fault_fetch=self._integrity.fetch_spec)
         if direction == "forward":

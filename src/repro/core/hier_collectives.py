@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 
 Pytree = Any
 
@@ -84,7 +83,7 @@ def nap_psum(x: jnp.ndarray, inner_axis: str, outer_axis: str) -> jnp.ndarray:
     on the scattered shard), all-gather over ``inner_axis`` (ICI).
     Equivalent to ``lax.psum(x, (inner_axis, outer_axis))``.
     """
-    inner = compat.axis_size(inner_axis)
+    inner = lax.axis_size(inner_axis)
     orig_shape = x.shape
     flat = _pad_to_multiple(x.reshape(-1), inner)
     shard = lax.psum_scatter(flat, inner_axis, scatter_dimension=0, tiled=True)
@@ -143,8 +142,8 @@ def nap_all_to_all(x: jnp.ndarray, inner_axis: str, outer_axis: str) -> jnp.ndar
     delivering to final destinations.  Bitwise-equal to the flat all-to-all
     over ``(outer, inner)``.
     """
-    n_in = compat.axis_size(inner_axis)
-    n_out = compat.axis_size(outer_axis)
+    n_in = lax.axis_size(inner_axis)
+    n_out = lax.axis_size(outer_axis)
     rest = x.shape[1:]
     # [n_out*n_in, ...] -> [n_out, n_in, ...]: row o = payload for pod o.
     y = x.reshape((n_out, n_in) + rest)
@@ -190,7 +189,7 @@ def compressed_psum_outer(x: jnp.ndarray, outer_axis: str,
 
     Returns (sum, new_residual).
     """
-    n = compat.axis_size(outer_axis)
+    n = lax.axis_size(outer_axis)
     if residual is None:
         residual = jnp.zeros_like(x)
     xc = x + residual
@@ -250,7 +249,7 @@ def nap_psum_compressed(x: jnp.ndarray, inner_axis: str, outer_axis: str,
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Hierarchical all-reduce with int8 DCI stage: RS(ICI, fp32) ->
     compressed psum(DCI, int8+EF) -> AG(ICI, fp32)."""
-    inner = compat.axis_size(inner_axis)
+    inner = lax.axis_size(inner_axis)
     orig = x.shape
     flat = _pad_to_multiple(x.reshape(-1), inner)
     shard = lax.psum_scatter(flat, inner_axis, scatter_dimension=0, tiled=True)
@@ -298,8 +297,8 @@ def nap_moe_dispatch(tokens: jnp.ndarray, dest_chip: jnp.ndarray,
     where the receive buffer is ordered by source chip.  This primitive is
     exercised by the MoE layer; see models/moe.py for the full layer.
     """
-    n_in = compat.axis_size(inner_axis)
-    n_out = compat.axis_size(outer_axis)
+    n_in = lax.axis_size(inner_axis)
+    n_out = lax.axis_size(outer_axis)
     T, D = tokens.shape
     K = dest_chip.shape[1]
     my_pod = lax.axis_index(outer_axis)

@@ -59,18 +59,19 @@ Local compute (``local_compute=``) — the **adaptive engine**:
   support.  Slots are ordered on-process → on-node → off-node, so the
   Pallas pipeline streams the blocks that depend on inter-node data
   last — the paper's Isend/compute overlap, expressed as pipeline stages.
-* ``"ell"`` — the **Pallas ELL path** (:mod:`repro.kernels.ell_spmv`) for
-  low-density / block-hostile ranks where padded BSR tiles densify:
-  kmax-padded rows, vectorised in-kernel row gather, same slot ordering.
+* ``"ell"`` — the **ELL path** (:mod:`repro.kernels.ell_spmv`, plain XLA)
+  for low-density / block-hostile ranks where padded BSR tiles densify:
+  kmax-padded rows, one vectorised row gather per slot.
 * ``"coo"`` — scalar ``segment_sum`` gathers (the pre-fusion reference
   path, kept as an in-graph oracle and for nv on hardware without Pallas).
 
 **Zero-copy x**: every per-rank buffer length is rounded up to the block
-lane width bn at compile time, so the BSR/ELL kernels read ``v_loc``,
+lane width bn at compile time, so the fused BSR kernel reads ``v_loc``,
 ``b_on_node`` and ``b_off_node`` as separate refs via slot-indexed
 index_maps — the packed x operand is never materialised as an HBM
 pad/concat (``materialize_x=True`` re-enables the old concat path as a
-bit-for-bit A/B oracle).
+bit-for-bit A/B oracle).  The ELL path concatenates the segments once
+per call before its row gathers.
 
 Plan compilation is fully vectorised (bulk ``np.searchsorted`` against the
 slot maps :meth:`NAPPlan.recv_slot_map` exposes — no per-element Python
@@ -95,7 +96,6 @@ import jax.numpy as jnp
 from jax.ops import segment_sum
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.comm_graph import (Message, NAPPlan, StandardPlan,
                                    build_nap_plan, build_standard_plan,
                                    lookup_slots)
@@ -173,8 +173,8 @@ def _memo_device_arrays(topo: Topology, arrays: Dict[str, np.ndarray],
     ``cache`` is normally a :class:`repro.mesh.buffers.BufferNamespace`
     (dict protocol) so the persistent-buffer registry accounts staging,
     reuse and eviction; placement goes through
-    :func:`repro.mesh.buffers.stage_mesh_array` — a plain ``jnp.asarray``
-    in a single process, a global ``jax.Array`` under a multi-process
+    :func:`repro.mesh.buffers.stage_mesh_array` — one shard per mesh
+    device, a global ``jax.Array`` under a multi-process
     ``jax.distributed`` mesh.
     """
     from repro.mesh.buffers import stage_mesh_array
@@ -700,7 +700,7 @@ def compile_nap(a: CSR, part: RowPartition, topo: Topology,
                          f"tile, got {bn}")
     # Segment lengths of the packed x operand are rounded up to the lane
     # width bn, so v_loc / b_on_node / b_off_node are bn-aligned views of
-    # one packed domain and the Pallas kernels gather them zero-copy (no
+    # one packed domain and the fused BSR kernel reads them zero-copy (no
     # HBM pad/concat per call).  Padding slots beyond the true sizes are
     # never referenced by a nonzero, so the rounding is mathematically
     # inert everywhere (incl. the COO path's segment_sum).  rows_pad is
@@ -1045,19 +1045,6 @@ def unpack_vector(w: np.ndarray, part: RowPartition, topo: Topology) -> np.ndarr
 # Shared run wrapper
 # ---------------------------------------------------------------------------
 
-#: Device-array names that carry matrix VALUES rather than structure.
-#: The shard_map builders pass these to the jitted program as ARGUMENTS
-#: (re-fetched from the compiled plan on every call) instead of baking
-#: them in as trace-time closure constants — so a hot value swap
-#: (:meth:`CompiledNAP.swap_values`: same sparsity, new numbers) flows
-#: into the SAME compiled executable with zero retraces, because the
-#: replacement arrays have identical shapes/dtypes and hit the jit cache.
-VALUE_ARRAY_NAMES = frozenset({
-    "on_proc_vals", "on_node_vals", "off_node_vals",
-    "ell_vals", "ell_t_vals", "fused_blocks", "A_vals",
-    "abft_col", "abft_col_abs", "abft_row", "abft_row_abs"})
-
-
 # ---------------------------------------------------------------------------
 # In-graph integrity primitives (jnp twins of repro.core.integrity)
 # ---------------------------------------------------------------------------
@@ -1133,7 +1120,7 @@ def _stack_chk(pairs: List[Tuple[jnp.ndarray, jnp.ndarray]],
     return jnp.stack(rows)
 
 
-def _make_run(call4, fmt: str, val_fetch=None, fault_fetch=None, stage=None):
+def _make_run(call4, fmt: str, arg_fetch, stage, fault_fetch=None):
     """Wrap a 4-D shard program into the public run callable.
 
     ``run(v_shards, donate=False)`` accepts [n_nodes, ppn, rows_pad] or
@@ -1141,20 +1128,21 @@ def _make_run(call4, fmt: str, val_fetch=None, fault_fetch=None, stage=None):
     entry with ``donate_argnums=(0,)`` (built lazily) so XLA may reuse the
     input shard buffer — the ``NapOperator.__call__(donate=...)`` path.
 
-    ``val_fetch()`` returns the CURRENT matrix-value device arrays, passed
-    as extra jit arguments each call (the hot-value-swap seam — see
-    :data:`VALUE_ARRAY_NAMES`).  ``run.n_traces()`` counts program traces:
-    it must not grow across a value swap with unchanged shapes.
+    ``arg_fetch()`` returns the plan's CURRENT device arrays, passed as
+    jit arguments on every call (see :func:`_plan_arg_fetch`), so a
+    hot value swap flows into the same executable.  ``run.n_traces()``
+    counts program traces: it must not grow across a value swap with
+    unchanged shapes.  ``stage`` (:func:`repro.mesh.buffers.input_stager`)
+    places the packed operand on the mesh, one shard per device.
 
     ``fault_fetch()`` (integrity-instrumented programs only) returns the
     armed fault-spec array — same shape/dtype every call, so arming or
     clearing scripted faults never retraces either.  With it set, ``run``
     returns the instrumented triple ``(w_shards, chk, abft)``.
 
-    ``stage`` (multi-process jobs only — see
-    :func:`repro.mesh.buffers.input_stager`) places the packed operand as
-    a GLOBAL sharded array before the jit call; ``None`` keeps the
-    single-process ``jnp.asarray`` path bit-for-bit.
+    ``run.jitted`` and ``run.args()`` expose the jitted 4-D entry and its
+    plan arguments, so the program can be lowered against described
+    devices (compile rehearsals) without running it.
     """
     counter = {"n": 0}
 
@@ -1164,86 +1152,56 @@ def _make_run(call4, fmt: str, val_fetch=None, fault_fetch=None, stage=None):
 
     jits = {False: jax.jit(traced)}
 
+    def args():
+        if fault_fetch is None:
+            return arg_fetch()
+        return (stage(np.asarray(fault_fetch()), np.int32),) + arg_fetch()
+
     def run(v_shards, donate: bool = False):
-        if stage is None:
-            v_shards = jnp.asarray(v_shards, jnp.float32)
-        else:
-            v_shards = stage(v_shards)
+        v_shards = stage(v_shards)
         donate = bool(donate)
         if donate and donate not in jits:
             jits[True] = jax.jit(traced, donate_argnums=(0,))
         fn = jits[donate]
-        vals = val_fetch() if val_fetch is not None else ()
-        if fault_fetch is not None:
-            spec_np = np.asarray(fault_fetch())
-            spec_arg = (jnp.asarray(spec_np, jnp.int32) if stage is None
-                        else stage(spec_np, np.int32))
-            if v_shards.ndim == 3:
-                w, chk, abft = fn(v_shards[..., None], spec_arg, *vals)
-                return w[..., 0], chk, abft
-            return fn(v_shards, spec_arg, *vals)
-        if v_shards.ndim == 3:
-            return fn(v_shards[..., None], *vals)[..., 0]
-        return fn(v_shards, *vals)
+        if v_shards.ndim == 4:
+            return fn(v_shards, *args())
+        out = fn(v_shards[..., None], *args())
+        if fault_fetch is None:
+            return out[..., 0]
+        w, chk, abft = out
+        return w[..., 0], chk, abft
 
     run.local_compute = fmt
     run.integrity = fault_fetch is not None
+    run.jitted = jits[False]
+    run.args = args
     # jitted 4-D entry, exposed for jaxpr/HLO checks — keeps the
-    # single-argument contract by binding the current value arrays.
-    if fault_fetch is not None:
-        run.run4 = lambda v_shards: jits[False](
-            v_shards, jnp.asarray(np.asarray(fault_fetch()), jnp.int32),
-            *(val_fetch() if val_fetch is not None else ()))
-    elif val_fetch is None:
-        run.run4 = jits[False]
-    else:
-        run.run4 = lambda v_shards: jits[False](v_shards, *val_fetch())
+    # single-argument contract by binding the current plan arrays.
+    run.run4 = lambda v_shards: jits[False](v_shards, *args())
     run.n_traces = lambda: counter["n"]
     return run
 
 
-def _bind_shard_program(smapped, compiled, names: List[str],
-                        with_fault: bool = False):
-    """(call4, val_fetch) for a shard program applied as
-    ``smapped(v_shards, *[arrays[k] for k in names])``.
+def _plan_arg_fetch(compiled, names: List[str]):
+    """``arg_fetch()`` returning the plan arrays ``names`` for a shard
+    program applied as ``smapped(v_shards, [fault_spec,] *arrays)``.
 
-    Structural arrays (gather/scatter maps, column indices) bind as
-    closure constants — they are immutable for the life of the plan.
-    :data:`VALUE_ARRAY_NAMES` entries instead arrive through ``val_fetch``
-    as per-call jit arguments read off the LIVE compiled plan, so
-    ``swap_values`` takes effect on the next call without retracing.
-    ``with_fault`` inserts the integrity fault-spec as the second
-    positional argument (the instrumented-program calling convention).
-
-    Multi-process jobs pass EVERY named array as an argument instead:
-    jax forbids closing over a ``jax.Array`` that spans non-addressable
-    devices, and the plan's device buffers are global under a
-    ``jax.distributed`` mesh.  The single-process split is unchanged.
+    Every plan array — gather/scatter maps, column indices and values —
+    is a jit ARGUMENT read off the LIVE compiled plan on each call, never
+    a closure constant: jax embeds closed-over arrays in the HLO as
+    literals, which at 10^6 rows turns one program into hundreds of MB
+    of constants, and a global multi-process ``jax.Array`` cannot be
+    closed over at all.  ``swap_values`` therefore takes effect on the
+    next call without retracing (replacement arrays have identical
+    shapes/dtypes).
     """
-    from repro.mesh.buffers import is_multiprocess
-    dev = compiled.device_arrays()
-    if is_multiprocess():
-        val_names = list(names)
-    else:
-        val_names = [k for k in names if k in VALUE_ARRAY_NAMES]
-    struct = {k: dev[k] for k in names if k not in val_names}
+    compiled.device_arrays()     # stage every named array once
 
-    if with_fault:
-        def call4(v_shards, fault_spec, *vals):
-            by = dict(zip(val_names, vals))
-            return smapped(v_shards, fault_spec,
-                           *[by[k] if k in by else struct[k] for k in names])
-    else:
-        def call4(v_shards, *vals):
-            by = dict(zip(val_names, vals))
-            return smapped(v_shards, *[by[k] if k in by else struct[k]
-                                       for k in names])
-
-    def val_fetch():
+    def arg_fetch():
         d = compiled.device_arrays()
-        return tuple(d[k] for k in val_names)
+        return tuple(d[k] for k in names)
 
-    return call4, val_fetch
+    return arg_fetch
 
 
 # ---------------------------------------------------------------------------
@@ -1252,7 +1210,7 @@ def _bind_shard_program(smapped, compiled, names: List[str],
 
 def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
                          local_compute: str = "auto", nv_block: int = 128,
-                         interpret: bool = True, materialize_x: bool = False,
+                         materialize_x: bool = False,
                          integrity: bool = False, fault_fetch=None):
     """Build the jitted shard_map NAPSpMV: f(v_shards) -> w_shards.
 
@@ -1261,11 +1219,12 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
     packed [n_nodes, ppn, rows_pad(, nv)] (identical shapes in the square
     single-partition case).  ``local_compute`` selects the
     local kernel: ``"auto"`` (default) defers to the compile-time format
-    autotuner, ``"bsr"`` / ``"ell"`` force the fused Pallas kernels and
-    ``"coo"`` the scalar segment_sum reference.  The resolved format is
-    exposed as ``run.local_compute``.  ``materialize_x=True`` re-enables
-    the legacy HBM pad/concat of the packed x operand (bit-for-bit equal
-    to the default zero-copy gather; kept as an A/B oracle).
+    autotuner, ``"bsr"`` forces the fused Pallas kernel, ``"ell"`` the
+    XLA ELL product and ``"coo"`` the scalar segment_sum reference.  The
+    resolved format is exposed as ``run.local_compute``.
+    ``materialize_x=True`` re-enables the legacy HBM pad/concat of the
+    BSR kernel's packed x operand (bit-for-bit equal to the default
+    zero-copy read; kept as an A/B oracle).
 
     ``integrity=True`` builds the INSTRUMENTED program instead: every
     message payload is checksummed by the sender before the scripted
@@ -1365,20 +1324,16 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
             if materialize_x:
                 x_cat = jnp.concatenate([v_loc, bnode, boff]).reshape(-1, bn, nv)
                 w_tiles = fused_bsr_spmm(fused_cols, fused_blocks, x_cat,
-                                         nv_block=nv_block, interpret=interpret)
+                                         nv_block=nv_block)
             else:
                 xs = tuple(seg.reshape(-1, bn, nv)
                            for seg in (v_loc, bnode, boff))
                 w_tiles = fused_bsr_spmm_packed(fused_cols, fused_blocks, xs,
-                                                nv_block=nv_block,
-                                                interpret=interpret)
+                                                nv_block=nv_block)
             w = w_tiles.reshape(-1, nv)[:rows_pad]
         elif fmt == "ell":
             ell_cols, ell_vals = tail
-            xs = ((jnp.concatenate([v_loc, bnode, boff]),) if materialize_x
-                  else (v_loc, bnode, boff))
-            w = ell_spmm_packed(ell_cols, ell_vals, xs,
-                                nv_block=nv_block, interpret=interpret)
+            w = ell_spmm_packed(ell_cols, ell_vals, (v_loc, bnode, boff))
         else:
             (on_proc_rows, on_proc_cols, on_proc_vals,
              on_node_rows, on_node_cols, on_node_vals,
@@ -1427,21 +1382,19 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
         names += ["abft_col", "abft_col_abs"]
     spec = P("node", "proc")
     n_in = 1 + len(names) + (1 if integrity else 0)
-    smapped = shard_map(per_device, mesh=mesh,
-                        in_specs=(spec,) * n_in,
-                        out_specs=(spec, spec, spec) if integrity else spec,
-                        check_vma=False)
-    call4, val_fetch = _bind_shard_program(smapped, compiled, names,
-                                           with_fault=integrity)
+    smapped = jax.shard_map(per_device, mesh=mesh,
+                            in_specs=(spec,) * n_in,
+                            out_specs=(spec, spec, spec) if integrity
+                            else spec,
+                            check_vma=False)
     from repro.mesh.buffers import input_stager
-    return _make_run(call4, fmt, val_fetch,
-                     fault_fetch=fault_fetch if integrity else None,
-                     stage=input_stager(compiled.topo))
+    return _make_run(smapped, fmt, _plan_arg_fetch(compiled, names),
+                     input_stager(compiled.topo),
+                     fault_fetch=fault_fetch if integrity else None)
 
 
 def nap_transpose_shardmap(compiled: CompiledNAP, mesh: Mesh,
                            local_compute: str = "auto", nv_block: int = 128,
-                           interpret: bool = True,
                            integrity: bool = False, fault_fetch=None):
     """Build the jitted shard_map transpose NAPSpMV: f(u_shards) -> z_shards
     with ``z = A.T u`` — the exact adjoint of :func:`nap_forward_shardmap`.
@@ -1461,8 +1414,8 @@ def nap_transpose_shardmap(compiled: CompiledNAP, mesh: Mesh,
     forward direction: ``"auto"`` resolves against the transpose verdict
     recorded on ``compiled.autotune["transpose"]`` (argmin of ell/coo —
     there is no transposed Pallas BSR kernel, so a ``"bsr"`` request also
-    defers to that verdict).  ``"ell"`` runs A_r^T as ONE Pallas ELL SpMM
-    over the packed contribution domain ``[z | c_on_node | c_off_node]``;
+    defers to that verdict).  ``"ell"`` runs A_r^T as ONE ELL SpMM over
+    the packed contribution domain ``[z | c_on_node | c_off_node]``;
     ``"coo"`` is the scalar segment_sum scatter reference.
     """
     fmt = compiled.resolve_transpose_local_compute(local_compute)
@@ -1521,8 +1474,7 @@ def nap_transpose_shardmap(compiled: CompiledNAP, mesh: Mesh,
         #    output domain of each block (local x rows / buffer slots).
         if fmt == "ell":
             ell_t_cols, ell_t_vals = tail
-            contrib = ell_spmm_packed(ell_t_cols, ell_t_vals, (u_loc,),
-                                      nv_block=nv_block, interpret=interpret)
+            contrib = ell_spmm_packed(ell_t_cols, ell_t_vals, (u_loc,))
             z = contrib[:cols_pad]
             c_node = contrib[cols_pad: cols_pad + bnode_pad]
             c_off = contrib[cols_pad + bnode_pad:]
@@ -1622,16 +1574,15 @@ def nap_transpose_shardmap(compiled: CompiledNAP, mesh: Mesh,
         names += ["abft_row", "abft_row_abs"]
     spec = P("node", "proc")
     n_in = 1 + len(names) + (1 if integrity else 0)
-    smapped = shard_map(per_device, mesh=mesh,
-                        in_specs=(spec,) * n_in,
-                        out_specs=(spec, spec, spec) if integrity else spec,
-                        check_vma=False)
-    call4, val_fetch = _bind_shard_program(smapped, compiled, names,
-                                           with_fault=integrity)
+    smapped = jax.shard_map(per_device, mesh=mesh,
+                            in_specs=(spec,) * n_in,
+                            out_specs=(spec, spec, spec) if integrity
+                            else spec,
+                            check_vma=False)
     from repro.mesh.buffers import input_stager
-    return _make_run(call4, fmt, val_fetch,
-                     fault_fetch=fault_fetch if integrity else None,
-                     stage=input_stager(compiled.topo))
+    return _make_run(smapped, fmt, _plan_arg_fetch(compiled, names),
+                     input_stager(compiled.topo),
+                     fault_fetch=fault_fetch if integrity else None)
 
 
 # ---------------------------------------------------------------------------
@@ -1891,15 +1842,15 @@ def compile_standard(a: CSR, part: RowPartition, topo: Topology,
 
 def standard_forward_shardmap(compiled: CompiledStandard, mesh: Mesh,
                               local_compute: str = "auto",
-                              nv_block: int = 128, interpret: bool = True,
+                              nv_block: int = 128,
                               materialize_x: bool = False,
                               integrity: bool = False, fault_fetch=None):
     """Algorithm 1 as a flat padded all-to-all over ("node","proc").
 
     Local compute runs through the same adaptive engine as the NAP path —
     ``"auto"`` (default) picks bsr/ell/coo from the format cost model over
-    the two-segment ``[v_loc | recv buffer]`` packed x domain; both Pallas
-    paths read the segments zero-copy.  The resolved format is exposed as
+    the two-segment ``[v_loc | recv buffer]`` packed x domain; the fused
+    BSR kernel reads the segments zero-copy.  The resolved format is exposed as
     ``run.local_compute``.  ``integrity=True`` instruments the single
     ``pair`` exchange + ABFT exactly like :func:`nap_forward_shardmap`.
     """
@@ -1939,19 +1890,16 @@ def standard_forward_shardmap(compiled: CompiledStandard, mesh: Mesh,
             if materialize_x:
                 x_cat = jnp.concatenate([v_loc, buf]).reshape(-1, bn, nv)
                 w_tiles = fused_bsr_spmm(fused_cols, fused_blocks, x_cat,
-                                         nv_block=nv_block, interpret=interpret)
+                                         nv_block=nv_block)
             else:
                 w_tiles = fused_bsr_spmm_packed(
                     fused_cols, fused_blocks,
                     (v_loc.reshape(-1, bn, nv), buf.reshape(-1, bn, nv)),
-                    nv_block=nv_block, interpret=interpret)
+                    nv_block=nv_block)
             w = w_tiles.reshape(-1, nv)[:rows_pad]
         elif fmt == "ell":
             ell_cols, ell_vals = tail
-            xs = ((jnp.concatenate([v_loc, buf]),) if materialize_x
-                  else (v_loc, buf))
-            w = ell_spmm_packed(ell_cols, ell_vals, xs,
-                                nv_block=nv_block, interpret=interpret)
+            w = ell_spmm_packed(ell_cols, ell_vals, (v_loc, buf))
         else:
             A_rows, A_cols, A_vals = tail
             full = jnp.concatenate([v_loc, buf])
@@ -1977,21 +1925,20 @@ def standard_forward_shardmap(compiled: CompiledStandard, mesh: Mesh,
         names += ["abft_col", "abft_col_abs"]
     spec = P("node", "proc")
     n_in = 1 + len(names) + (1 if integrity else 0)
-    smapped = shard_map(per_device, mesh=mesh,
-                        in_specs=(spec,) * n_in,
-                        out_specs=(spec, spec, spec) if integrity else spec,
-                        check_vma=False)
-    call4, val_fetch = _bind_shard_program(smapped, compiled, names,
-                                           with_fault=integrity)
+    smapped = jax.shard_map(per_device, mesh=mesh,
+                            in_specs=(spec,) * n_in,
+                            out_specs=(spec, spec, spec) if integrity
+                            else spec,
+                            check_vma=False)
     from repro.mesh.buffers import input_stager
-    return _make_run(call4, fmt, val_fetch,
-                     fault_fetch=fault_fetch if integrity else None,
-                     stage=input_stager(compiled.topo))
+    return _make_run(smapped, fmt, _plan_arg_fetch(compiled, names),
+                     input_stager(compiled.topo),
+                     fault_fetch=fault_fetch if integrity else None)
 
 
 def standard_transpose_shardmap(compiled: CompiledStandard, mesh: Mesh,
                                 local_compute: str = "auto",
-                                nv_block: int = 128, interpret: bool = True,
+                                nv_block: int = 128,
                                 integrity: bool = False, fault_fetch=None):
     """Transpose of Algorithm 1 against the same compiled plan:
     f(u_shards) -> z_shards with ``z = A.T u``.
@@ -2004,8 +1951,8 @@ def standard_transpose_shardmap(compiled: CompiledStandard, mesh: Mesh,
     re-applies (its own adjoint), and ``send_idx`` scatters the returned
     contributions into the owners' rows.  Transposed local compute runs
     the adaptive engine restricted to ell/coo — ``"auto"`` resolves
-    against ``compiled.autotune["transpose"]``, ``"ell"`` runs one Pallas
-    ELL SpMM of A_r^T over the packed contribution domain.
+    against ``compiled.autotune["transpose"]``, ``"ell"`` runs one ELL
+    SpMM of A_r^T over the packed contribution domain.
     """
     fmt = compiled.resolve_transpose_local_compute(local_compute)
     if fmt == "ell":
@@ -2034,8 +1981,7 @@ def standard_transpose_shardmap(compiled: CompiledStandard, mesh: Mesh,
         # transposed local SpMV over the packed domain [v_loc | buf]
         if fmt == "ell":
             ell_t_cols, ell_t_vals = tail
-            c = ell_spmm_packed(ell_t_cols, ell_t_vals, (u_loc,),
-                                nv_block=nv_block, interpret=interpret)
+            c = ell_spmm_packed(ell_t_cols, ell_t_vals, (u_loc,))
         else:
             A_rows, A_cols, A_vals = tail
             c = segment_sum(A_vals[:, None] * u_loc[A_rows], A_cols,
@@ -2076,16 +2022,15 @@ def standard_transpose_shardmap(compiled: CompiledStandard, mesh: Mesh,
         names += ["abft_row", "abft_row_abs"]
     spec = P("node", "proc")
     n_in = 1 + len(names) + (1 if integrity else 0)
-    smapped = shard_map(per_device, mesh=mesh,
-                        in_specs=(spec,) * n_in,
-                        out_specs=(spec, spec, spec) if integrity else spec,
-                        check_vma=False)
-    call4, val_fetch = _bind_shard_program(smapped, compiled, names,
-                                           with_fault=integrity)
+    smapped = jax.shard_map(per_device, mesh=mesh,
+                            in_specs=(spec,) * n_in,
+                            out_specs=(spec, spec, spec) if integrity
+                            else spec,
+                            check_vma=False)
     from repro.mesh.buffers import input_stager
-    return _make_run(call4, fmt, val_fetch,
-                     fault_fetch=fault_fetch if integrity else None,
-                     stage=input_stager(compiled.topo))
+    return _make_run(smapped, fmt, _plan_arg_fetch(compiled, names),
+                     input_stager(compiled.topo),
+                     fault_fetch=fault_fetch if integrity else None)
 
 
 # ---------------------------------------------------------------------------

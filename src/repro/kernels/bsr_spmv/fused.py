@@ -33,29 +33,20 @@ mathematically inert; the index_map clamps them to 0 to stay in bounds.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
-
-def _fused_kernel(cols_ref, blk_ref, x_ref, o_ref):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += jnp.dot(blk_ref[0, 0], x_ref[0],
-                          preferred_element_type=jnp.float32)
+from repro.compat import interpret_mode
 
 
 @functools.partial(jax.jit, static_argnames=("nv_block", "interpret"))
 def fused_bsr_spmm(cols: jax.Array, blocks: jax.Array, x: jax.Array,
-                   *, nv_block: int = 128, interpret: bool = True) -> jax.Array:
+                   *, nv_block: int = 128,
+                   interpret: Optional[bool] = None) -> jax.Array:
     """w = A @ x for the fused padded-uniform BSR layout, nv-tiled.
 
     cols:   [n_brows, ktot] int32 block-column ids into the concatenated
@@ -64,37 +55,11 @@ def fused_bsr_spmm(cols: jax.Array, blocks: jax.Array, x: jax.Array,
     x:      [n_bcols, bn, nv] — concat(v_loc, b_on_node, b_off_node) blocks
     returns [n_brows, bm, nv] float32
 
-    Grid: (n_brows, nv_tiles, ktot) — block rows and nv tiles are parallel,
-    the slot axis is the sequential accumulation axis.  nv is padded up to a
-    multiple of ``nv_block`` and sliced back.
+    The one-segment case of :func:`fused_bsr_spmm_packed` (the
+    materialised-concat A/B oracle of the zero-copy path).
     """
-    n_brows, ktot, bm, bn = blocks.shape
-    nv = x.shape[-1]
-    nv_block = min(nv_block, max(nv, 1))
-    nv_pad = -(-nv // nv_block) * nv_block
-    if nv_pad != nv:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, nv_pad - nv)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_brows, nv_pad // nv_block, ktot),
-        in_specs=[
-            pl.BlockSpec((1, 1, bm, bn), lambda i, v, k, cols: (i, k, 0, 0)),
-            # the sparse gather: x block chosen by the prefetched col id
-            pl.BlockSpec((1, bn, nv_block),
-                         lambda i, v, k, cols: (jnp.maximum(cols[i, k], 0), 0, v)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, nv_block), lambda i, v, k, cols: (i, 0, v)),
-    )
-    out = pl.pallas_call(
-        _fused_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_brows, bm, nv_pad), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(cols, blocks, x)
-    return out[..., :nv] if nv_pad != nv else out
+    return fused_bsr_spmm_packed(cols, blocks, (x,), nv_block=nv_block,
+                                 interpret=interpret)
 
 
 def fused_bsr_spmm_ref(cols, blocks, x) -> jnp.ndarray:
@@ -107,22 +72,29 @@ def fused_bsr_spmm_ref(cols, blocks, x) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Zero-copy packed-x variant
+# Zero-copy packed-x kernel
 # ---------------------------------------------------------------------------
 #
-# Same math as fused_bsr_spmm, but the x operand arrives as SEPARATE
-# bn-aligned segments (v_loc, b_on_node, b_off_node) instead of one
-# HBM-materialised concat.  Each segment gets its own ref whose index_map
-# routes the prefetched block-column id into that segment's local block
-# index (clamped to 0 when the slot belongs to another segment); the
-# kernel then selects the one block that is in range.  Because an
-# out-of-range ref's index_map pins it to block 0, the Pallas pipeline
-# re-fetches it only on segment transitions — slots are sorted
-# on-process -> on-node -> off-node, so each x ref's DMA stream stays
-# monotone and the extra traffic is at most one block per segment switch.
+# The x operand arrives as SEPARATE bn-aligned segments (v_loc,
+# b_on_node, b_off_node) instead of one HBM-materialised concat.  Each
+# segment gets its own ref whose index_map routes the prefetched
+# block-column id into that segment's local block index (clamped to 0
+# when the slot belongs to another segment); the kernel then selects the
+# one block that is in range.  Because an out-of-range ref's index_map
+# pins it to block 0, the Pallas pipeline re-fetches it only on segment
+# transitions — slots are sorted on-process -> on-node -> off-node, so
+# each x ref's DMA stream stays monotone and the extra traffic is at
+# most one block per segment switch.
+#
+# The block-column ids are scalar-prefetched into SMEM (1 MiB on a v5e),
+# flattened to 1-D: a 2-D [n_brows, ktot] array pads its minor axis to
+# 128 words, which at 2^20 rows (131072 block rows) needs 64 MiB.  Block
+# rows are split into calls of at most _SMEM_COL_IDS ids each.
+
+_SMEM_COL_IDS = 2**17
 
 
-def _make_packed_kernel(bounds):
+def _make_packed_kernel(bounds, ktot):
     def kernel(cols_ref, blk_ref, *rest):
         *x_refs, o_ref = rest
         k = pl.program_id(2)
@@ -131,29 +103,37 @@ def _make_packed_kernel(bounds):
         def _init():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        c = cols_ref[pl.program_id(0), k]
+        c = cols_ref[pl.program_id(0) * ktot + k]
         x = x_refs[-1][0]
         for b, x_ref in zip(reversed(bounds[:-1]), reversed(x_refs[:-1])):
             x = jnp.where(c < b, x_ref[0], x)
+        # full f32 passes on the MXU: every local format meets one f32 bound
         o_ref[...] += jnp.dot(blk_ref[0, 0], x,
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
 
     return kernel
 
 
-def _segment_spec(lo, hi, bn, nv_block):
+def _segment_spec(lo, hi, bn, nv_block, ktot):
     # route block col c into this segment's local index; pin to 0 otherwise
     def index_map(i, v, k, cols):
-        c = cols[i, k]
+        c = cols[i * ktot + k]
         return (jnp.where((c >= lo) & (c < hi), c - lo, 0), 0, v)
 
     return pl.BlockSpec((1, bn, nv_block), index_map)
 
 
+def _block_spec(r0, bm, bn):
+    # matrix blocks of this call's block rows, offset into the full array
+    return pl.BlockSpec((1, 1, bm, bn),
+                        lambda i, v, k, cols: (i + r0, k, 0, 0))
+
+
 @functools.partial(jax.jit, static_argnames=("nv_block", "interpret"))
 def fused_bsr_spmm_packed(cols: jax.Array, blocks: jax.Array, xs, *,
                           nv_block: int = 128,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: Optional[bool] = None) -> jax.Array:
     """w = A @ concat(xs) without materialising the concat in HBM.
 
     cols:   [n_brows, ktot] int32 block-column ids into the packed domain
@@ -163,6 +143,10 @@ def fused_bsr_spmm_packed(cols: jax.Array, blocks: jax.Array, xs, *,
     xs:     tuple of [n_bcols_s, bn, nv] segments (1..3 of them)
     returns [n_brows, bm, nv] float32 — bit-for-bit equal to
     ``fused_bsr_spmm(cols, blocks, jnp.concatenate(xs))``.
+
+    Grid per call: (block rows, nv tiles, ktot) — block rows and nv tiles
+    are parallel, the slot axis is the sequential accumulation axis.  nv
+    is padded up to a multiple of ``nv_block`` and sliced back.
     """
     xs = tuple(jnp.asarray(x, jnp.float32) for x in xs)
     n_brows, ktot, bm, bn = blocks.shape
@@ -176,24 +160,28 @@ def fused_bsr_spmm_packed(cols: jax.Array, blocks: jax.Array, xs, *,
     for x in xs:
         acc += x.shape[0]
         bounds.append(acc)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_brows, nv_pad // nv_block, ktot),
-        in_specs=[
-            pl.BlockSpec((1, 1, bm, bn), lambda i, v, k, cols: (i, k, 0, 0)),
-        ] + [
-            _segment_spec(lo, hi, bn, nv_block)
-            for lo, hi in zip([0] + bounds[:-1], bounds)
-        ],
-        out_specs=pl.BlockSpec((1, bm, nv_block), lambda i, v, k, cols: (i, 0, v)),
-    )
-    out = pl.pallas_call(
-        _make_packed_kernel(tuple(bounds)),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_brows, bm, nv_pad), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(cols, blocks, *xs)
+    seg_specs = [_segment_spec(lo, hi, bn, nv_block, ktot)
+                 for lo, hi in zip([0] + bounds[:-1], bounds)]
+    kernel = _make_packed_kernel(tuple(bounds), ktot)
+    step = max(1, _SMEM_COL_IDS // ktot)
+    outs = []
+    for r0 in range(0, n_brows, step):
+        n = min(step, n_brows - r0)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n, nv_pad // nv_block, ktot),
+            in_specs=[_block_spec(r0, bm, bn)] + seg_specs,
+            out_specs=pl.BlockSpec((1, bm, nv_block),
+                                   lambda i, v, k, cols: (i, 0, v)),
+        )
+        outs.append(pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n, bm, nv_pad), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+            ),
+            interpret=interpret_mode(interpret),
+        )(cols[r0:r0 + n].reshape(-1), blocks, *xs))
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
     return out[..., :nv] if nv_pad != nv else out

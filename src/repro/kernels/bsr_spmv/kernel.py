@@ -33,13 +33,14 @@ and what remains for a real multi-host mesh (ROADMAP "Open items").
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.compat import interpret_mode
 
 
 def _kernel(cols_ref, blk_ref, x_ref, o_ref):
@@ -50,12 +51,13 @@ def _kernel(cols_ref, blk_ref, x_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     o_ref[...] += jnp.dot(blk_ref[0, 0], x_ref[0],
+                          precision=jax.lax.Precision.HIGHEST,
                           preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bsr_spmm_padded(cols: jax.Array, blocks: jax.Array, x: jax.Array,
-                    *, interpret: bool = True) -> jax.Array:
+                    *, interpret: Optional[bool] = None) -> jax.Array:
     """w = A @ x for the padded-uniform BSR layout.
 
     cols:   [n_brows, kmax] int32 block-column ids (-1 = padding)
@@ -80,8 +82,8 @@ def bsr_spmm_padded(cols: jax.Array, blocks: jax.Array, x: jax.Array,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_brows, bm, nv), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(cols, blocks, x)

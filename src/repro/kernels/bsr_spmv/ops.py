@@ -11,7 +11,7 @@ from repro.kernels.bsr_spmv.kernel import bsr_spmm_padded
 from repro.sparse.bsr import BSR
 
 
-def bsr_spmm(bsr: BSR, x, *, interpret: bool = True) -> jax.Array:
+def bsr_spmm(bsr: BSR, x, *, interpret: Optional[bool] = None) -> jax.Array:
     """w = A @ x with x [n_cols, nv]; returns [n_rows, nv] (padded shape)."""
     cols, blocks, _ = bsr.padded_uniform()
     bm, bn = bsr.block_shape
@@ -26,7 +26,7 @@ def bsr_spmm(bsr: BSR, x, *, interpret: bool = True) -> jax.Array:
     return out.reshape(bsr.shape[0], -1)
 
 
-def bsr_spmv(bsr: BSR, v, *, interpret: bool = True) -> jax.Array:
+def bsr_spmv(bsr: BSR, v, *, interpret: Optional[bool] = None) -> jax.Array:
     """w = A @ v for a single vector; returns [n_rows] (padded shape)."""
     v = jnp.asarray(v, jnp.float32).reshape(-1, 1)
     return bsr_spmm(bsr, v, interpret=interpret).reshape(-1)

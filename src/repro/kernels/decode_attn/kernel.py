@@ -18,13 +18,14 @@ Supports the gemma2 logit soft-cap (scores = cap * tanh(scores / cap)).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.compat import interpret_mode
 
 NEG_BIG = -1e30
 
@@ -71,7 +72,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 def decode_attention_grouped(q: jax.Array, k: jax.Array, v: jax.Array,
                              lengths: jax.Array, *, scale: float,
                              softcap: float = 0.0, block_s: int = 512,
-                             interpret: bool = True) -> jax.Array:
+                             interpret: Optional[bool] = None) -> jax.Array:
     """q [B, Hkv, g, D]; k, v [B, Hkv, S, D]; lengths [B] -> out [B, Hkv, g, D]."""
     B, Hkv, g, D = q.shape
     S = k.shape[2]
@@ -97,8 +98,8 @@ def decode_attention_grouped(q: jax.Array, k: jax.Array, v: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lengths, q, k, v)

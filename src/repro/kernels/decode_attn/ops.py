@@ -1,6 +1,8 @@
 """User-facing decode attention: flat-head layout + cache padding."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -8,7 +10,7 @@ from repro.kernels.decode_attn.kernel import decode_attention_grouped
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, softcap: float = 0.0,
-                     block_s: int = 512, interpret: bool = True) -> jax.Array:
+                     block_s: int = 512, interpret: Optional[bool] = None) -> jax.Array:
     """GQA decode attention.
 
     q:        [B, H, D]   one new token per sequence

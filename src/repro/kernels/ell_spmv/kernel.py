@@ -1,123 +1,57 @@
-"""Pallas TPU kernel: ELL (padded-row) SpMV / SpMM over a packed x operand.
+"""ELL (padded-row) SpMV / SpMM over a packed x operand, as plain XLA.
 
 This is the block-hostile branch of the adaptive local-compute engine
 (`core/spmv_jax.py`): where the fused BSR path would densify (bm, bn)
 tiles at low block fill, the ELL path keeps the matrix as two
-[n_rows, kmax] arrays (column ids + values) and gathers x *rows* inside
-the kernel on the VPU — no MXU tiles, no scatter, padding overhead
-bounded by kmax / mean-row-length.
+[n_rows, kmax] arrays (column ids + values) and gathers x rows on the
+VPU — no MXU tiles, no scatter, padding overhead bounded by
+kmax / mean-row-length.
 
-Zero-copy packed x: the NAPSpMV's three buffers (``v_loc``, on-node recv,
-off-node recv) are passed as SEPARATE refs — the executor never
-materialises the concatenated operand in HBM.  Column ids are emitted in
-the packed domain ``[0, len(v) | len(v)+len(bnode) | ...)`` at plan-compile
-time, and the kernel concatenates the segment blocks in VMEM (a register/
-VMEM move, not an HBM round-trip) before one vectorised gather.  Ordering
-the segments on-process -> on-node -> off-node keeps the streaming
-convention of the fused BSR kernel.
+The product is one XLA loop over the kmax slots: slot k gathers
+``x[cols[:, k]]`` and accumulates ``vals[:, k] * x[cols[:, k]]`` into the
+[n_rows, nv] result.  It is not a Pallas kernel because Mosaic's gather
+lowering refuses an in-kernel row gather at every shape ("Shape mismatch
+in input, indices and output").  The slot loop compiles on the TPU at
+any size and never materialises the [n_rows, kmax, nv] gather that the
+one-shot ``(vals[..., None] * x[cols]).sum(1)`` spelling would (1 GiB at
+2^20 rows x kmax 25, nv 1).
 
-Grid: (n_rows / rows_block, nv / nv_block), both parallel; each step is
-one fused gather + multiply + k-axis reduction, so interpret-mode grid
-overhead stays tiny (the BSR path's slot axis is gone).
-
-VMEM per grid step (f32):
-
-    rows_block * kmax * 8        cols + vals tile
-  + n_x * nv_block * 4           the whole packed x, one nv tile
-  + rows_block * kmax * nv_block * 4   gather temporary
-  + rows_block * nv_block * 4    output tile
-
-``_pick_rows_block`` shrinks rows_block until this fits the budget; the
-format autotuner (`core/cost_model.py`) refuses ELL outright when the
-packed x alone cannot fit, falling back to COO.
+The NAPSpMV's buffers (``v_loc``, on-node recv, off-node recv) arrive as
+separate segments; column ids live in the packed domain
+``[0, len(v) | len(v)+len(bnode) | ...)`` and the segments are
+concatenated once per call (one [n_x, nv] copy).  What the product holds
+in device memory is counted by
+:func:`repro.core.cost_model.ell_resident_bytes`, which the format
+autotuner uses to refuse ELL when it does not fit.
 
 Padding slots (col == -1, val == 0) clamp to x row 0 and multiply by
 zero, so they are mathematically inert.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-from repro.compat import tpu_compiler_params
-
-# Per-step working-set allowance used when auto-picking rows_block; well
-# under the ~16 MiB of VMEM to leave room for double buffering.
-_VMEM_STEP_BUDGET = 6 * 2**20
+from jax import lax
 
 
-def _pick_rows_block(n_rows: int, kmax: int, n_x: int, nv_block: int) -> int:
-    """Largest row tile from {n_rows, 128, 8} dividing n_rows that keeps the
-    per-step working set under the VMEM budget (8 always divides: packed
-    row counts are padded to the BSR lane multiple upstream)."""
-    for rb in (n_rows, 128, 8):
-        if rb > n_rows or n_rows % rb:
-            continue
-        step = (rb * kmax * 8 + n_x * nv_block * 4
-                + rb * kmax * nv_block * 4 + rb * nv_block * 4)
-        if step <= _VMEM_STEP_BUDGET:
-            return rb
-    return 8
-
-
-def _ell_kernel(cols_ref, vals_ref, *rest):
-    *x_refs, o_ref = rest
-    x = x_refs[0][...]
-    if len(x_refs) > 1:  # VMEM concat of the packed segments — no HBM copy
-        x = jnp.concatenate([x] + [r[...] for r in x_refs[1:]], axis=0)
-    cols = cols_ref[...]                                   # [rb, kmax]
-    gathered = jnp.take(x, jnp.maximum(cols, 0).reshape(-1), axis=0,
-                        ).reshape(cols.shape + (x.shape[-1],))
-    o_ref[...] = (vals_ref[...][..., None] * gathered).sum(axis=1)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("nv_block", "rows_block", "interpret"))
-def ell_spmm_packed(cols: jax.Array, vals: jax.Array, xs, *,
-                    nv_block: int = 128, rows_block: int = 0,
-                    interpret: bool = True) -> jax.Array:
-    """w = A @ concat(xs) for the ELL layout, without materialising the concat.
+@jax.jit
+def ell_spmm_packed(cols: jax.Array, vals: jax.Array, xs) -> jax.Array:
+    """w = A @ concat(xs) for the ELL layout.
 
     cols: [n_rows, kmax] int32 column ids in the packed x domain (-1 = pad)
     vals: [n_rows, kmax] float32 (0 on padding slots)
     xs:   tuple of [len_i, nv] segments; the packed domain is their
           concatenation in order (e.g. (v_loc, b_on_node, b_off_node))
     returns [n_rows, nv] float32
-
-    Grid: (n_rows / rows_block, nv / nv_block), both parallel.  nv is
-    padded up to a multiple of nv_block and sliced back.
     """
     xs = tuple(jnp.asarray(x, jnp.float32) for x in xs)
+    x = xs[0] if len(xs) == 1 else jnp.concatenate(xs)
     n_rows, kmax = cols.shape
-    nv = xs[0].shape[-1]
-    nv_block = min(nv_block, max(nv, 1))
-    nv_pad = -(-nv // nv_block) * nv_block
-    if nv_pad != nv:
-        xs = tuple(jnp.pad(x, ((0, 0), (0, nv_pad - nv))) for x in xs)
-    n_x = sum(x.shape[0] for x in xs)
-    if not rows_block:
-        rows_block = _pick_rows_block(n_rows, kmax, n_x, nv_block)
-    assert n_rows % rows_block == 0, (n_rows, rows_block)
 
-    grid = (n_rows // rows_block, nv_pad // nv_block)
-    in_specs = [
-        pl.BlockSpec((rows_block, kmax), lambda i, v: (i, 0)),
-        pl.BlockSpec((rows_block, kmax), lambda i, v: (i, 0)),
-    ] + [
-        pl.BlockSpec((x.shape[0], nv_block), lambda i, v: (0, v)) for x in xs
-    ]
-    out = pl.pallas_call(
-        _ell_kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((rows_block, nv_block), lambda i, v: (i, v)),
-        out_shape=jax.ShapeDtypeStruct((n_rows, nv_pad), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel"),
-        ),
-        interpret=interpret,
-    )(cols, vals, *xs)
-    return out[:, :nv] if nv_pad != nv else out
+    def slot(k, acc):
+        c = lax.dynamic_index_in_dim(cols, k, 1, keepdims=False)
+        v = lax.dynamic_index_in_dim(vals, k, 1, keepdims=False)
+        return acc + v[:, None] * x[jnp.maximum(c, 0)]
+
+    return lax.fori_loop(0, kmax, slot,
+                         jnp.zeros((n_rows, x.shape[1]), jnp.float32))

@@ -29,7 +29,6 @@ import traceback
 
 import jax
 
-from repro import compat
 from repro.configs import all_arch_ids, get_config
 from repro.configs.shapes import SHAPES, cell_runnable
 from repro.core.hlo_analysis import analyze_hlo
@@ -79,7 +78,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         # OVERSTATES the TPU footprint; `analytic` is the TPU-native budget.
         mem["peak_gb"] = mem["argument_gb"] + mem["temp_gb"]
         mem["analytic"] = cell.analytic_gb
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         text = compiled.as_text()
         cost = analyze_hlo(text, pod_boundary=256 if multi_pod else 0)
         mf = model_flops_for(cell.kind, cell.n_active_params, cell.tokens)

@@ -12,11 +12,10 @@ Two jobs, one seam:
   (``serve.PlanCache`` LRU / elastic ``rebuild``) releases its namespace
   so the device memory is accounted, not leaked.
 
-* Placement — the ONE place that knows whether this process is part of a
-  multi-process ``jax.distributed`` mesh.  Single-process staging is a
-  plain ``jnp.asarray`` (bit-identical to the declared-topo seed path);
-  multi-process staging builds a GLOBAL ``jax.Array`` laid out
-  ``P("node", "proc")`` over the process mesh, where each process
+* Placement — every mesh-shaped array is staged as a ``jax.Array`` laid
+  out ``P("node", "proc")`` over the topology's device mesh, one shard
+  per device: on one chip, on the four chips of a host, or as a GLOBAL
+  array over a multi-process ``jax.distributed`` mesh, where each process
   materialises only its addressable shards.  ``fetch_mesh_array``
   inverts it: fully-addressable results fetch with ``np.asarray``,
   global results gather their shards across processes (mask-select per
@@ -46,12 +45,16 @@ __all__ = ["BufferNamespace", "BufferRegistry", "default_registry",
 # ---------------------------------------------------------------------------
 
 def process_count() -> int:
-    """Processes in the jax.distributed job (1 when unattached/jax-free)."""
+    """Processes in the jax.distributed job (1 when unattached/jax-free).
+
+    Only a missing jax counts as one process: a backend that fails to
+    start raises here instead of passing for a single CPU process.
+    """
     try:
         import jax
-        return int(jax.process_count())
-    except Exception:
+    except ImportError:
         return 1
+    return int(jax.process_count())
 
 
 def is_multiprocess() -> bool:
@@ -81,33 +84,23 @@ def _global_sharding(topo: Topology):
 def stage_mesh_array(g: np.ndarray, topo: Topology, dtype=None):
     """Device-stage one mesh-shaped ``[n_nodes, ppn, ...]`` host array.
 
-    Single-process: plain ``jnp.asarray`` — bit-identical to the
-    declared-topo path.  Multi-process: a global ``jax.Array`` sharded
-    ``P("node", "proc")``; each process materialises only the shards it
+    Returns a ``jax.Array`` sharded ``P("node", "proc")`` over
+    :func:`mesh_for` ``(topo)``: shard ``[i, j]`` lives on mesh device
+    ``(i, j)``, so a four-chip topology holds one shard per chip.  Under
+    a multi-process mesh each process materialises only the shards it
     can address (its own node rows), never the full job's buffers.
     """
-    import jax.numpy as jnp
-    if dtype is not None:
-        g = np.asarray(g, dtype)
-    if not is_multiprocess():
-        return jnp.asarray(g)
     import jax
-    g = np.asarray(g)
+    g = np.asarray(g, dtype)
     return jax.make_array_from_callback(g.shape, _global_sharding(topo),
                                         lambda idx: g[idx])
 
 
 def input_stager(topo: Topology):
-    """Per-call operand stager for the jitted run path.
-
-    ``None`` in a single process — the seed's ``jnp.asarray(v, f32)``
-    stays untouched (bit-identity).  Multi-process, returns
-    ``stage(shards, dtype=f32)`` placing the packed ``[n_nodes, ppn,
-    pad(, nv)]`` operand globally so the shard_map program can consume
-    it.
-    """
-    if not is_multiprocess():
-        return None
+    """Per-call operand stager for the jitted run path:
+    ``stage(shards, dtype=f32)`` places the packed ``[n_nodes, ppn,
+    pad(, nv)]`` operand on the mesh (:func:`stage_mesh_array`) so the
+    shard_map program consumes it where it lies."""
 
     def stage(shards, dtype=np.float32):
         return stage_mesh_array(np.asarray(shards, dtype), topo)

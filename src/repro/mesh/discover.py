@@ -43,7 +43,7 @@ def discover_topology(*, strict: bool = True) -> Topology:
     """
     try:
         import jax
-    except Exception:        # jax-free numpy install: the seed default
+    except ImportError:      # jax-free numpy install: the seed default
         return Topology(n_nodes=1, ppn=1)
     n_proc = int(jax.process_count())
     ppn = int(jax.local_device_count())
@@ -61,7 +61,7 @@ def discovery_report() -> Dict[str, object]:
     """Machine-readable view of what discovery saw (benchmarks embed it)."""
     try:
         import jax
-    except Exception:
+    except ImportError:
         return {"source": "fallback", "jax": False,
                 "n_nodes": 1, "ppn": 1, "platform": "none"}
     topo = discover_topology(strict=False)

@@ -2,8 +2,10 @@
 
 Two halves of one contract:
 
-* :func:`launch` — subprocess fan-out for tests/CI: spawn N python
-  processes on localhost, each wired to a fresh coordinator through the
+* :func:`launch` — the CPU harness for tests/CI: spawn N python
+  processes on localhost, pinned to the CPU backend
+  (``JAX_PLATFORMS=cpu`` — a chip belongs to one process, and gloo
+  collectives are CPU-only), each wired to a fresh coordinator through the
   ``REPRO_MESH_*`` environment variables, run a target per process and
   collect its output.  The target is either a ``"pkg.mod:fn"`` spec
   (re-entered via ``python -m repro.mesh.launcher``) or a script path
@@ -134,10 +136,10 @@ def launch(target: str, n_processes: int, *, args: Sequence[str] = (),
            ) -> LaunchResult:
     """Run ``target`` in ``n_processes`` coordinator-connected processes.
 
-    Every child gets the ``REPRO_MESH_*`` variables plus an XLA flag
-    forcing ``local_devices`` host devices (overriding any inherited
-    forced count — the parent's device fan-out must not leak into
-    children).  Raises :class:`LaunchError` if any process exits
+    Every child gets the ``REPRO_MESH_*`` variables, ``JAX_PLATFORMS=cpu``
+    and an XLA flag forcing ``local_devices`` host devices (overriding any
+    inherited forced count — the parent's device fan-out must not leak
+    into children).  Raises :class:`LaunchError` if any process exits
     non-zero or exceeds ``timeout_s``.
     """
     coordinator = pick_coordinator()
@@ -149,6 +151,7 @@ def launch(target: str, n_processes: int, *, args: Sequence[str] = (),
                                   local_devices))
         child_env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={local_devices}")
+        child_env["JAX_PLATFORMS"] = "cpu"
         procs.append(subprocess.Popen(
             _child_cmd(target, args, python), env=child_env,
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True))

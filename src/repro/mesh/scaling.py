@@ -85,11 +85,9 @@ def measure_phase_walls(plan, topo: Topology, bytes_per_val: int = 4,
     exact shape :meth:`PostalParams.calibrated` consumes.
     """
     import jax
-    import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
     from repro.comm.cost import planned_traffic
-    from repro.compat import shard_map
     from repro.mesh.buffers import fetch_mesh_array, input_stager, mesh_for
 
     traffic = planned_traffic(plan, bytes_per_val=bytes_per_val)
@@ -116,12 +114,12 @@ def measure_phase_walls(plan, topo: Topology, bytes_per_val: int = 4,
             return jax.lax.all_to_all(x.reshape(-1), axis, 0, 0,
                                       tiled=True).reshape(x.shape)
 
-        smapped = shard_map(per_device, mesh=mesh, in_specs=(spec,),
-                            out_specs=spec, check_vma=False)
+        smapped = jax.shard_map(per_device, mesh=mesh, in_specs=(spec,),
+                                out_specs=spec, check_vma=False)
         f = jax.jit(smapped)
         host = np.random.default_rng(0).standard_normal(
             (topo.n_nodes, topo.ppn, n_slots * pad)).astype(np.float32)
-        x = jnp.asarray(host) if stage is None else stage(host)
+        x = stage(host)
         fetch_mesh_array(f(x))            # warm-up: trace + compile
         wall = _best_of(lambda: fetch_mesh_array(f(x)), repeats)
         walls.append({

@@ -51,7 +51,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core.topology import Topology
 from repro.moe.plan import (DISPATCH_MODES, choose_dispatch,
                             dispatch_partitions, representative_routing,
@@ -217,7 +216,7 @@ def moe_apply_sharded(p, cfg, x: jax.Array, ep: EPInfo, mesh) -> jax.Array:
     pod = ep.pod_axis
     x_spec = P(pod, None, None) if pod else P(None, None, None)
     e_spec = P(ep.manual_axes if pod else ep.inner_axis)
-    out = compat.shard_map(
+    out = jax.shard_map(
         island, mesh=mesh,
         in_specs=(x_spec, P(), e_spec, e_spec, e_spec),
         out_specs=x_spec,
@@ -232,8 +231,8 @@ def moe_apply_sharded(p, cfg, x: jax.Array, ep: EPInfo, mesh) -> jax.Array:
 
 def _moe_island(cfg, ep, x, router, w_gate, w_up, w_down):
     """Manual-collective MoE over the EP axes; runs per (pod?, model) chip."""
-    n_in = compat.axis_size(ep.inner_axis)
-    n_out = compat.axis_size(ep.pod_axis) if ep.pod_axis else 1
+    n_in = lax.axis_size(ep.inner_axis)
+    n_out = lax.axis_size(ep.pod_axis) if ep.pod_axis else 1
     my_in = lax.axis_index(ep.inner_axis)
     my_out = lax.axis_index(ep.pod_axis) if ep.pod_axis else 0
     n_chips = n_in * n_out

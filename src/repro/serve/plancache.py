@@ -15,7 +15,7 @@ fingerprint per entry:
 * same structure, new values   → **hot swap**: ``op.swap_values`` rebuilds
   the value arrays in place and the compiled program re-runs with zero
   retraces (value arrays are jit arguments — see
-  :data:`repro.core.spmv_jax.VALUE_ARRAY_NAMES`); counted under
+  :func:`repro.core.spmv_jax._plan_arg_fetch`); counted under
   ``stats["hot_swaps"]``;
 * new structure                → miss, a fresh operator compiles.
 

@@ -392,7 +392,6 @@ def spgemm_shardmap(compiled: CompiledSpGemm, mesh, dtype=None,
     import jax.numpy as jnp
     from jax.ops import segment_sum
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.core.spmv_jax import _apply_fault, _msg_checksums, _stack_chk
 
     if dtype is None:
@@ -485,10 +484,10 @@ def spgemm_shardmap(compiled: CompiledSpGemm, mesh, dtype=None,
     dev = compiled.device_arrays(dtype)
     spec = P("node", "proc")
     n_in = 1 + len(names) + (1 if integrity else 0)
-    smapped = shard_map(per_device, mesh=mesh,
-                        in_specs=(spec,) * n_in,
-                        out_specs=(spec, spec) if integrity else spec,
-                        check_vma=False)
+    smapped = jax.shard_map(per_device, mesh=mesh,
+                            in_specs=(spec,) * n_in,
+                            out_specs=(spec, spec) if integrity else spec,
+                            check_vma=False)
     if integrity:
         jitted = jax.jit(lambda b_shards, fault_spec: smapped(
             b_shards, fault_spec, *[dev[k] for k in names]))

@@ -22,14 +22,14 @@ from jax.sharding import PartitionSpec as P
 from repro.core import hier_collectives as hc
 
 PODS, INNER = 2, 4
-from repro.compat import make_mesh, shard_map as compat_shard_map
+from repro.compat import make_mesh
 
 mesh = make_mesh((PODS, INNER), ("pod", "inner"))
 rng = np.random.default_rng(0)
 
 
 def smap(fn, in_specs, out_specs):
-    return jax.jit(compat_shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
 
 
 def test_psum_family():

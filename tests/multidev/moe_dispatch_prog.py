@@ -22,7 +22,7 @@ from repro.models.moe import EPInfo, moe_apply_local, moe_apply_sharded, moe_ini
 
 cfg0 = get_reduced("qwen3-moe-235b-a22b").replace(
     n_experts=8, top_k=4, moe_dff=32, d_model=32, capacity_factor=8.0)
-from repro.compat import make_mesh, set_mesh
+from repro.compat import make_mesh
 
 mesh = make_mesh((2, 4), ("pod", "model"))
 params = moe_init(jax.random.key(0), cfg0, jnp.float32)
@@ -33,7 +33,7 @@ want = np.asarray(moe_apply_local(params, cfg0, x))
 def run_island(cfg):
     ep = EPInfo(inner_axis="model", pod_axis="pod")
     fn = jax.jit(lambda p, xx: moe_apply_sharded(p, cfg, xx, ep, mesh))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = fn.lower(params, x).compile()
         got = np.asarray(fn(params, x))
     # pod_boundary=4: devices 0-3 are pod 0, 4-7 pod 1 on the (2, 4) mesh
@@ -77,7 +77,7 @@ def loss_ref(p, xx):
     return (moe_apply_local(p, cfg0, xx) ** 2).sum()
 
 g_ref = jax.grad(loss_ref)(params, x)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     g_nap = jax.jit(jax.grad(lambda p, xx: loss(p, xx, "nap")))(params, x)
 for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g_nap)):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),

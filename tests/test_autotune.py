@@ -1,7 +1,7 @@
 """Density-driven local-compute format autotuner (single-process).
 
 Covers the cost-model chooser's three regimes (dense blocks -> bsr,
-flat low-density rows -> ell, skewed rows / VMEM-hostile -> coo), the
+flat low-density rows -> ell, skewed rows / too big for HBM -> coo), the
 stats + verdict compile_nap records on CompiledNAP, the packed ELL
 emission's layout invariant, and the cache-key extensions that keep
 ``local_compute`` / tuner switches from returning stale plans.
@@ -11,7 +11,7 @@ import pytest
 
 from repro.core.cost_model import (LOCAL_FORMATS, LocalComputeParams,
                                    TPU_V5E_LOCAL, choose_local_format,
-                                   local_format_times)
+                                   ell_resident_bytes, local_format_times)
 from repro.core.partition import contiguous_partition, make_partition
 from repro.core.spmv import split_all_blocks
 from repro.core.spmv_jax import (clear_compile_cache, compile_nap)
@@ -48,10 +48,35 @@ def test_chooser_prefers_coo_on_skewed_rows():
 
 
 def test_chooser_rejects_ell_when_x_exceeds_vmem():
-    stats = {"rows_pad": 4096, "n_x": 6_000_000, "nnz_pad": 40_000,
+    # the ELL product's device footprint (the padded working copy of
+    # cols + vals alone is 8 GiB at 2^26 rows x kmax 12 -> 16 sublanes)
+    # exceeds the HBM budget
+    stats = {"rows_pad": 2**26, "n_x": 2**26, "nnz_pad": 40_000,
              "bsr_blocks": 5000, "bm": 8, "bn": 128, "ell_kmax": 12}
+    assert ell_resident_bytes(2**26, 12, 2**26, 1) > \
+        TPU_V5E_LOCAL.ell_hbm_budget
     assert local_format_times(stats)["ell"] == float("inf")
     assert choose_local_format(stats) != "ell"
+    # a 6M-entry packed x is no reason to refuse ELL any more: the x
+    # operand stays in HBM, not in a VMEM block
+    stats.update(rows_pad=4096, n_x=6_000_000)
+    assert local_format_times(stats)["ell"] < float("inf")
+
+
+@pytest.mark.parametrize("rows,kmax,n_x,nv,want", [
+    # staged cols/vals keep kmax unpadded; the working copy pads it to 8
+    # sublanes; width 1 packs densely on the lanes, other widths pad to 8
+    (2**20, 25, 2**20 + 256, 1,
+     2 * 4 * 25 * 2**20 + 2 * 4 * 32 * 2**20 + 2 * 4 * (2**20 + 256)
+     + 4 * 2**20),
+    (2**20, 25, 2**20 + 256, 8,
+     2 * 4 * 25 * 2**20 + 2 * 4 * 32 * 2**20 + 2 * 4 * 8 * (2**20 + 256)
+     + 4 * 8 * 2**20),
+    (100, 3, 130, 2,
+     2 * 4 * 3 * 128 + 2 * 4 * 8 * 128 + 2 * 4 * 8 * 256 + 4 * 8 * 128),
+])
+def test_ell_resident_bytes_counts_tile_padding(rows, kmax, n_x, nv, want):
+    assert ell_resident_bytes(rows, kmax, n_x, nv) == want
 
 
 # ---------------------------------------------------------------------------

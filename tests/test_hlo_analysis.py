@@ -6,7 +6,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.hlo_analysis import (_comp_header_name, _crosses_pod,
                                      _first_group, _shape_bytes, analyze_hlo)
 
@@ -22,7 +21,7 @@ def test_scan_trip_count_flops():
     c = jax.jit(g).lower(jax.ShapeDtypeStruct((64, 64), jnp.float32)).compile()
     r = analyze_hlo(c.as_text())
     assert r.dot_flops == 10 * 2 * 64**3
-    xla = compat.cost_analysis(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert xla == pytest.approx(2 * 64**3, rel=0.01)  # one body only
 
 

@@ -55,7 +55,7 @@ def test_checksum_np_matches_jnp_twin():
     rng = np.random.default_rng(0)
     # f64 words need x64 enabled to survive jnp.asarray un-truncated
     # (the f64 SpGEMM wire runs under the same flag)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for dtype in (np.float32, np.float64):
             for shape in [(3, 8), (1, 1), (4, 5)]:
                 buf = rng.standard_normal(shape).astype(dtype)

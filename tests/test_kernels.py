@@ -1,4 +1,9 @@
-"""Pallas kernel sweeps (interpret mode) vs pure-jnp / numpy oracles."""
+"""Kernel sweeps vs pure-jnp / numpy oracles.
+
+Pallas kernels run in interpret mode here (``interpret=True``); the ELL
+product is plain XLA.  ``tests/test_tpu_compile.py`` compiles the same
+kernels for a described TPU.
+"""
 import numpy as np
 import pytest
 
@@ -112,14 +117,14 @@ def test_fused_bsr_packed_bitwise_equals_concat(n_segments, nv, nv_block):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("n_segments", [1, 3])
-@pytest.mark.parametrize("nv,nv_block,rows_block", [
-    (1, 128, 0),    # single RHS, auto row tile
-    (8, 4, 0),      # nv tiled into 2 blocks
-    (12, 8, 8),     # nv not a multiple of nv_block + forced 8-row tiles
-    (128, 64, 16),  # wide multi-RHS
+@pytest.mark.parametrize("nv,seed", [
+    (1, 0),      # single RHS
+    (8, 0),      # multi-RHS on one sublane tile
+    (12, 8),     # nv not a multiple of the sublane tile
+    (128, 16),   # wide multi-RHS
 ])
-def test_ell_packed_kernel_vs_ref(n_segments, nv, nv_block, rows_block):
-    rng = np.random.default_rng(n_segments * 10 + nv + rows_block)
+def test_ell_packed_kernel_vs_ref(n_segments, nv, seed):
+    rng = np.random.default_rng(n_segments * 10 + nv + seed)
     n_rows, kmax = 32, 5
     seg_lens = [16, 8, 24][:n_segments]
     n_x = sum(seg_lens)
@@ -129,17 +134,13 @@ def test_ell_packed_kernel_vs_ref(n_segments, nv, nv_block, rows_block):
     bounds = np.cumsum([0] + seg_lens)
     x = rng.standard_normal((n_x, nv)).astype(np.float32)
     xs = tuple(x[bounds[i]:bounds[i + 1]] for i in range(n_segments))
-    got = ell_spmm_packed(jnp.asarray(cols), jnp.asarray(vals), xs,
-                          nv_block=nv_block, rows_block=rows_block,
-                          interpret=True)
+    got = ell_spmm_packed(jnp.asarray(cols), jnp.asarray(vals), xs)
     want = ell_spmm_packed_ref(jnp.asarray(cols), jnp.asarray(vals), xs)
     assert got.shape == (n_rows, nv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
     # zero-copy multi-segment == materialised single-segment, bit-for-bit
-    got_cat = ell_spmm_packed(jnp.asarray(cols), jnp.asarray(vals), (x,),
-                              nv_block=nv_block, rows_block=rows_block,
-                              interpret=True)
+    got_cat = ell_spmm_packed(jnp.asarray(cols), jnp.asarray(vals), (x,))
     assert np.array_equal(np.asarray(got), np.asarray(got_cat))
 
 
@@ -151,8 +152,7 @@ def test_ell_spmv_matches_csr_matvec():
     want = a.matvec(v)
     np.testing.assert_allclose(ell.matvec(v), want, rtol=1e-6)
     got = ell_spmm_packed(jnp.asarray(ell.cols), jnp.asarray(ell.vals),
-                          (v.reshape(-1, 1).astype(np.float32),),
-                          interpret=True)
+                          (v.reshape(-1, 1).astype(np.float32),))
     np.testing.assert_allclose(np.asarray(got).ravel()[: a.shape[0]], want,
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(ell_spmv_ref(ell, v))[: a.shape[0]],
@@ -165,8 +165,7 @@ def test_ell_padding_slots_are_inert():
     vals = np.array([[2.0, 0.0], [3.0, 1.0]], np.float32)
     x = np.array([[10.0], [100.0]], np.float32)
     got = ell_spmm_packed(jnp.asarray(np.tile(cols, (4, 1))),
-                          jnp.asarray(np.tile(vals, (4, 1))), (x,),
-                          interpret=True)
+                          jnp.asarray(np.tile(vals, (4, 1))), (x,))
     np.testing.assert_allclose(np.asarray(got)[:2].ravel(), [20.0, 310.0])
 
 
