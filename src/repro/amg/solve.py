@@ -21,6 +21,7 @@ import numpy as np
 from repro.amg.hierarchy import Level
 from repro.core.integrity import IntegrityError
 from repro.core.partition import contiguous_partition
+from repro.core.spans import span
 from repro.sparse.csr import CSR
 
 
@@ -247,13 +248,14 @@ def cg_solve(a: CSR, b: np.ndarray, tol: float = 1e-8, maxiter: int = 500,
     is not transient — retrying cannot help).
     """
     mv = spmv or a.matvec
-    x = np.zeros_like(b) if x0 is None else np.array(x0, dtype=b.dtype)
-    r = b - mv(x)
-    z = precond(r) if precond else r
-    p = z.copy()
-    rz = float(r @ z)
-    b_norm = max(float(np.linalg.norm(b)), 1e-30)
-    rel = float(np.linalg.norm(r)) / b_norm
+    with span("repro.cg.init"):
+        x = np.zeros_like(b) if x0 is None else np.array(x0, dtype=b.dtype)
+        r = b - mv(x)
+        z = precond(r) if precond else r
+        p = z.copy()
+        rz = float(r @ z)
+        b_norm = max(float(np.linalg.norm(b)), 1e-30)
+        rel = float(np.linalg.norm(r)) / b_norm
     if rel < tol:     # warm start already converged
         return x, 0, rel
     snap = (x.copy(), r.copy(), p.copy(), rz) if verify_every else None
@@ -261,42 +263,48 @@ def cg_solve(a: CSR, b: np.ndarray, tol: float = 1e-8, maxiter: int = 500,
     failed_at = -1
     it = 1
     while it <= maxiter:
-        ap = mv(p)
-        alpha = rz / max(float(p @ ap), 1e-300)
-        x += alpha * p
-        r -= alpha * ap
-        verified = False
-        if verify_every and it % verify_every == 0:
-            drift = float(np.linalg.norm((b - mv(x)) - r)) / b_norm
-            if drift > verify_tol:
-                if failed_at == it:
-                    raise IntegrityError(
-                        f"CG true-residual replay check failed twice at "
-                        f"iteration {it} (drift {drift:.3e} > "
-                        f"{verify_tol:.1e}): persistent SpMV corruption")
-                failed_at = it
-                x, r, p = snap[0].copy(), snap[1].copy(), snap[2].copy()
-                rz = snap[3]
-                it = snap_it + 1
-                continue
-            verified = True
-            failed_at = -1
+        # one iteration's host work, its applies included, ends before
+        # the callback, so a caller may close its own spans there
+        with span("repro.cg.iteration"):
+            ap = mv(p)
+            alpha = rz / max(float(p @ ap), 1e-300)
+            x += alpha * p
+            r -= alpha * ap
+            verified = False
+            if verify_every and it % verify_every == 0:
+                drift = float(np.linalg.norm((b - mv(x)) - r)) / b_norm
+                if drift > verify_tol:
+                    if failed_at == it:
+                        raise IntegrityError(
+                            f"CG true-residual replay check failed twice "
+                            f"at iteration {it} (drift {drift:.3e} > "
+                            f"{verify_tol:.1e}): persistent SpMV "
+                            f"corruption")
+                    failed_at = it
+                    x, r, p = snap[0].copy(), snap[1].copy(), snap[2].copy()
+                    rz = snap[3]
+                    it = snap_it + 1
+                    continue
+                verified = True
+                failed_at = -1
+            rel = float(np.linalg.norm(r)) / b_norm
+            if not rel < tol:
+                z = precond(r) if precond else r
+                rz_new = float(r @ z)
+                p = z + (rz_new / max(rz, 1e-300)) * p
+                rz = rz_new
+                # snapshot AFTER the direction update: the saved tuple is
+                # the complete loop-top state of iteration it+1, so a
+                # rollback replays the clean trajectory exactly (a
+                # verify-point snapshot would pair the new x/r with the
+                # PREVIOUS search direction)
+                if verified:
+                    snap = (x.copy(), r.copy(), p.copy(), rz)
+                    snap_it = it
         if callback is not None:
             callback(it, x)
-        rel = float(np.linalg.norm(r)) / b_norm
         if rel < tol:
             return x, it, rel
-        z = precond(r) if precond else r
-        rz_new = float(r @ z)
-        p = z + (rz_new / max(rz, 1e-300)) * p
-        rz = rz_new
-        # snapshot AFTER the direction update: the saved tuple is the
-        # complete loop-top state of iteration it+1, so a rollback replays
-        # the clean trajectory exactly (a verify-point snapshot would pair
-        # the new x/r with the PREVIOUS search direction)
-        if verified:
-            snap = (x.copy(), r.copy(), p.copy(), rz)
-            snap_it = it
         it += 1
     return x, maxiter, float(np.linalg.norm(r)) / b_norm
 

@@ -61,6 +61,7 @@ from repro.core.cost_model import (LocalComputeParams, MachineParams,
 from repro.core.integrity import (IntegrityError, IntegrityState, MessageFault,
                                   SimWire)
 from repro.core.partition import RowPartition
+from repro.core.spans import span
 from repro.core.spmv import (simulate_nap_spmv, simulate_nap_spmv_transpose,
                              simulate_standard_spmv,
                              simulate_standard_spmv_transpose)
@@ -184,24 +185,35 @@ class _ShardmapExecutor:
 
     # -- the ONE packed-x path shared by all shard_map executors -----------
     def _apply(self, direction: str, v: np.ndarray, donate: bool) -> np.ndarray:
+        """One apply, under the host span ``repro.apply`` with a child
+        span per step: ``repro.pack``, ``repro.stage`` and
+        ``repro.dispatch`` (in the run callable), ``repro.fetch`` and
+        ``repro.unpack``."""
         from repro.core.spmv_jax import pack_vector, unpack_vector
         from repro.mesh.buffers import fetch_mesh_array
 
-        c = self.compiled
-        if direction == "forward":
-            in_part, in_pad, out_part = self.col_part, c.cols_pad, self.row_part
-            v = check_operand(self.a.shape[1], v)
-        else:
-            in_part, in_pad, out_part = self.row_part, c.rows_pad, self.col_part
-            v = check_operand(self.a.shape[0], v)
-        shards = pack_vector(v, in_part, self.topo, in_pad)
-        if self._integrity is not None:
-            w = self._apply_verified(direction, shards)
-        else:
-            w = self._run(direction)(shards, donate=donate)
-        # fetch_mesh_array == np.asarray single-process; under a
-        # multi-process mesh it gathers the global shards bitwise-exactly
-        return unpack_vector(fetch_mesh_array(w), out_part, self.topo)
+        with span("repro.apply"):
+            c = self.compiled
+            if direction == "forward":
+                in_part, in_pad = self.col_part, c.cols_pad
+                out_part = self.row_part
+                v = check_operand(self.a.shape[1], v)
+            else:
+                in_part, in_pad = self.row_part, c.rows_pad
+                out_part = self.col_part
+                v = check_operand(self.a.shape[0], v)
+            with span("repro.pack"):
+                shards = pack_vector(v, in_part, self.topo, in_pad)
+            if self._integrity is not None:
+                w = self._apply_verified(direction, shards)
+            else:
+                w = self._run(direction)(shards, donate=donate)
+            # fetch_mesh_array == np.asarray single-process; under a
+            # multi-process mesh it gathers the global shards bitwise-exactly
+            with span("repro.fetch"):
+                w = fetch_mesh_array(w)
+            with span("repro.unpack"):
+                return unpack_vector(w, out_part, self.topo)
 
     def _apply_verified(self, direction: str, shards) -> np.ndarray:
         """Integrity path: arm any scripted faults, run the instrumented
@@ -218,8 +230,9 @@ class _ShardmapExecutor:
         st.arm(direction)
         try:
             w, chk, abft = self._run(direction)(shards, donate=False)
-            mism = st.verify(fetch_mesh_array(chk), fetch_mesh_array(abft),
-                             direction, n_terms)
+            with span("repro.verify"):
+                mism = st.verify(fetch_mesh_array(chk),
+                                 fetch_mesh_array(abft), direction, n_terms)
             if not mism:
                 return w
             if st.mode == "detect":
@@ -231,8 +244,9 @@ class _ShardmapExecutor:
             st.counters["retries"] += 1
             st.disarm()
             w, chk, abft = self._run(direction)(shards, donate=False)
-            mism = st.verify(fetch_mesh_array(chk), fetch_mesh_array(abft),
-                             direction, n_terms)
+            with span("repro.verify"):
+                mism = st.verify(fetch_mesh_array(chk),
+                                 fetch_mesh_array(abft), direction, n_terms)
             if mism:
                 raise IntegrityError(
                     f"integrity mismatch persisted through retry on "

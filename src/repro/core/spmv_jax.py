@@ -106,6 +106,7 @@ from repro.core.cost_model import (LOCAL_FORMATS, LocalComputeParams,
                                    TPU_V5E_LOCAL, choose_local_format,
                                    local_format_times)
 from repro.core.partition import RowPartition
+from repro.core.spans import span
 from repro.core.spmv import LocalBlocks, split_all_blocks
 from repro.core.topology import Topology
 from repro.kernels.bsr_spmv.fused import fused_bsr_spmm, fused_bsr_spmm_packed
@@ -1133,7 +1134,9 @@ def _make_run(call4, fmt: str, arg_fetch, stage, fault_fetch=None):
     hot value swap flows into the same executable.  ``run.n_traces()``
     counts program traces: it must not grow across a value swap with
     unchanged shapes.  ``stage`` (:func:`repro.mesh.buffers.input_stager`)
-    places the packed operand on the mesh, one shard per device.
+    places the packed operand on the mesh, one shard per device, under
+    the host span ``repro.stage``; the jitted call and the reshapes
+    around it run under ``repro.dispatch``.
 
     ``fault_fetch()`` (integrity-instrumented programs only) returns the
     armed fault-spec array — same shape/dtype every call, so arming or
@@ -1158,18 +1161,20 @@ def _make_run(call4, fmt: str, arg_fetch, stage, fault_fetch=None):
         return (stage(np.asarray(fault_fetch()), np.int32),) + arg_fetch()
 
     def run(v_shards, donate: bool = False):
-        v_shards = stage(v_shards)
-        donate = bool(donate)
-        if donate and donate not in jits:
-            jits[True] = jax.jit(traced, donate_argnums=(0,))
-        fn = jits[donate]
-        if v_shards.ndim == 4:
-            return fn(v_shards, *args())
-        out = fn(v_shards[..., None], *args())
-        if fault_fetch is None:
-            return out[..., 0]
-        w, chk, abft = out
-        return w[..., 0], chk, abft
+        with span("repro.stage"):
+            v_shards = stage(v_shards)
+        with span("repro.dispatch"):
+            donate = bool(donate)
+            if donate and donate not in jits:
+                jits[True] = jax.jit(traced, donate_argnums=(0,))
+            fn = jits[donate]
+            if v_shards.ndim == 4:
+                return fn(v_shards, *args())
+            out = fn(v_shards[..., None], *args())
+            if fault_fetch is None:
+                return out[..., 0]
+            w, chk, abft = out
+            return w[..., 0], chk, abft
 
     run.local_compute = fmt
     run.integrity = fault_fetch is not None
@@ -1273,95 +1278,105 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
 
         chks = {}
 
-        def exchange(buf, phase, axis):
-            # Sender checksums the CLEAN payload, the scripted fault (if
-            # armed for this device+phase) corrupts it at the pack
-            # boundary, then payload and checksum words travel through
-            # the same collective; the receiver recomputes.  Uninstrumented
-            # (integrity=False) this is literally the bare all_to_all.
-            if not integrity:
-                return jax.lax.all_to_all(buf, axis, 0, 0, tiled=True)
-            sent = _msg_checksums(buf)
-            buf = _apply_fault(buf, fault_spec[ph[phase]])
-            recv = jax.lax.all_to_all(buf, axis, 0, 0, tiled=True)
-            expect = jax.lax.all_to_all(sent[:, None], axis, 0, 0,
-                                        tiled=True)[:, 0]
-            chks[phase] = (expect, _msg_checksums(recv))
-            return recv
+        def exchange(src, send, phase, axis):
+            # One phase under the scope repro.exchange.<phase>: the
+            # packing gather src[send], then the sender checksums the
+            # CLEAN payload, the scripted fault (if armed for this
+            # device+phase) corrupts it at the pack boundary, and payload
+            # and checksum words travel through the same collective; the
+            # receiver recomputes.  Uninstrumented (integrity=False) this
+            # is literally the gather and the bare all_to_all.
+            with jax.named_scope(f"repro.exchange.{phase}"):
+                buf = src[send]
+                if not integrity:
+                    return jax.lax.all_to_all(buf, axis, 0, 0, tiled=True)
+                sent = _msg_checksums(buf)
+                buf = _apply_fault(buf, fault_spec[ph[phase]])
+                recv = jax.lax.all_to_all(buf, axis, 0, 0, tiled=True)
+                expect = jax.lax.all_to_all(sent[:, None], axis, 0, 0,
+                                            tiled=True)[:, 0]
+                chks[phase] = (expect, _msg_checksums(recv))
+                return recv
 
         # Phase A+B (overlap in Alg. 3): intra-node exchanges over "proc".
-        full_out = v_loc[full_send]                       # [ppn, full_pad, nv]
-        full_recv = exchange(full_out, "full", "proc")
-        init_out = v_loc[init_send]
-        init_recv = exchange(init_out, "init", "proc")
+        full_recv = exchange(v_loc, full_send, "full", "proc")
+        init_recv = exchange(v_loc, init_send, "init", "proc")
 
         # Phase C: ONE aggregated inter-node all-to-all over "node".
-        staged = jnp.concatenate([v_loc, init_recv.reshape(-1, nv)])
-        inter_out = staged[inter_gather]                  # [n_nodes, inter_pad, nv]
-        inter_recv = exchange(inter_out, "inter", "node")
+        with jax.named_scope("repro.buffers"):
+            staged = jnp.concatenate([v_loc, init_recv.reshape(-1, nv)])
+        inter_recv = exchange(staged, inter_gather, "inter", "node")
 
         # Phase D: intra-node scatter of received off-node data.
         inter_flat = inter_recv.reshape(-1, nv)
-        final_out = inter_flat[final_send]
-        final_recv = exchange(final_out, "final", "proc")
+        final_recv = exchange(inter_flat, final_send, "final", "proc")
 
-        # Buffers of Algorithm 3's three local_spmv calls.
-        bnode = full_recv.reshape(-1, nv)[bnode_gather]   # [bnode_pad, nv]
         boff_parts = [inter_flat, final_recv.reshape(-1, nv)]
         if ms:
             # Phase E (multistep only): the low-duplication columns ship
             # owner -> requester in one flat exchange, bypassing the
             # aggregation; boff_gather resolves against all three buffers.
-            direct_out = v_loc[direct_send]           # [n_procs, direct_pad, nv]
-            direct_recv = exchange(direct_out, "direct", ("node", "proc"))
+            direct_recv = exchange(v_loc, direct_send, "direct",
+                                   ("node", "proc"))
             boff_parts.append(direct_recv.reshape(-1, nv))
-        boff = jnp.concatenate(boff_parts)[boff_gather]
 
-        if fmt == "bsr":
-            fused_cols, fused_blocks = tail
-            # segment lengths are bn-aligned at compile time: the three
-            # buffers ARE the packed x domain — no pad/concat round-trip.
-            if materialize_x:
-                x_cat = jnp.concatenate([v_loc, bnode, boff]).reshape(-1, bn, nv)
-                w_tiles = fused_bsr_spmm(fused_cols, fused_blocks, x_cat,
-                                         nv_block=nv_block)
+        # Buffers of Algorithm 3's three local_spmv calls.
+        with jax.named_scope("repro.buffers"):
+            bnode = full_recv.reshape(-1, nv)[bnode_gather]   # [bnode_pad, nv]
+            boff = jnp.concatenate(boff_parts)[boff_gather]
+
+        with jax.named_scope("repro.local"):
+            if fmt == "bsr":
+                fused_cols, fused_blocks = tail
+                # segment lengths are bn-aligned at compile time: the three
+                # buffers ARE the packed x domain — no pad/concat round-trip.
+                if materialize_x:
+                    x_cat = jnp.concatenate([v_loc, bnode, boff])
+                    w_tiles = fused_bsr_spmm(fused_cols, fused_blocks,
+                                             x_cat.reshape(-1, bn, nv),
+                                             nv_block=nv_block)
+                else:
+                    xs = tuple(seg.reshape(-1, bn, nv)
+                               for seg in (v_loc, bnode, boff))
+                    w_tiles = fused_bsr_spmm_packed(fused_cols, fused_blocks,
+                                                    xs, nv_block=nv_block)
+                w = w_tiles.reshape(-1, nv)[:rows_pad]
+            elif fmt == "ell":
+                ell_cols, ell_vals = tail
+                w = ell_spmm_packed(ell_cols, ell_vals, (v_loc, bnode, boff))
             else:
-                xs = tuple(seg.reshape(-1, bn, nv)
-                           for seg in (v_loc, bnode, boff))
-                w_tiles = fused_bsr_spmm_packed(fused_cols, fused_blocks, xs,
-                                                nv_block=nv_block)
-            w = w_tiles.reshape(-1, nv)[:rows_pad]
-        elif fmt == "ell":
-            ell_cols, ell_vals = tail
-            w = ell_spmm_packed(ell_cols, ell_vals, (v_loc, bnode, boff))
-        else:
-            (on_proc_rows, on_proc_cols, on_proc_vals,
-             on_node_rows, on_node_cols, on_node_vals,
-             off_node_rows, off_node_cols, off_node_vals) = tail
-            # local_spmv(A_on_process, v) — no communication needed (Alg. 3).
-            w = segment_sum(on_proc_vals[:, None] * v_loc[on_proc_cols],
-                            on_proc_rows, num_segments=rows_pad)
-            # local_spmv(A_on_node, b_l->l)
-            w = w + segment_sum(on_node_vals[:, None] * bnode[on_node_cols],
-                                on_node_rows, num_segments=rows_pad)
-            # local_spmv(A_off_node, b_nl->l)
-            w = w + segment_sum(off_node_vals[:, None] * boff[off_node_cols],
-                                off_node_rows, num_segments=rows_pad)
+                (on_proc_rows, on_proc_cols, on_proc_vals,
+                 on_node_rows, on_node_cols, on_node_vals,
+                 off_node_rows, off_node_cols, off_node_vals) = tail
+                # local_spmv(A_on_process, v) — no communication (Alg. 3).
+                w = segment_sum(on_proc_vals[:, None] * v_loc[on_proc_cols],
+                                on_proc_rows, num_segments=rows_pad)
+                # local_spmv(A_on_node, b_l->l)
+                w = w + segment_sum(
+                    on_node_vals[:, None] * bnode[on_node_cols],
+                    on_node_rows, num_segments=rows_pad)
+                # local_spmv(A_off_node, b_nl->l)
+                w = w + segment_sum(
+                    off_node_vals[:, None] * boff[off_node_cols],
+                    off_node_rows, num_segments=rows_pad)
         if not integrity:
             return w.reshape(1, 1, rows_pad, -1)
-        # Scripted compute-side corruption (what ABFT exists to catch) is
-        # applied to the LOCAL result, after the wire but before the check.
-        w = _apply_fault(w[None], fault_spec[ph["compute"]])[0]
-        # ABFT: sum(y_p) vs c_p · x_packed over the SAME received buffers
-        # the compute consumed, plus the |c_p|·|x| tolerance scale.
-        d = (abft_col[:cols_pad] @ v_loc
-             + abft_col[cols_pad: cols_pad + bnode_pad] @ bnode
-             + abft_col[cols_pad + bnode_pad:] @ boff)
-        s = (abft_abs[:cols_pad] @ jnp.abs(v_loc)
-             + abft_abs[cols_pad: cols_pad + bnode_pad] @ jnp.abs(bnode)
-             + abft_abs[cols_pad + bnode_pad:] @ jnp.abs(boff))
-        abft = jnp.stack([jnp.sum(w, axis=0), d, s])
-        chk = _stack_chk([chks[p] for p in msg_phases], max_slots)
+        with jax.named_scope("repro.abft"):
+            # Scripted compute-side corruption (what ABFT exists to catch)
+            # is applied to the LOCAL result, after the wire but before
+            # the check.
+            w = _apply_fault(w[None], fault_spec[ph["compute"]])[0]
+            # ABFT: sum(y_p) vs c_p · x_packed over the SAME received
+            # buffers the compute consumed, plus the |c_p|·|x| tolerance
+            # scale.
+            d = (abft_col[:cols_pad] @ v_loc
+                 + abft_col[cols_pad: cols_pad + bnode_pad] @ bnode
+                 + abft_col[cols_pad + bnode_pad:] @ boff)
+            s = (abft_abs[:cols_pad] @ jnp.abs(v_loc)
+                 + abft_abs[cols_pad: cols_pad + bnode_pad] @ jnp.abs(bnode)
+                 + abft_abs[cols_pad + bnode_pad:] @ jnp.abs(boff))
+            abft = jnp.stack([jnp.sum(w, axis=0), d, s])
+            chk = _stack_chk([chks[p] for p in msg_phases], max_slots)
         return (w.reshape(1, 1, rows_pad, -1),
                 chk.reshape((1, 1) + chk.shape),
                 abft.reshape((1, 1) + abft.shape))
@@ -1456,59 +1471,70 @@ def nap_transpose_shardmap(compiled: CompiledNAP, mesh: Mesh,
 
         chks = {}
 
-        def exchange(buf, phase, axis):
-            # Reverse-direction twin of the forward builder's exchange():
-            # checksum the clean pre-exchange contribution buffer, apply
-            # the armed fault at the pack boundary, verify post-delivery.
-            if not integrity:
-                return jax.lax.all_to_all(buf, axis, 0, 0, tiled=True)
-            sent = _msg_checksums(buf)
-            buf = _apply_fault(buf, fault_spec[ph[phase]])
-            recv = jax.lax.all_to_all(buf, axis, 0, 0, tiled=True)
-            expect = jax.lax.all_to_all(sent[:, None], axis, 0, 0,
-                                        tiled=True)[:, 0]
-            chks[phase] = (expect, _msg_checksums(recv))
-            return recv
+        def exchange(buf, send, num_segments, phase, axis):
+            # Reverse-direction twin of the forward builder's exchange(),
+            # under the same scope repro.exchange.<phase>: checksum the
+            # clean pre-exchange contribution buffer, apply the armed
+            # fault at the pack boundary, verify post-delivery, then
+            # scatter-add over ``send`` (the adjoint of the forward
+            # packing gather).
+            with jax.named_scope(f"repro.exchange.{phase}"):
+                if not integrity:
+                    recv = jax.lax.all_to_all(buf, axis, 0, 0, tiled=True)
+                else:
+                    sent = _msg_checksums(buf)
+                    buf = _apply_fault(buf, fault_spec[ph[phase]])
+                    recv = jax.lax.all_to_all(buf, axis, 0, 0, tiled=True)
+                    expect = jax.lax.all_to_all(sent[:, None], axis, 0, 0,
+                                                tiled=True)[:, 0]
+                    chks[phase] = (expect, _msg_checksums(recv))
+                return segment_sum(recv.reshape(-1, nv), send.reshape(-1),
+                                   num_segments=num_segments)
 
         # -- transposed local_spmv blocks: rows index u, cols index the
         #    output domain of each block (local x rows / buffer slots).
-        if fmt == "ell":
-            ell_t_cols, ell_t_vals = tail
-            contrib = ell_spmm_packed(ell_t_cols, ell_t_vals, (u_loc,))
-            z = contrib[:cols_pad]
-            c_node = contrib[cols_pad: cols_pad + bnode_pad]
-            c_off = contrib[cols_pad + bnode_pad:]
-        else:
-            (on_proc_rows, on_proc_cols, on_proc_vals,
-             on_node_rows, on_node_cols, on_node_vals,
-             off_node_rows, off_node_cols, off_node_vals) = tail
-            z = segment_sum(on_proc_vals[:, None] * u_loc[on_proc_rows],
-                            on_proc_cols, num_segments=cols_pad)
-            c_node = segment_sum(on_node_vals[:, None] * u_loc[on_node_rows],
-                                 on_node_cols, num_segments=bnode_pad)
-            c_off = segment_sum(off_node_vals[:, None] * u_loc[off_node_rows],
-                                off_node_cols, num_segments=boff_pad)
+        with jax.named_scope("repro.local"):
+            if fmt == "ell":
+                ell_t_cols, ell_t_vals = tail
+                contrib = ell_spmm_packed(ell_t_cols, ell_t_vals, (u_loc,))
+                z = contrib[:cols_pad]
+                c_node = contrib[cols_pad: cols_pad + bnode_pad]
+                c_off = contrib[cols_pad + bnode_pad:]
+            else:
+                (on_proc_rows, on_proc_cols, on_proc_vals,
+                 on_node_rows, on_node_cols, on_node_vals,
+                 off_node_rows, off_node_cols, off_node_vals) = tail
+                z = segment_sum(on_proc_vals[:, None] * u_loc[on_proc_rows],
+                                on_proc_cols, num_segments=cols_pad)
+                c_node = segment_sum(
+                    on_node_vals[:, None] * u_loc[on_node_rows],
+                    on_node_cols, num_segments=bnode_pad)
+                c_off = segment_sum(
+                    off_node_vals[:, None] * u_loc[off_node_rows],
+                    off_node_cols, num_segments=boff_pad)
 
         if integrity:
             # Compute-side fault + transpose ABFT over the packed
             # contribution domain, BEFORE any communication: the sum of
             # every local contribution equals the row-sum vector (A_p 1)
             # dotted with u_loc.
-            packed_c = jnp.concatenate([z, c_node, c_off])
-            packed_c = _apply_fault(packed_c[None],
-                                    fault_spec[ph["compute"]])[0]
-            abft = jnp.stack([jnp.sum(packed_c, axis=0),
-                              abft_row @ u_loc,
-                              abft_abs @ jnp.abs(u_loc)])
-            z = packed_c[:cols_pad]
-            c_node = packed_c[cols_pad: cols_pad + bnode_pad]
-            c_off = packed_c[cols_pad + bnode_pad:]
+            with jax.named_scope("repro.abft"):
+                packed_c = jnp.concatenate([z, c_node, c_off])
+                packed_c = _apply_fault(packed_c[None],
+                                        fault_spec[ph["compute"]])[0]
+                abft = jnp.stack([jnp.sum(packed_c, axis=0),
+                                  abft_row @ u_loc,
+                                  abft_abs @ jnp.abs(u_loc)])
+                z = packed_c[:cols_pad]
+                c_node = packed_c[cols_pad: cols_pad + bnode_pad]
+                c_off = packed_c[cols_pad + bnode_pad:]
 
         # -- reverse of boff = concat(inter | final [| direct])[boff_gather]
-        comb = segment_sum(
-            c_off, boff_gather,
-            num_segments=(nn * inter_pad + ppn * final_pad
-                          + (n_procs * direct_pad if ms else 0)))
+        with jax.named_scope("repro.buffers"):
+            comb = segment_sum(
+                c_off, boff_gather,
+                num_segments=(nn * inter_pad + ppn * final_pad
+                              + (n_procs * direct_pad if ms else 0)))
         inter_c = comb[: nn * inter_pad]
         final_recv_c = comb[nn * inter_pad: nn * inter_pad + ppn * final_pad
                             ].reshape(ppn, final_pad, nv)
@@ -1518,44 +1544,35 @@ def nap_transpose_shardmap(compiled: CompiledNAP, mesh: Mesh,
             #    all_to_all straight back and scatter into the owners' rows.
             direct_recv_c = comb[nn * inter_pad + ppn * final_pad:
                                  ].reshape(n_procs, direct_pad, nv)
-            direct_out_c = exchange(direct_recv_c, "direct", ("node", "proc"))
-            z_direct = segment_sum(direct_out_c.reshape(-1, nv),
-                                   direct_send.reshape(-1),
-                                   num_segments=cols_pad)
+            z_direct = exchange(direct_recv_c, direct_send, cols_pad,
+                                "direct", ("node", "proc"))
 
         # -- reverse phase D: adjoint all_to_all + scatter over final_send
-        final_out_c = exchange(final_recv_c, "final", "proc")
-        inter_c = inter_c + segment_sum(final_out_c.reshape(-1, nv),
-                                        final_send.reshape(-1),
-                                        num_segments=nn * inter_pad)
+        inter_c = inter_c + exchange(final_recv_c, final_send,
+                                     nn * inter_pad, "final", "proc")
 
         # -- reverse phase C: adjoint inter-node all_to_all + scatter over
         #    inter_gather into the staged domain concat(v_loc, init_recv)
-        inter_out_c = exchange(inter_c.reshape(nn, inter_pad, nv),
-                               "inter", "node")
-        staged_c = segment_sum(inter_out_c.reshape(-1, nv),
-                               inter_gather.reshape(-1),
-                               num_segments=cols_pad + ppn * init_pad)
+        staged_c = exchange(inter_c.reshape(nn, inter_pad, nv), inter_gather,
+                            cols_pad + ppn * init_pad, "inter", "node")
         z = z + staged_c[:cols_pad]
 
         # -- reverse phase B: init redistribution back to the owners
         init_recv_c = staged_c[cols_pad:].reshape(ppn, init_pad, nv)
-        init_out_c = exchange(init_recv_c, "init", "proc")
-        z = z + segment_sum(init_out_c.reshape(-1, nv),
-                            init_send.reshape(-1), num_segments=cols_pad)
+        z = z + exchange(init_recv_c, init_send, cols_pad, "init", "proc")
 
         # -- reverse phase A: on-node buffer contributions back to owners
-        full_recv_c = segment_sum(c_node, bnode_gather,
-                                  num_segments=ppn * full_pad)
-        full_out_c = exchange(full_recv_c.reshape(ppn, full_pad, nv),
-                              "full", "proc")
-        z = z + segment_sum(full_out_c.reshape(-1, nv),
-                            full_send.reshape(-1), num_segments=cols_pad)
+        with jax.named_scope("repro.buffers"):
+            full_recv_c = segment_sum(c_node, bnode_gather,
+                                      num_segments=ppn * full_pad)
+        z = z + exchange(full_recv_c.reshape(ppn, full_pad, nv), full_send,
+                         cols_pad, "full", "proc")
         if ms:
             z = z + z_direct
         if not integrity:
             return z.reshape(1, 1, cols_pad, -1)
-        chk = _stack_chk([chks[p] for p in msg_phases], max_slots)
+        with jax.named_scope("repro.abft"):
+            chk = _stack_chk([chks[p] for p in msg_phases], max_slots)
         return (z.reshape(1, 1, cols_pad, -1),
                 chk.reshape((1, 1) + chk.shape),
                 abft.reshape((1, 1) + abft.shape))
@@ -1875,44 +1892,49 @@ def standard_forward_shardmap(compiled: CompiledStandard, mesh: Mesh,
             abft_col, abft_abs = tail[-2:]
             tail = tail[:-2]
         nv = v_loc.shape[-1]
-        out = v_loc[send_idx]                               # [n_procs, pair_pad, nv]
-        if integrity:
-            sent = _msg_checksums(out)
-            out = _apply_fault(out, fault_spec[ph["pair"]])
-        recv = jax.lax.all_to_all(out, ("node", "proc"), 0, 0, tiled=True)
-        if integrity:
-            expect = jax.lax.all_to_all(sent[:, None], ("node", "proc"),
-                                        0, 0, tiled=True)[:, 0]
-            chk_pair = (expect, _msg_checksums(recv))
-        buf = recv.reshape(-1, nv)[buf_gather]              # [buf_pad, nv]
-        if fmt == "bsr":
-            fused_cols, fused_blocks = tail
-            if materialize_x:
-                x_cat = jnp.concatenate([v_loc, buf]).reshape(-1, bn, nv)
-                w_tiles = fused_bsr_spmm(fused_cols, fused_blocks, x_cat,
-                                         nv_block=nv_block)
+        with jax.named_scope("repro.exchange.pair"):
+            out = v_loc[send_idx]                     # [n_procs, pair_pad, nv]
+            if integrity:
+                sent = _msg_checksums(out)
+                out = _apply_fault(out, fault_spec[ph["pair"]])
+            recv = jax.lax.all_to_all(out, ("node", "proc"), 0, 0,
+                                      tiled=True)
+            if integrity:
+                expect = jax.lax.all_to_all(sent[:, None], ("node", "proc"),
+                                            0, 0, tiled=True)[:, 0]
+                chk_pair = (expect, _msg_checksums(recv))
+        with jax.named_scope("repro.buffers"):
+            buf = recv.reshape(-1, nv)[buf_gather]          # [buf_pad, nv]
+        with jax.named_scope("repro.local"):
+            if fmt == "bsr":
+                fused_cols, fused_blocks = tail
+                if materialize_x:
+                    x_cat = jnp.concatenate([v_loc, buf]).reshape(-1, bn, nv)
+                    w_tiles = fused_bsr_spmm(fused_cols, fused_blocks, x_cat,
+                                             nv_block=nv_block)
+                else:
+                    w_tiles = fused_bsr_spmm_packed(
+                        fused_cols, fused_blocks,
+                        (v_loc.reshape(-1, bn, nv), buf.reshape(-1, bn, nv)),
+                        nv_block=nv_block)
+                w = w_tiles.reshape(-1, nv)[:rows_pad]
+            elif fmt == "ell":
+                ell_cols, ell_vals = tail
+                w = ell_spmm_packed(ell_cols, ell_vals, (v_loc, buf))
             else:
-                w_tiles = fused_bsr_spmm_packed(
-                    fused_cols, fused_blocks,
-                    (v_loc.reshape(-1, bn, nv), buf.reshape(-1, bn, nv)),
-                    nv_block=nv_block)
-            w = w_tiles.reshape(-1, nv)[:rows_pad]
-        elif fmt == "ell":
-            ell_cols, ell_vals = tail
-            w = ell_spmm_packed(ell_cols, ell_vals, (v_loc, buf))
-        else:
-            A_rows, A_cols, A_vals = tail
-            full = jnp.concatenate([v_loc, buf])
-            w = segment_sum(A_vals[:, None] * full[A_cols], A_rows,
-                            num_segments=rows_pad)
+                A_rows, A_cols, A_vals = tail
+                full = jnp.concatenate([v_loc, buf])
+                w = segment_sum(A_vals[:, None] * full[A_cols], A_rows,
+                                num_segments=rows_pad)
         if not integrity:
             return w.reshape(1, 1, rows_pad, -1)
-        w = _apply_fault(w[None], fault_spec[ph["compute"]])[0]
-        d = abft_col[:cols_pad] @ v_loc + abft_col[cols_pad:] @ buf
-        s = (abft_abs[:cols_pad] @ jnp.abs(v_loc)
-             + abft_abs[cols_pad:] @ jnp.abs(buf))
-        abft = jnp.stack([jnp.sum(w, axis=0), d, s])
-        chk = _stack_chk([chk_pair], topo.n_procs)
+        with jax.named_scope("repro.abft"):
+            w = _apply_fault(w[None], fault_spec[ph["compute"]])[0]
+            d = abft_col[:cols_pad] @ v_loc + abft_col[cols_pad:] @ buf
+            s = (abft_abs[:cols_pad] @ jnp.abs(v_loc)
+                 + abft_abs[cols_pad:] @ jnp.abs(buf))
+            abft = jnp.stack([jnp.sum(w, axis=0), d, s])
+            chk = _stack_chk([chk_pair], topo.n_procs)
         return (w.reshape(1, 1, rows_pad, -1),
                 chk.reshape((1, 1) + chk.shape),
                 abft.reshape((1, 1) + abft.shape))
@@ -1979,38 +2001,44 @@ def standard_transpose_shardmap(compiled: CompiledStandard, mesh: Mesh,
             tail = tail[:-2]
         nv = u_loc.shape[-1]
         # transposed local SpMV over the packed domain [v_loc | buf]
-        if fmt == "ell":
-            ell_t_cols, ell_t_vals = tail
-            c = ell_spmm_packed(ell_t_cols, ell_t_vals, (u_loc,))
-        else:
-            A_rows, A_cols, A_vals = tail
-            c = segment_sum(A_vals[:, None] * u_loc[A_rows], A_cols,
-                            num_segments=n_x)
+        with jax.named_scope("repro.local"):
+            if fmt == "ell":
+                ell_t_cols, ell_t_vals = tail
+                c = ell_spmm_packed(ell_t_cols, ell_t_vals, (u_loc,))
+            else:
+                A_rows, A_cols, A_vals = tail
+                c = segment_sum(A_vals[:, None] * u_loc[A_rows], A_cols,
+                                num_segments=n_x)
         if integrity:
             # compute fault + transpose ABFT pre-communication (see the
             # NAP transpose builder — same contract)
-            c = _apply_fault(c[None], fault_spec[ph["compute"]])[0]
-            abft = jnp.stack([jnp.sum(c, axis=0), abft_row @ u_loc,
-                              abft_abs @ jnp.abs(u_loc)])
+            with jax.named_scope("repro.abft"):
+                c = _apply_fault(c[None], fault_spec[ph["compute"]])[0]
+                abft = jnp.stack([jnp.sum(c, axis=0), abft_row @ u_loc,
+                                  abft_abs @ jnp.abs(u_loc)])
         z = c[:cols_pad]
         # reverse of buf = recv.reshape(-1)[buf_gather]
-        recv_c = segment_sum(c[cols_pad:], buf_gather,
-                             num_segments=n_procs * pair_pad)
-        out = recv_c.reshape(n_procs, pair_pad, nv)
-        if integrity:
-            sent = _msg_checksums(out)
-            out = _apply_fault(out, fault_spec[ph["pair"]])
-        out_c = jax.lax.all_to_all(out, ("node", "proc"), 0, 0, tiled=True)
-        if integrity:
-            expect = jax.lax.all_to_all(sent[:, None], ("node", "proc"),
-                                        0, 0, tiled=True)[:, 0]
-            chk_pair = (expect, _msg_checksums(out_c))
-        # reverse of out = v_loc[send_idx]
-        z = z + segment_sum(out_c.reshape(-1, nv), send_idx.reshape(-1),
-                            num_segments=cols_pad)
+        with jax.named_scope("repro.buffers"):
+            recv_c = segment_sum(c[cols_pad:], buf_gather,
+                                 num_segments=n_procs * pair_pad)
+        with jax.named_scope("repro.exchange.pair"):
+            out = recv_c.reshape(n_procs, pair_pad, nv)
+            if integrity:
+                sent = _msg_checksums(out)
+                out = _apply_fault(out, fault_spec[ph["pair"]])
+            out_c = jax.lax.all_to_all(out, ("node", "proc"), 0, 0,
+                                       tiled=True)
+            if integrity:
+                expect = jax.lax.all_to_all(sent[:, None], ("node", "proc"),
+                                            0, 0, tiled=True)[:, 0]
+                chk_pair = (expect, _msg_checksums(out_c))
+            # reverse of out = v_loc[send_idx]
+            z = z + segment_sum(out_c.reshape(-1, nv), send_idx.reshape(-1),
+                                num_segments=cols_pad)
         if not integrity:
             return z.reshape(1, 1, cols_pad, -1)
-        chk = _stack_chk([chk_pair], n_procs)
+        with jax.named_scope("repro.abft"):
+            chk = _stack_chk([chk_pair], n_procs)
         return (z.reshape(1, 1, cols_pad, -1),
                 chk.reshape((1, 1) + chk.shape),
                 abft.reshape((1, 1) + abft.shape))
