@@ -344,8 +344,10 @@ def ell_resident_bytes(rows: int, kmax: int, n_x: int, nv: int) -> int:
 
     cols + vals ``[rows, kmax]`` twice: as staged (tile (1, 128), kmax
     unpadded) and as the slot loop's sublane-padded working copy, which
-    the compiler makes by relayout; the x segments and their packed
-    concat (``[n_x, nv]`` each); the ``[rows, nv]`` result.  The slot loop
+    the compiler makes by relayout; the x segments and their concat
+    (``[n_x, nv]`` each, where ``n_x`` is the domain the column ids
+    index: the received buffers for a composed forward program); the
+    ``[rows, nv]`` result.  The slot loop
     keeps no gather temporary (see ``kernels/ell_spmv/kernel.py``).
     ``tests/test_tpu_compile.py`` checks this against the compiler's own
     memory analysis at 2^20 rows.
@@ -368,6 +370,8 @@ def local_format_times(stats: Dict[str, float],
       bsr_blocks padded (bm, bn) tiles incl. cross-rank kmax alignment
       bm, bn     block shape
       ell_kmax   padded ELL slots per row (cross-rank max)
+      ell_n_x    x length the ELL product reads: the received domain
+                 its composed column ids index (optional; n_x if absent)
     """
     bm, bn = int(stats["bm"]), int(stats["bn"])
     rows, n_x = stats["rows_pad"], stats["n_x"]
@@ -379,10 +383,10 @@ def local_format_times(stats: Dict[str, float],
     times = {"bsr": max(bsr_flops / params.mxu_flops,
                         bsr_bytes / params.hbm_bw)}
 
-    kmax = stats["ell_kmax"]
+    kmax, ell_n_x = stats["ell_kmax"], stats.get("ell_n_x", n_x)
     ell_flops = 2.0 * rows * kmax * nv
-    ell_bytes = rows * kmax * 8 + n_x * 4 * nv + out_b
-    if ell_resident_bytes(rows, kmax, n_x, nv) > params.ell_hbm_budget:
+    ell_bytes = rows * kmax * 8 + ell_n_x * 4 * nv + out_b
+    if ell_resident_bytes(rows, kmax, ell_n_x, nv) > params.ell_hbm_budget:
         times["ell"] = float("inf")  # the product does not fit the device
     else:
         times["ell"] = max(ell_flops / params.vpu_flops,

@@ -338,7 +338,8 @@ class NapShardmapExecutor(_ShardmapExecutor):
         out = {f"messages_{k}": v for k, v in
                nap_stats(self.compiled.plan).items()}
         out.update(padded_traffic(self.compiled,
-                                  integrity=self.spec.integrity))
+                                  integrity=self.spec.integrity,
+                                  local_compute=self.spec.local_compute))
         return out
 
     def cost(self, machine: MachineParams) -> Dict[str, float]:
@@ -381,7 +382,8 @@ class MultistepShardmapExecutor(_ShardmapExecutor):
         out = {f"messages_{k}": v for k, v in
                multistep_stats(self.compiled.ms_plan).items()}
         out.update(padded_traffic(self.compiled,
-                                  integrity=self.spec.integrity))
+                                  integrity=self.spec.integrity,
+                                  local_compute=self.spec.local_compute))
         return out
 
     def cost(self, machine: MachineParams) -> Dict[str, float]:
@@ -416,7 +418,8 @@ class StandardShardmapExecutor(_ShardmapExecutor):
         out = {f"messages_{k}": v for k, v in
                standard_stats(self.compiled.plan).items()}
         out.update(padded_traffic(self.compiled,
-                                  integrity=self.spec.integrity))
+                                  integrity=self.spec.integrity,
+                                  local_compute=self.spec.local_compute))
         return out
 
     def cost(self, machine: MachineParams) -> Dict[str, float]:

@@ -70,8 +70,12 @@ lane width bn at compile time, so the fused BSR kernel reads ``v_loc``,
 ``b_on_node`` and ``b_off_node`` as separate refs via slot-indexed
 index_maps — the packed x operand is never materialised as an HBM
 pad/concat (``materialize_x=True`` re-enables the old concat path as a
-bit-for-bit A/B oracle).  The ELL path concatenates the segments once
-per call before its row gathers.
+bit-for-bit A/B oracle).  The ELL path never forms ``b_on_node`` or
+``b_off_node``: its column ids are composed with those buffer gathers
+at plan compile and index the received buffers
+``[v_loc | full | inter | final (| direct)]`` (``[v_loc | recv]`` for
+the standard plan), which it concatenates once per call before its row
+gathers.
 
 Plan compilation is fully vectorised (bulk ``np.searchsorted`` against the
 slot maps :meth:`NAPPlan.recv_slot_map` exposes — no per-element Python
@@ -274,16 +278,44 @@ class CompiledNAP:
         """Element length of the packed [v_loc | b_on_node | b_off_node] x."""
         return self.cols_pad + self.pads["bnode"] + self.pads["boff"]
 
+    @property
+    def recv_x_len(self) -> int:
+        """Element length of the received x domain
+        ``[v_loc | full_recv | inter_recv | final_recv (| direct_recv)]``
+        (each recv buffer flattened) that the forward ELL ids index."""
+        topo, p = self.topo, self.pads
+        n = (self.cols_pad + topo.ppn * p["full"]
+             + topo.n_nodes * p["inter"] + topo.ppn * p["final"])
+        if self.comm == "multistep":
+            n += topo.n_procs * p["direct"]
+        return n
+
+    def recv_domain_map(self) -> np.ndarray:
+        """[n_procs, packed_x_len] int32: where each packed-domain column
+        sits in the received domain (:attr:`recv_x_len`) — v_loc maps to
+        itself, b_on_node through ``bnode_gather`` into ``full_recv`` and
+        b_off_node through ``boff_gather`` into the ``inter | final
+        (| direct)`` block that follows it.  Composing a column id with
+        this map replaces the program's two buffer gathers."""
+        off = self.cols_pad + self.topo.ppn * self.pads["full"]
+        return _recv_domain_map(self.cols_pad, [
+            (self.cols_pad, self.arrays["bnode_gather"]),
+            (off, self.arrays["boff_gather"])])
+
     def ensure_ell(self) -> None:
-        """Materialise the packed ELL arrays (lazily, once) — the
-        block-hostile branch of the adaptive engine."""
+        """Materialise the forward ELL arrays (lazily, once) — the
+        block-hostile branch of the adaptive engine.  The column ids are
+        composed with :meth:`recv_domain_map` at emission, so the ELL
+        program reads the received buffers directly and never gathers
+        ``bnode``/``boff``; slot order and values are those of the
+        packed-domain emission."""
         if "ell_cols" in self.arrays:
             return
         assert self.local_blocks is not None, "compiled plan lost its blocks"
         cols, vals, kmax = _fused_ell_arrays(
             self.local_blocks, self.rows_pad, self.cols_pad,
             self.pads["bnode"], self.pads["boff"])
-        self.arrays["ell_cols"] = cols
+        self.arrays["ell_cols"] = _compose_cols(cols, self.recv_domain_map())
         self.arrays["ell_vals"] = vals
         self.ell_kmax = kmax
 
@@ -432,16 +464,66 @@ def _swap_refresh_lazy(compiled, formats) -> List[str]:
 #: ABFT checksum-vector names — value arrays derived from the matrix
 #: values, so a hot swap refreshes them like the format value arrays.
 _ABFT_NAMES = ("abft_col", "abft_col_abs", "abft_row", "abft_row_abs")
+#: ... and their received-domain twins, which the composed ELL programs read.
+_ABFT_RECV_NAMES = ("abft_col_recv", "abft_col_abs_recv")
 
 
 def _swap_refresh_abft(compiled) -> List[str]:
     """Re-emit the ABFT checksum vectors if they were materialised."""
     if "abft_col" not in compiled.arrays:
         return []
-    for k in _ABFT_NAMES:
+    recv = _ABFT_RECV_NAMES[0] in compiled.arrays
+    names = _ABFT_NAMES + (_ABFT_RECV_NAMES if recv else ())
+    for k in names:
         del compiled.arrays[k]
     compiled.ensure_abft()
-    return list(_ABFT_NAMES)
+    if recv:
+        _ensure_abft_recv(compiled)
+    return list(names)
+
+
+def _recv_domain_map(cols_pad: int,
+                     segments: List[Tuple[int, np.ndarray]]) -> np.ndarray:
+    """Per-rank map from the packed x domain ``[v_loc | seg_0 | seg_1 ...]``
+    to the received domain: v_loc to itself, packed slot ``j`` of segment
+    ``i`` to ``start_i + gather_i[:, j]``, where ``gather_i`` is the
+    program's ``[n_procs, pad_i]`` buffer gather into the received
+    buffers and ``start_i`` where those buffers begin."""
+    n = segments[0][1].shape[0]
+    ident = np.broadcast_to(np.arange(cols_pad, dtype=np.int32), (n, cols_pad))
+    return np.concatenate(
+        [ident] + [(start + g).astype(np.int32) for start, g in segments],
+        axis=1)
+
+
+def _compose_cols(cols: np.ndarray, remap: np.ndarray) -> np.ndarray:
+    """Rewrite stacked ``[n_procs, rows, kmax]`` packed-domain column ids
+    through the per-rank ``remap`` (in place, rank by rank); ``-1``
+    padding slots stay ``-1``."""
+    for r in range(cols.shape[0]):
+        c = cols[r]
+        cols[r] = np.where(c >= 0, remap[r][np.maximum(c, 0)], -1)
+    return cols
+
+
+def _ensure_abft_recv(compiled) -> None:
+    """Materialise (lazily, once) ``abft_col_recv``/``abft_col_abs_recv``:
+    the ABFT column sums ``abft_col``/``abft_col_abs`` moved onto the
+    received domain through ``recv_domain_map``, so a composed ELL
+    program checks ``sum(y_p)`` against the very buffers its product
+    reads.  Padding slots carry zero weight, so the scatter-add is exact
+    wherever the map sends each real column to its own position."""
+    if _ABFT_RECV_NAMES[0] in compiled.arrays:
+        return
+    compiled.ensure_abft()
+    remap = compiled.recv_domain_map()
+    n_x = compiled.recv_x_len
+    for src, dst in zip(("abft_col", "abft_col_abs"), _ABFT_RECV_NAMES):
+        packed = compiled.arrays[src].astype(np.float64)
+        out = np.zeros((packed.shape[0], n_x), np.float64)
+        for r in range(packed.shape[0]):
+            np.add.at(out[r], remap[r], packed[r])
+        compiled.arrays[dst] = out.astype(np.float32)
 
 
 def _swap_finish(compiled, a_new: CSR, changed: List[str]) -> None:
@@ -555,9 +637,12 @@ def _fused_ell_arrays(blocks: List[LocalBlocks], rows_pad: int, cols_pad: int,
 def _format_stats_from_coo(per_rank_rc: List[Tuple[np.ndarray, np.ndarray]],
                            rows_pad: int, n_x: int, nnz_pad_total: int,
                            block_shape: Tuple[int, int],
-                           tuner: LocalComputeParams) -> Dict[str, object]:
+                           tuner: LocalComputeParams,
+                           ell_n_x: Optional[int] = None) -> Dict[str, object]:
     """Layout stats + format decision from per-rank packed-domain COOs,
-    without materialising any format.
+    without materialising any format.  ``ell_n_x`` is the x length the
+    ELL product reads (the received domain its composed ids index;
+    defaults to ``n_x``).
 
     BSR tile counts come from unique (block row, block col) keys over the
     packed column domain; ELL kmax from per-row counts — both pure bulk
@@ -589,7 +674,7 @@ def _format_stats_from_coo(per_rank_rc: List[Tuple[np.ndarray, np.ndarray]],
     stats = {
         "rows_pad": rows_pad, "n_x": n_x, "nnz_pad": nnz_pad_total,
         "bsr_blocks": n_brows * kb_global, "bm": bm, "bn": bn,
-        "ell_kmax": ke_global,
+        "ell_kmax": ke_global, "ell_n_x": n_x if ell_n_x is None else ell_n_x,
     }
     times = local_format_times(stats, tuner)
     for entry in per_rank:
@@ -608,10 +693,12 @@ def _format_stats_from_coo(per_rank_rc: List[Tuple[np.ndarray, np.ndarray]],
 def _autotune_stats(blocks: List[LocalBlocks], rows_pad: int, cols_pad: int,
                     bnode_pad: int, boff_pad: int, nnz_pad_total: int,
                     block_shape: Tuple[int, int],
-                    tuner: LocalComputeParams) -> Dict[str, object]:
+                    tuner: LocalComputeParams,
+                    recv_x_len: int) -> Dict[str, object]:
     """NAP three-segment packed domain -> format stats + decision,
     for BOTH directions: the forward verdict at the top level and the
-    transpose verdict (over the reversed domain) under ``"transpose"``."""
+    transpose verdict (over the reversed domain) under ``"transpose"``.
+    ``recv_x_len`` is the received domain the forward ELL ids index."""
     per_rank_rc = []
     for blk in blocks:
         parts = [blk.on_proc.to_coo(), blk.on_node.to_coo(),
@@ -622,7 +709,8 @@ def _autotune_stats(blocks: List[LocalBlocks], rows_pad: int, cols_pad: int,
         per_rank_rc.append((rows, cols))
     n_x = cols_pad + bnode_pad + boff_pad
     out = _format_stats_from_coo(per_rank_rc, rows_pad, n_x,
-                                 nnz_pad_total, block_shape, tuner)
+                                 nnz_pad_total, block_shape, tuner,
+                                 ell_n_x=recv_x_len)
     out["transpose"] = _transpose_format_stats(
         [(c, r) for r, c in per_rank_rc], n_x, rows_pad, nnz_pad_total,
         block_shape, tuner)
@@ -813,16 +901,17 @@ def compile_nap(a: CSR, part: RowPartition, topo: Topology,
 
     pads = dict(full=full_pad, init=init_pad, inter=inter_pad, final=final_pad,
                 bnode=bnode_pad, boff=boff_pad, **{f"nnz_{k}": v for k, v in nnz_pads.items()})
-    autotune = _autotune_stats(blocks, rows_pad, cols_pad, bnode_pad, boff_pad,
-                               sum(nnz_pads.values()), tuple(block_shape),
-                               tuner)
     compiled = CompiledNAP(topo=topo, part=part, col_part=cpart,
                            rows_pad=rows_pad, cols_pad=cols_pad, pads=pads,
                            arrays=arrays, plan=plan,
                            block_shape=tuple(block_shape),
-                           local_blocks=blocks, autotune=autotune,
+                           local_blocks=blocks,
                            requested_local_compute=local_compute,
                            a_ref=a, _cache_token=key)
+    compiled.autotune = _autotune_stats(
+        blocks, rows_pad, cols_pad, bnode_pad, boff_pad,
+        sum(nnz_pads.values()), tuple(block_shape), tuner,
+        compiled.recv_x_len)
     if key is not None:
         _cache_put(key, compiled)
     return compiled
@@ -987,17 +1076,18 @@ def compile_multistep(a: CSR, part: RowPartition, topo: Topology,
     pads = dict(full=full_pad, init=init_pad, inter=inter_pad, final=final_pad,
                 direct=direct_pad, bnode=bnode_pad, boff=boff_pad,
                 **{f"nnz_{k}": v for k, v in nnz_pads.items()})
-    autotune = _autotune_stats(blocks, rows_pad, cols_pad, bnode_pad, boff_pad,
-                               sum(nnz_pads.values()), tuple(block_shape),
-                               tuner)
     compiled = CompiledNAP(topo=topo, part=part, col_part=cpart,
                            rows_pad=rows_pad, cols_pad=cols_pad, pads=pads,
                            arrays=arrays, plan=nap_plan,
                            block_shape=tuple(block_shape),
-                           local_blocks=blocks, autotune=autotune,
+                           local_blocks=blocks,
                            requested_local_compute=local_compute,
                            comm="multistep", ms_plan=plan,
                            a_ref=a, _cache_token=key)
+    compiled.autotune = _autotune_stats(
+        blocks, rows_pad, cols_pad, bnode_pad, boff_pad,
+        sum(nnz_pads.values()), tuple(block_shape), tuner,
+        compiled.recv_x_len)
     if key is not None:
         _cache_put(key, compiled)
     return compiled
@@ -1119,6 +1209,21 @@ def _stack_chk(pairs: List[Tuple[jnp.ndarray, jnp.ndarray]],
         rows.append(jnp.stack([jnp.pad(expect, (0, pad)),
                                jnp.pad(actual, (0, pad))]))
     return jnp.stack(rows)
+
+
+def _abft_dots(col: jnp.ndarray, col_abs: jnp.ndarray,
+               segs) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """ABFT checksum dot and tolerance scale, ``(c · x, |c| · |x|)``,
+    over an x domain given as consecutive segments, each read where it
+    lies (the concatenation is never formed)."""
+    bounds = np.cumsum([0] + [seg.shape[0] for seg in segs])
+    spans = list(zip(bounds[:-1], bounds[1:], segs))
+
+    def dot(w, f):
+        terms = (w[a: b] @ f(seg) for a, b, seg in spans)
+        return sum(terms, next(terms))
+
+    return dot(col, lambda x: x), dot(col_abs, jnp.abs)
 
 
 def _make_run(call4, fmt: str, arg_fetch, stage, fault_fetch=None):
@@ -1250,7 +1355,6 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
     topo = compiled.topo
     rows_pad = compiled.rows_pad
     bn = compiled.block_shape[1]
-    cols_pad, bnode_pad = compiled.cols_pad, compiled.pads["bnode"]
     # multistep plans add the fifth "direct" exchange; with comm="nap"
     # every ms branch below is dead at trace time and the emitted program
     # is bit-for-bit the single-step one.
@@ -1258,8 +1362,15 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
     ph = phase_index("multistep" if ms else "nap")
     msg_phases = MULTISTEP_MESSAGE_PHASES if ms else NAP_MESSAGE_PHASES
     max_slots = topo.n_procs if ms else max(topo.ppn, topo.n_nodes)
+    # The ELL ids are composed with the buffer gathers at plan compile
+    # (CompiledNAP.recv_domain_map), so the ELL program reads the
+    # received buffers as they arrive; BSR (whose zero-copy x needs
+    # bn-aligned buffers) and COO gather bnode/boff here.
+    composed = fmt == "ell"
     if integrity:
         compiled.ensure_abft()
+        if composed:
+            _ensure_abft_recv(compiled)
 
     def per_device(v_loc, *args):
         squeeze = lambda x: x.reshape(x.shape[2:])
@@ -1267,10 +1378,13 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
             fault_spec = squeeze(args[0])                   # [n_phases, 4]
             args = args[1:]
         v_loc = squeeze(v_loc)                              # [rows_pad, nv]
-        (full_send, init_send, final_send, inter_gather, bnode_gather,
-         boff_gather) = map(squeeze, args[:6])
-        direct_send = squeeze(args[6]) if ms else None
-        tail = tuple(map(squeeze, args[7 if ms else 6:]))
+        full_send, init_send, final_send, inter_gather = map(squeeze, args[:4])
+        args = args[4:]
+        if not composed:
+            bnode_gather, boff_gather = map(squeeze, args[:2])
+            args = args[2:]
+        direct_send = squeeze(args[0]) if ms else None
+        tail = tuple(map(squeeze, args[1 if ms else 0:]))
         if integrity:
             abft_col, abft_abs = tail[-2:]
             tail = tail[:-2]
@@ -1320,10 +1434,15 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
                                    ("node", "proc"))
             boff_parts.append(direct_recv.reshape(-1, nv))
 
-        # Buffers of Algorithm 3's three local_spmv calls.
-        with jax.named_scope("repro.buffers"):
-            bnode = full_recv.reshape(-1, nv)[bnode_gather]   # [bnode_pad, nv]
-            boff = jnp.concatenate(boff_parts)[boff_gather]
+        if composed:
+            # the received domain [v_loc | full | inter | final (| direct)]
+            x_segs = (v_loc, full_recv.reshape(-1, nv)) + tuple(boff_parts)
+        else:
+            # Buffers of Algorithm 3's three local_spmv calls.
+            with jax.named_scope("repro.buffers"):
+                bnode = full_recv.reshape(-1, nv)[bnode_gather]  # [bnode_pad, nv]
+                boff = jnp.concatenate(boff_parts)[boff_gather]
+            x_segs = (v_loc, bnode, boff)
 
         with jax.named_scope("repro.local"):
             if fmt == "bsr":
@@ -1343,7 +1462,7 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
                 w = w_tiles.reshape(-1, nv)[:rows_pad]
             elif fmt == "ell":
                 ell_cols, ell_vals = tail
-                w = ell_spmm_packed(ell_cols, ell_vals, (v_loc, bnode, boff))
+                w = ell_spmm_packed(ell_cols, ell_vals, x_segs)
             else:
                 (on_proc_rows, on_proc_cols, on_proc_vals,
                  on_node_rows, on_node_cols, on_node_vals,
@@ -1366,25 +1485,21 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
             # is applied to the LOCAL result, after the wire but before
             # the check.
             w = _apply_fault(w[None], fault_spec[ph["compute"]])[0]
-            # ABFT: sum(y_p) vs c_p · x_packed over the SAME received
-            # buffers the compute consumed, plus the |c_p|·|x| tolerance
-            # scale.
-            d = (abft_col[:cols_pad] @ v_loc
-                 + abft_col[cols_pad: cols_pad + bnode_pad] @ bnode
-                 + abft_col[cols_pad + bnode_pad:] @ boff)
-            s = (abft_abs[:cols_pad] @ jnp.abs(v_loc)
-                 + abft_abs[cols_pad: cols_pad + bnode_pad] @ jnp.abs(bnode)
-                 + abft_abs[cols_pad + bnode_pad:] @ jnp.abs(boff))
+            # ABFT: sum(y_p) vs c_p · x over the SAME buffers the compute
+            # consumed (c_p on the received domain when composed), plus
+            # the |c_p|·|x| tolerance scale.
+            d, s = _abft_dots(abft_col, abft_abs, x_segs)
             abft = jnp.stack([jnp.sum(w, axis=0), d, s])
             chk = _stack_chk([chks[p] for p in msg_phases], max_slots)
         return (w.reshape(1, 1, rows_pad, -1),
                 chk.reshape((1, 1) + chk.shape),
                 abft.reshape((1, 1) + abft.shape))
 
-    names = ["full_send", "init_send", "final_send", "inter_gather",
-             "bnode_gather", "boff_gather"]
+    names = ["full_send", "init_send", "final_send", "inter_gather"]
+    if not composed:
+        names += ["bnode_gather", "boff_gather"]
     if ms:
-        names.insert(6, "direct_send")
+        names.append("direct_send")
     if fmt == "bsr":
         names += ["fused_cols", "fused_blocks"]
     elif fmt == "ell":
@@ -1394,7 +1509,8 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
                   "on_node_rows", "on_node_cols", "on_node_vals",
                   "off_node_rows", "off_node_cols", "off_node_vals"]
     if integrity:
-        names += ["abft_col", "abft_col_abs"]
+        names += list(_ABFT_RECV_NAMES if composed
+                      else ("abft_col", "abft_col_abs"))
     spec = P("node", "proc")
     n_in = 1 + len(names) + (1 if integrity else 0)
     smapped = jax.shard_map(per_device, mesh=mesh,
@@ -1656,6 +1772,20 @@ class CompiledStandard:
         return self.n_x
 
     @property
+    def recv_x_len(self) -> int:
+        """Element length of the received domain ``[v_loc | recv]`` (the
+        flat ``[n_procs, pair_pad]`` recv buffer) the forward ELL ids
+        index."""
+        return self.cols_pad + self.topo.n_procs * self.pair_pad
+
+    def recv_domain_map(self) -> np.ndarray:
+        """[n_procs, n_x] int32: each packed column's position in the
+        received domain — v_loc to itself, the buffer through
+        ``buf_gather`` (see :meth:`CompiledNAP.recv_domain_map`)."""
+        return _recv_domain_map(self.cols_pad, [
+            (self.cols_pad, self.arrays["buf_gather"])])
+
+    @property
     def chosen_local_compute(self) -> str:
         return str(self.autotune.get("chosen", "coo"))
 
@@ -1682,13 +1812,15 @@ class CompiledStandard:
             self.nnz_pad, fill=0.0)
 
     def ensure_ell(self) -> None:
+        """Forward ELL arrays, column ids composed with
+        :meth:`recv_domain_map` (no ``buf_gather`` in the ELL program)."""
         if "ell_cols" in self.arrays:
             return
         e_cols, e_vals, _ = stack_ell([
             ELL.from_coo(rr, cc, vv, (self.rows_pad, self.n_x),
                          n_rows_pad=self.rows_pad)
             for rr, cc, vv in self.per_rank_coo])
-        self.arrays["ell_cols"] = e_cols
+        self.arrays["ell_cols"] = _compose_cols(e_cols, self.recv_domain_map())
         self.arrays["ell_vals"] = e_vals
 
     def ensure_ell_t(self) -> None:
@@ -1839,19 +1971,19 @@ def compile_standard(a: CSR, part: RowPartition, topo: Topology,
                              cols_pad + blk.on_node_cols.size + cc2])
         vv = np.concatenate([vv0, vv1, vv2])
         per_rank_coo.append((rr, cc, vv))
-    autotune = _format_stats_from_coo(
-        [(rr, cc) for rr, cc, _ in per_rank_coo], rows_pad, n_x,
-        nnz_pad, (bm, bn), tuner)
-    autotune["transpose"] = _transpose_format_stats(
-        [(cc, rr) for rr, cc, _ in per_rank_coo], n_x, rows_pad,
-        nnz_pad, (bm, bn), tuner)
     compiled = CompiledStandard(
         topo=topo, part=part, col_part=cpart, rows_pad=rows_pad,
         cols_pad=cols_pad, buf_pad=buf_pad,
         pair_pad=pair_pad, nnz_pad=nnz_pad, block_shape=tuple(block_shape),
         arrays=dict(send_idx=send_idx, buf_gather=buf_gather),
-        per_rank_coo=per_rank_coo, plan=plan, autotune=autotune,
+        per_rank_coo=per_rank_coo, plan=plan,
         requested_local_compute=local_compute, a_ref=a, _cache_token=key)
+    compiled.autotune = _format_stats_from_coo(
+        [(rr, cc) for rr, cc, _ in per_rank_coo], rows_pad, n_x,
+        nnz_pad, (bm, bn), tuner, ell_n_x=compiled.recv_x_len)
+    compiled.autotune["transpose"] = _transpose_format_stats(
+        [(cc, rr) for rr, cc, _ in per_rank_coo], n_x, rows_pad,
+        nnz_pad, (bm, bn), tuner)
     if key is not None:
         _cache_put(key, compiled)
     return compiled
@@ -1875,19 +2007,27 @@ def standard_forward_shardmap(compiled: CompiledStandard, mesh: Mesh,
     {"coo": compiled.ensure_coo, "ell": compiled.ensure_ell,
      "bsr": compiled.ensure_fused}[fmt]()
     topo = compiled.topo
-    rows_pad, cols_pad = compiled.rows_pad, compiled.cols_pad
+    rows_pad = compiled.rows_pad
     bn = compiled.block_shape[1]
     ph = phase_index("standard")
+    # ELL ids index [v_loc | recv] directly (see CompiledStandard.ensure_ell)
+    composed = fmt == "ell"
     if integrity:
         compiled.ensure_abft()
+        if composed:
+            _ensure_abft_recv(compiled)
 
     def per_device(v_loc, *args):
         squeeze = lambda x: x.reshape(x.shape[2:])
         if integrity:
             fault_spec = squeeze(args[0])                   # [n_phases, 4]
             args = args[1:]
-        v_loc, send_idx, buf_gather = map(squeeze, (v_loc,) + args[:2])
-        tail = tuple(map(squeeze, args[2:]))
+        v_loc, send_idx = map(squeeze, (v_loc, args[0]))
+        args = args[1:]
+        if not composed:
+            buf_gather = squeeze(args[0])
+            args = args[1:]
+        tail = tuple(map(squeeze, args))
         if integrity:
             abft_col, abft_abs = tail[-2:]
             tail = tail[:-2]
@@ -1903,8 +2043,11 @@ def standard_forward_shardmap(compiled: CompiledStandard, mesh: Mesh,
                 expect = jax.lax.all_to_all(sent[:, None], ("node", "proc"),
                                             0, 0, tiled=True)[:, 0]
                 chk_pair = (expect, _msg_checksums(recv))
-        with jax.named_scope("repro.buffers"):
-            buf = recv.reshape(-1, nv)[buf_gather]          # [buf_pad, nv]
+        if composed:
+            buf = recv.reshape(-1, nv)
+        else:
+            with jax.named_scope("repro.buffers"):
+                buf = recv.reshape(-1, nv)[buf_gather]      # [buf_pad, nv]
         with jax.named_scope("repro.local"):
             if fmt == "bsr":
                 fused_cols, fused_blocks = tail
@@ -1930,21 +2073,20 @@ def standard_forward_shardmap(compiled: CompiledStandard, mesh: Mesh,
             return w.reshape(1, 1, rows_pad, -1)
         with jax.named_scope("repro.abft"):
             w = _apply_fault(w[None], fault_spec[ph["compute"]])[0]
-            d = abft_col[:cols_pad] @ v_loc + abft_col[cols_pad:] @ buf
-            s = (abft_abs[:cols_pad] @ jnp.abs(v_loc)
-                 + abft_abs[cols_pad:] @ jnp.abs(buf))
+            d, s = _abft_dots(abft_col, abft_abs, (v_loc, buf))
             abft = jnp.stack([jnp.sum(w, axis=0), d, s])
             chk = _stack_chk([chk_pair], topo.n_procs)
         return (w.reshape(1, 1, rows_pad, -1),
                 chk.reshape((1, 1) + chk.shape),
                 abft.reshape((1, 1) + abft.shape))
 
-    names = ["send_idx", "buf_gather"]
+    names = ["send_idx"] if composed else ["send_idx", "buf_gather"]
     names += {"bsr": ["fused_cols", "fused_blocks"],
               "ell": ["ell_cols", "ell_vals"],
               "coo": ["A_rows", "A_cols", "A_vals"]}[fmt]
     if integrity:
-        names += ["abft_col", "abft_col_abs"]
+        names += list(_ABFT_RECV_NAMES if composed
+                      else ("abft_col", "abft_col_abs"))
     spec = P("node", "proc")
     n_in = 1 + len(names) + (1 if integrity else 0)
     smapped = jax.shard_map(per_device, mesh=mesh,
@@ -2095,7 +2237,8 @@ def _phase_lists(compiled) -> Dict[str, Tuple[int, List, List]]:
     return out
 
 
-def padded_traffic(compiled, integrity: str = "off") -> Dict[str, object]:
+def padded_traffic(compiled, integrity: str = "off",
+                   local_compute: str = "auto") -> Dict[str, object]:
     """Padded (SPMD-actual) vs effective bytes per phase, float32 payloads.
 
     Padded bytes are what the static all-to-alls actually move (every rank
@@ -2115,6 +2258,13 @@ def padded_traffic(compiled, integrity: str = "off") -> Dict[str, object]:
       side-channel all_to_all the instrumented program runs per phase
       (one u32 per slot per rank), and ``checksum_total`` sums them —
       the wires the integrity mode adds are not free.
+
+    ``buffer_gather_elems`` counts the buffer positions (each ``nv``
+    wide) that the forward program's buffer step gathers per apply on
+    the bottleneck rank — ``bnode`` + ``boff`` (NAP, multistep) or
+    ``buf`` (standard), padding included, as the SPMD gather runs them.
+    It is 0 when ``local_compute`` resolves to ``ell``, whose column ids
+    are composed with those gathers at plan compile.
     """
     topo = compiled.topo
     pads = getattr(compiled, "pads", None)
@@ -2148,5 +2298,11 @@ def padded_traffic(compiled, integrity: str = "off") -> Dict[str, object]:
     if integrity != "off":
         out["checksum_total"] = checksum_total
         transpose["checksum_total"] = checksum_total
+    if compiled.resolve_local_compute(local_compute) == "ell":
+        out["buffer_gather_elems"] = 0
+    elif pads is not None:
+        out["buffer_gather_elems"] = pads["bnode"] + pads["boff"]
+    else:
+        out["buffer_gather_elems"] = compiled.buf_pad
     out["transpose"] = transpose
     return out

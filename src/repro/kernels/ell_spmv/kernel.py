@@ -16,11 +16,15 @@ any size and never materialises the [n_rows, kmax, nv] gather that the
 one-shot ``(vals[..., None] * x[cols]).sum(1)`` spelling would (1 GiB at
 2^20 rows x kmax 25, nv 1).
 
-The NAPSpMV's buffers (``v_loc``, on-node recv, off-node recv) arrive as
-separate segments; column ids live in the packed domain
-``[0, len(v) | len(v)+len(bnode) | ...)`` and the segments are
-concatenated once per call (one [n_x, nv] copy).  What the product holds
-in device memory is counted by
+The forward shard program passes ``v_loc`` and the exchange's received
+buffers as they arrive (``full``, ``inter``, ``final`` and, under
+multistep, ``direct``, each flattened; ``recv`` for the standard plan).
+The plan composes the column ids with Algorithm 3's buffer gathers, so
+they index the concatenation of those segments
+``[0, len(v) | len(v)+len(full) | ...)`` and no ``bnode``/``boff``
+buffer is formed; the segments are concatenated once per call (one
+[n_x, nv] copy).  The transpose passes ``u_loc`` alone.  What the
+product holds in device memory is counted by
 :func:`repro.core.cost_model.ell_resident_bytes`, which the format
 autotuner uses to refuse ELL when it does not fit.
 
@@ -40,8 +44,9 @@ def ell_spmm_packed(cols: jax.Array, vals: jax.Array, xs) -> jax.Array:
 
     cols: [n_rows, kmax] int32 column ids in the packed x domain (-1 = pad)
     vals: [n_rows, kmax] float32 (0 on padding slots)
-    xs:   tuple of [len_i, nv] segments; the packed domain is their
-          concatenation in order (e.g. (v_loc, b_on_node, b_off_node))
+    xs:   tuple of [len_i, nv] segments; the column domain is their
+          concatenation in order (e.g. (v_loc, full_recv, inter_recv,
+          final_recv) in the forward NAP program)
     returns [n_rows, nv] float32
     """
     xs = tuple(jnp.asarray(x, jnp.float32) for x in xs)

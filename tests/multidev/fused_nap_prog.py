@@ -18,7 +18,8 @@ A block-hostile low-density problem additionally asserts the format
 autotuner rejects BSR, and a jaxpr scan asserts the packed x operand is
 NOT materialised as an HBM concat by the zero-copy BSR executor (while
 its materialize_x oracle path IS — a differential check, immune to shape
-coincidences); the XLA ELL product concatenates it exactly once.
+coincidences); the XLA ELL product, whose column ids index the received
+buffers, concatenates those exactly once and never the packed x.
 """
 import os
 
@@ -157,21 +158,27 @@ def check_block_hostile_autotune():
     want = dense_oracle(a, v)
     n_x = compiled.packed_x_len
 
+    n_recv = compiled.recv_x_len
+    assert n_recv != n_x
     for fmt in ("auto", "ell", "bsr"):
         run = nap_forward_shardmap(compiled, mesh, local_compute=fmt)
         got = unpack_vector(np.asarray(run(shards)), part, topo)
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
-        # the zero-copy BSR kernel must NOT materialise the packed x
-        # concat; the XLA ELL product concatenates the segments once
+        # neither the zero-copy BSR kernel nor the composed ELL product
+        # materialises the packed x concat; the XLA ELL product
+        # concatenates the received domain once instead
         n_cat = _count_packed_x_concats(run.run4, shards, n_x, nv)
-        assert n_cat == (0 if run.local_compute == "bsr" else 1), (fmt, n_cat)
+        assert n_cat == 0, (fmt, n_cat)
+        n_recv_cat = _count_packed_x_concats(run.run4, shards, n_recv, nv)
+        assert n_recv_cat == (run.local_compute == "ell"), (fmt, n_recv_cat)
     # ...while the BSR materialize_x oracle path DOES (differential: proves
     # the scan actually sees the concat when it exists)
     run_mat = nap_forward_shardmap(compiled, mesh, local_compute="bsr",
                                 materialize_x=True)
     assert _count_packed_x_concats(run_mat.run4, shards, n_x, nv) >= 1
     print(f"block-hostile autotune ok: chose {compiled.chosen_local_compute}, "
-          f"no packed-x concat in the zero-copy BSR jaxpr", flush=True)
+          f"no packed-x concat in the zero-copy BSR or composed ELL jaxpr",
+          flush=True)
 
 
 def main():

@@ -136,19 +136,23 @@ def test_dense_block_diagonal_selects_bsr():
 
 @pytest.mark.parametrize("nn,ppn", TOPOS)
 def test_packed_ell_layout_equals_local_blocks(nn, ppn):
-    """The ELL arrays, viewed densely per rank, reproduce the three column
-    blocks at their packed-domain offsets (v_loc | on-node | off-node)."""
+    """The ELL arrays, viewed densely per rank over the received domain
+    and read back through the plan's packed -> received column map,
+    reproduce the three column blocks at their packed-domain offsets
+    (v_loc | on-node | off-node)."""
     topo = Topology(n_nodes=nn, ppn=ppn)
     a = random_fixed_nnz(60, 6, seed=11)
     part = make_partition("contiguous", 60, topo.n_procs)
     compiled = compile_nap(a, part, topo, block_shape=(8, 16), cache=False)
     compiled.ensure_ell()
     rows_pad, pads = compiled.rows_pad, compiled.pads
+    remap = compiled.recv_domain_map()
+    assert remap.shape == (topo.n_procs, compiled.packed_x_len)
     for r, blk in enumerate(split_all_blocks(a, part, topo)):
         ell = ELL(cols=compiled.arrays["ell_cols"][r],
                   vals=compiled.arrays["ell_vals"][r],
-                  shape=(rows_pad, compiled.packed_x_len))
-        dense = ell.to_dense()
+                  shape=(rows_pad, compiled.recv_x_len))
+        dense = ell.to_dense()[:, remap[r]]
         nr = blk.rows.size
         np.testing.assert_allclose(dense[:nr, :nr], blk.on_proc.to_dense(),
                                    atol=1e-6)
