@@ -309,6 +309,36 @@ def cg_solve(a: CSR, b: np.ndarray, tol: float = 1e-8, maxiter: int = 500,
     return x, maxiter, float(np.linalg.norm(r)) / b_norm
 
 
+def pagerank(op, d: float = 0.85, iters: int = 20,
+             dangling: Optional[np.ndarray] = None) -> np.ndarray:
+    """PageRank as LDBC Graphalytics defines it, ``iters`` iterations
+    from ``1 / N``, over an operator of ``B = I - d * A^T * D_out^-1``
+    (:func:`repro.sparse.generators.pagerank_matrix`).
+
+    One iteration is one apply of B, in Jacobi form::
+
+        x <- x + (c - B x),   c = (1 - d) / N + d / N * sum(x[dangling])
+
+    which is the power iteration ``x <- d A^T D^-1 x + c``: the teleport
+    share and the mass of the dangling vertices (no out-edges), spread
+    uniformly.  ``dangling`` is a boolean mask; without it the dangling
+    vertices are read from B's column sums, one ``op.T`` apply (a
+    dangling column holds only its diagonal 1, every other sums to
+    ``1 - d``).  Each iteration runs under the host span
+    ``repro.pagerank.iteration``.
+    """
+    n = op.shape[1]
+    if dangling is None:
+        colsum = np.asarray(op.T @ np.ones(n, np.float32), np.float64)
+        dangling = colsum > 1.0 - d / 2
+    x = np.full(n, 1.0 / n)
+    for _ in range(iters):
+        with span("repro.pagerank.iteration"):
+            c = (1.0 - d) / n + d / n * float(x[dangling].sum())
+            x = x + (c - np.asarray(op @ x.astype(np.float32), np.float64))
+    return x
+
+
 def _safe_div(num: float, den: float) -> float:
     """num/den with a sign-preserving breakdown guard (BiCG denominators
     are legitimately negative — clamping with max() would flip search

@@ -18,7 +18,7 @@ choice and the §Roofline collective term.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 from repro.core.comm_graph import Message, NAPPlan, StandardPlan
 
@@ -309,6 +309,9 @@ class LocalComputeParams:
     bytes the ELL product may hold (:func:`ell_resident_bytes`): half of
     a v5e chip's 16 GiB, leaving the rest to the exchange buffers, the
     operand and the other direction's format arrays.
+    ``ell_combine_slots`` prices one chunk partial of a row-split ELL
+    (its sorted ``segment_sum`` into the result) in slots of the slot
+    loop.
     """
 
     name: str = "tpu_v5e_local"
@@ -317,6 +320,11 @@ class LocalComputeParams:
     scatter_flops: float = 4.0e9  # segment_sum / scalar scatter-add rate
     hbm_bw: float = 8.1e11        # HBM bandwidth
     ell_hbm_budget: int = 8 * 2**30  # max device bytes of one ELL product
+    # one combined partial, in slots: a sorted segment_sum partial took
+    # 8.9 to 9.4 ns and a slot 6.8 to 7.3 ns on a TPU v5e (ratios 1.23 to
+    # 1.39 over seven shapes: the scale-20 Graph500 graph at K = 4 to 32,
+    # the transposed 2^20 x 25 random matrix at K = 8 to 32; PERF.md)
+    ell_combine_slots: float = 1.3
 
     def signature(self) -> tuple:
         return dataclasses.astuple(self)
@@ -338,7 +346,8 @@ def _tiled_bytes(n: int, width: int) -> int:
     return 4 * sub * (-(-n // 128) * 128)
 
 
-def ell_resident_bytes(rows: int, kmax: int, n_x: int, nv: int) -> int:
+def ell_resident_bytes(rows: int, kmax: int, n_x: int, nv: int,
+                       chunks: int = 0) -> int:
     """Device bytes the ELL product holds inside the shard_map program,
     tile padding included.
 
@@ -347,14 +356,55 @@ def ell_resident_bytes(rows: int, kmax: int, n_x: int, nv: int) -> int:
     the compiler makes by relayout; the x segments and their concat
     (``[n_x, nv]`` each, where ``n_x`` is the domain the column ids
     index: the received buffers for a composed forward program); the
-    ``[rows, nv]`` result.  The slot loop
-    keeps no gather temporary (see ``kernels/ell_spmv/kernel.py``).
-    ``tests/test_tpu_compile.py`` checks this against the compiler's own
-    memory analysis at 2^20 rows.
+    ``[rows, nv]`` result.  A row-split layout of ``chunks`` chunk rows
+    (``kmax`` is then the split width) holds cols + vals over its chunk
+    rows instead, and besides them the ``[chunks, nv]`` partials and the
+    int32 ``chunk_row`` map.  The slot loop keeps no gather temporary
+    (see ``kernels/ell_spmv/kernel.py``).  ``tests/test_tpu_compile.py``
+    checks this against the compiler's own memory analysis at 2^20 rows.
     """
-    staged = 4 * kmax * (-(-rows // 128) * 128)
-    return (2 * staged + 2 * _tiled_bytes(rows, kmax)
+    slot_rows = chunks or rows
+    staged = 4 * kmax * (-(-slot_rows // 128) * 128)
+    held = (2 * staged + 2 * _tiled_bytes(slot_rows, kmax)
             + 2 * _tiled_bytes(n_x, nv) + _tiled_bytes(rows, nv))
+    if chunks:
+        held += _tiled_bytes(chunks, nv) + 4 * (-(-chunks // 128) * 128)
+    return held
+
+
+def _ell_time(rows: int, width: int, chunks: int, n_x: int, nv: int,
+              params: LocalComputeParams) -> float:
+    """Modeled seconds of one ELL layout: ``rows`` rows of ``width``
+    slots (``chunks == 0``), or ``chunks`` chunk rows of ``width`` slots
+    and their combine."""
+    if ell_resident_bytes(rows, width, n_x, nv, chunks) \
+            > params.ell_hbm_budget:
+        return float("inf")  # the product does not fit the device
+    slots = (chunks or rows) * width + params.ell_combine_slots * chunks
+    return max(2.0 * slots * nv / params.vpu_flops,
+               (slots * 8 + n_x * 4 * nv + 4 * rows * nv) / params.hbm_bw)
+
+
+def choose_ell_split(stats: Dict[str, object],
+                     params: LocalComputeParams = TPU_V5E_LOCAL,
+                     nv: int = 1) -> Tuple[int, int, float]:
+    """``(width, chunk rows, modeled seconds)`` of the cheapest ELL
+    layout: the unsplit one (``ell_kmax`` slots over ``rows_pad`` rows,
+    no combine) or a split ``[K, chunk rows]`` of ``stats["ell_splits"]``
+    (the powers of two below ``ell_kmax``).  Ties keep the unsplit
+    layout; where none fits the device, the one that holds least wins."""
+    rows, kmax = int(stats["rows_pad"]), int(stats["ell_kmax"])
+    n_x = int(stats.get("ell_n_x", stats["n_x"]))
+    layouts = [(kmax, rows, 0)] + [(int(k), int(c), int(c))
+                                   for k, c in stats.get("ell_splits", ())]
+
+    def cost(layout):
+        t = _ell_time(rows, layout[0], layout[2], n_x, nv, params)
+        return t, (ell_resident_bytes(rows, layout[0], n_x, nv, layout[2])
+                   if t == float("inf") else 0)
+
+    best = min(layouts, key=cost)
+    return best[0], best[1], cost(best)[0]
 
 
 def local_format_times(stats: Dict[str, float],
@@ -372,6 +422,9 @@ def local_format_times(stats: Dict[str, float],
       ell_kmax   padded ELL slots per row (cross-rank max)
       ell_n_x    x length the ELL product reads: the received domain
                  its composed column ids index (optional; n_x if absent)
+      ell_splits ``[K, chunk rows]`` of each row-split ELL layout, chunk
+                 rows padded to the max over ranks (optional; the ELL
+                 time is that of the best layout, :func:`choose_ell_split`)
     """
     bm, bn = int(stats["bm"]), int(stats["bn"])
     rows, n_x = stats["rows_pad"], stats["n_x"]
@@ -383,14 +436,7 @@ def local_format_times(stats: Dict[str, float],
     times = {"bsr": max(bsr_flops / params.mxu_flops,
                         bsr_bytes / params.hbm_bw)}
 
-    kmax, ell_n_x = stats["ell_kmax"], stats.get("ell_n_x", n_x)
-    ell_flops = 2.0 * rows * kmax * nv
-    ell_bytes = rows * kmax * 8 + ell_n_x * 4 * nv + out_b
-    if ell_resident_bytes(rows, kmax, ell_n_x, nv) > params.ell_hbm_budget:
-        times["ell"] = float("inf")  # the product does not fit the device
-    else:
-        times["ell"] = max(ell_flops / params.vpu_flops,
-                           ell_bytes / params.hbm_bw)
+    times["ell"] = choose_ell_split(stats, params, nv)[2]
 
     nnz = stats["nnz_pad"]
     coo_flops = 2.0 * nnz * nv

@@ -107,8 +107,8 @@ from repro.core.integrity import (MULTISTEP_MESSAGE_PHASES,
                                   NAP_MESSAGE_PHASES, STD_MESSAGE_PHASES,
                                   phase_index)
 from repro.core.cost_model import (LOCAL_FORMATS, LocalComputeParams,
-                                   TPU_V5E_LOCAL, choose_local_format,
-                                   local_format_times)
+                                   TPU_V5E_LOCAL, choose_ell_split,
+                                   choose_local_format, local_format_times)
 from repro.core.partition import RowPartition
 from repro.core.spans import span
 from repro.core.spmv import LocalBlocks, split_all_blocks
@@ -117,7 +117,7 @@ from repro.kernels.bsr_spmv.fused import fused_bsr_spmm, fused_bsr_spmm_packed
 from repro.kernels.ell_spmv.kernel import ell_spmm_packed
 from repro.sparse.bsr import BSR
 from repro.sparse.csr import CSR
-from repro.sparse.ell import ELL, stack_ell
+from repro.sparse.ell import ELL, padded_chunk_rows, stack_ell
 
 
 def _pad_to(arrs: List[np.ndarray], pad: int, fill: float = 0) -> np.ndarray:
@@ -308,27 +308,33 @@ class CompiledNAP:
         composed with :meth:`recv_domain_map` at emission, so the ELL
         program reads the received buffers directly and never gathers
         ``bnode``/``boff``; slot order and values are those of the
-        packed-domain emission."""
+        packed-domain emission.  Rows are split at the width the
+        autotuner chose (``autotune["ell"]``), with ``ell_chunk_row``
+        beside the arrays where it is below the longest row."""
         if "ell_cols" in self.arrays:
             return
         assert self.local_blocks is not None, "compiled plan lost its blocks"
-        cols, vals, kmax = _fused_ell_arrays(
+        cols, vals, kmax, chunk_row = _fused_ell_arrays(
             self.local_blocks, self.rows_pad, self.cols_pad,
-            self.pads["bnode"], self.pads["boff"])
+            self.pads["bnode"], self.pads["boff"],
+            split=_split_width(self.autotune))
         self.arrays["ell_cols"] = _compose_cols(cols, self.recv_domain_map())
         self.arrays["ell_vals"] = vals
+        _set_chunk_row(self.arrays, "ell_chunk_row", chunk_row)
         self.ell_kmax = kmax
 
     def ensure_ell_t(self) -> None:
         """Materialise the TRANSPOSED packed ELL arrays (lazily, once):
         A_r^T over the packed contribution domain
         ``[z(cols_pad) | c_on_node | c_off_node]`` with x = u_loc — the
-        vectorised alternative to the transpose COO scatter path."""
+        vectorised alternative to the transpose COO scatter path, split
+        at the transpose autotuner's width like :meth:`ensure_ell`."""
         if "ell_t_cols" in self.arrays:
             return
         assert self.local_blocks is not None, "compiled plan lost its blocks"
         cols_pad, bnode_pad = self.cols_pad, self.pads["bnode"]
         out_len = self.packed_x_len
+        split = _split_width(self.autotune.get("transpose", {}))
         per_rank: List[ELL] = []
         for blk in self.local_blocks:
             op_r, op_c, op_v = blk.on_proc.to_coo()
@@ -340,10 +346,11 @@ class CompiledNAP:
             vals = np.concatenate([op_v, on_v, off_v])
             per_rank.append(ELL.from_coo(rows_t, cols_t, vals,
                                          (out_len, self.rows_pad),
-                                         n_rows_pad=out_len))
-        cols, vals, kmax = stack_ell(per_rank)
+                                         n_rows_pad=out_len, split=split))
+        cols, vals, kmax, chunk_row = stack_ell(per_rank)
         self.arrays["ell_t_cols"] = cols
         self.arrays["ell_t_vals"] = vals
+        _set_chunk_row(self.arrays, "ell_t_chunk_row", chunk_row)
         self.ell_t_kmax = kmax
 
     def ensure_fused(self) -> None:
@@ -506,6 +513,38 @@ def _compose_cols(cols: np.ndarray, remap: np.ndarray) -> np.ndarray:
     return cols
 
 
+def _split_width(autotune: Dict[str, object]) -> int:
+    """The ELL split width an autotune verdict (one direction's) chose,
+    0 where it chose the unsplit layout."""
+    width = int(autotune.get("ell", {}).get("ell_split_width", 0))
+    kmax = int(autotune.get("stats", {}).get("ell_kmax", 0))
+    return width if 0 < width < kmax else 0
+
+
+def _set_chunk_row(arrays: Dict[str, np.ndarray], name: str,
+                   chunk_row: Optional[np.ndarray]) -> None:
+    """Keep a split layout's ``chunk_row`` map under ``name`` (nothing
+    for an unsplit one)."""
+    if chunk_row is not None:
+        arrays[name] = chunk_row
+
+
+def _ell_names(compiled, cols: str, vals: str, chunk_row: str) -> List[str]:
+    """The ELL product's plan arrays, its ``chunk_row`` map last where
+    the layout is split."""
+    return [cols, vals] + ([chunk_row] if chunk_row in compiled.arrays
+                           else [])
+
+
+def _ell_product(ell_args, xs, n_rows: int):
+    """The ELL local product over the program's ELL arguments
+    ``(cols, vals[, chunk_row])`` (see :func:`_ell_names`)."""
+    if len(ell_args) == 2:
+        return ell_spmm_packed(*ell_args, xs)
+    cols, vals, chunk_row = ell_args
+    return ell_spmm_packed(cols, vals, xs, chunk_row, n_rows=n_rows)
+
+
 def _ensure_abft_recv(compiled) -> None:
     """Materialise (lazily, once) ``abft_col_recv``/``abft_col_abs_recv``:
     the ABFT column sums ``abft_col``/``abft_col_abs`` moved onto the
@@ -614,11 +653,12 @@ def _fused_bsr_arrays(blocks: List[LocalBlocks], rows_pad: int, cols_pad: int,
 
 
 def _fused_ell_arrays(blocks: List[LocalBlocks], rows_pad: int, cols_pad: int,
-                      bnode_pad: int, boff_pad: int
-                      ) -> Tuple[np.ndarray, np.ndarray, int]:
+                      bnode_pad: int, boff_pad: int, split: int = 0):
     """Emit each rank's three column blocks as one ELL over the packed x
     domain ``[v_loc(cols_pad) | b_on_node | b_off_node]`` (offsets
-    cols_pad and cols_pad + bnode_pad), stacked to a shared kmax."""
+    cols_pad and cols_pad + bnode_pad), rows split at ``split`` (0: not
+    split), stacked to a shared width: :func:`repro.sparse.ell.stack_ell`'s
+    ``(cols, vals, width, chunk_row)``."""
     n_x = cols_pad + bnode_pad + boff_pad
     per_rank: List[ELL] = []
     for blk in blocks:
@@ -630,7 +670,7 @@ def _fused_ell_arrays(blocks: List[LocalBlocks], rows_pad: int, cols_pad: int,
                                cols_pad + bnode_pad + off_c])
         vals = np.concatenate([op_v, on_v, off_v])
         per_rank.append(ELL.from_coo(rows, cols, vals, (rows_pad, n_x),
-                                     n_rows_pad=rows_pad))
+                                     n_rows_pad=rows_pad, split=split))
     return stack_ell(per_rank)
 
 
@@ -645,49 +685,78 @@ def _format_stats_from_coo(per_rank_rc: List[Tuple[np.ndarray, np.ndarray]],
     defaults to ``n_x``).
 
     BSR tile counts come from unique (block row, block col) keys over the
-    packed column domain; ELL kmax from per-row counts — both pure bulk
-    numpy.  The SPMD program is bulk-synchronous, so the global decision
-    uses stats maxed over ranks; per-rank verdicts are recorded for
-    diagnostics/benchmarks.  Shared by compile_nap (three-segment packed
+    packed column domain; ELL kmax from per-row counts, and the chunk
+    rows of each row-split width (the powers of two below kmax) from the
+    same counts — all pure bulk numpy.  The SPMD program is
+    bulk-synchronous, so the global decision uses stats maxed over
+    ranks; per-rank verdicts are recorded for diagnostics/benchmarks.
+    ``"ell"`` holds the ELL layout the tuner picked
+    (:func:`_ell_layout`).  Shared by compile_nap (three-segment packed
     domain) and standard_spmv_shardmap (two-segment).
     """
     bm, bn = block_shape
     nbc = n_x // bn
     n_brows = -(-rows_pad // bm)
     per_rank = []
+    row_counts = []
     kb_global = 1
-    ke_global = 1
     for rank, (rows, cols) in enumerate(per_rank_rc):
         keys = np.unique((rows // bm) * nbc + cols // bn)
         kb = int(np.bincount((keys // nbc).astype(np.int64),
                              minlength=n_brows).max(initial=0))
-        ke = max(1, int(np.bincount(rows.astype(np.int64),
-                                    minlength=rows_pad).max(initial=0)))
+        counts = np.bincount(rows.astype(np.int64), minlength=rows_pad)
         nnz = int(rows.size)
         per_rank.append({
             "rank": rank, "nnz": nnz, "bsr_tiles": int(keys.size),
             "bsr_fill": nnz / max(int(keys.size) * bm * bn, 1),
-            "ell_kmax": ke,
+            "ell_kmax": max(1, int(counts.max(initial=0))),
         })
+        row_counts.append(counts)
         kb_global = max(kb_global, kb)
-        ke_global = max(ke_global, ke)
+    ke_global = max(e["ell_kmax"] for e in per_rank)
+    # chunk rows of each split width K, per rank: ceil(row length / K)
+    # summed over rows (a rank whose rows all fit K keeps one chunk row
+    # per row that holds an entry); the stack pads the largest as
+    # stack_ell does
+    widths = [1 << i for i in range(max(0, (ke_global - 1).bit_length()))]
+    chunks = [[int((-(-c // k)).sum()) for k in widths] for c in row_counts]
     stats = {
         "rows_pad": rows_pad, "n_x": n_x, "nnz_pad": nnz_pad_total,
         "bsr_blocks": n_brows * kb_global, "bm": bm, "bn": bn,
         "ell_kmax": ke_global, "ell_n_x": n_x if ell_n_x is None else ell_n_x,
+        "ell_splits": [
+            [k, padded_chunk_rows(max(1, max(c[i] for c in chunks)))]
+            for i, k in enumerate(widths)],
     }
     times = local_format_times(stats, tuner)
-    for entry in per_rank:
+    for entry, c in zip(per_rank, chunks):
         rank_stats = dict(stats, bsr_blocks=entry["bsr_tiles"],
-                          ell_kmax=entry["ell_kmax"], nnz_pad=entry["nnz"])
+                          ell_kmax=entry["ell_kmax"], nnz_pad=entry["nnz"],
+                          ell_splits=[[k, n] for k, n in zip(widths, c)
+                                      if k < entry["ell_kmax"]])
         entry["choice"] = choose_local_format(rank_stats, tuner)
     return {
         "chosen": min(LOCAL_FORMATS, key=lambda f: times[f]),
         "times": times,
         "stats": stats,
+        "ell": _ell_layout(stats, tuner,
+                           max((e["nnz"] for e in per_rank), default=0)),
         "per_rank": per_rank,
         "tuner": tuner.name,
     }
+
+
+def _ell_layout(stats: Dict[str, object], tuner: LocalComputeParams,
+                nnz: int) -> Dict[str, object]:
+    """The ELL layout the tuner picks for one direction, as
+    ``autotune_report()`` and ``op.stats()`` report it: the split width
+    (``ell_kmax`` where rows are not split), the chunk rows and slots
+    each rank's program runs, and those slots per entry of the fullest
+    rank."""
+    width, chunk_rows, _ = choose_ell_split(stats, tuner)
+    slots = width * chunk_rows
+    return {"ell_split_width": width, "ell_chunk_rows": chunk_rows,
+            "ell_slots": slots, "ell_slots_per_nnz": slots / max(nnz, 1)}
 
 
 def _autotune_stats(blocks: List[LocalBlocks], rows_pad: int, cols_pad: int,
@@ -734,8 +803,8 @@ def _transpose_format_stats(per_rank_rc_t: List[Tuple[np.ndarray, np.ndarray]],
                                 block_shape, tuner)
     times = {f: at["times"][f] for f in ("ell", "coo")}
     return {"chosen": min(times, key=lambda f: times[f]), "times": times,
-            "stats": at["stats"], "per_rank": at["per_rank"],
-            "tuner": tuner.name}
+            "stats": at["stats"], "ell": at["ell"],
+            "per_rank": at["per_rank"], "tuner": tuner.name}
 
 
 def _stack_padded_bsr(per_rank: List[BSR]) -> Tuple[np.ndarray, np.ndarray, int]:
@@ -1461,8 +1530,7 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
                                                     xs, nv_block=nv_block)
                 w = w_tiles.reshape(-1, nv)[:rows_pad]
             elif fmt == "ell":
-                ell_cols, ell_vals = tail
-                w = ell_spmm_packed(ell_cols, ell_vals, x_segs)
+                w = _ell_product(tail, x_segs, rows_pad)
             else:
                 (on_proc_rows, on_proc_cols, on_proc_vals,
                  on_node_rows, on_node_cols, on_node_vals,
@@ -1503,7 +1571,7 @@ def nap_forward_shardmap(compiled: CompiledNAP, mesh: Mesh,
     if fmt == "bsr":
         names += ["fused_cols", "fused_blocks"]
     elif fmt == "ell":
-        names += ["ell_cols", "ell_vals"]
+        names += _ell_names(compiled, "ell_cols", "ell_vals", "ell_chunk_row")
     else:
         names += ["on_proc_rows", "on_proc_cols", "on_proc_vals",
                   "on_node_rows", "on_node_cols", "on_node_vals",
@@ -1611,8 +1679,7 @@ def nap_transpose_shardmap(compiled: CompiledNAP, mesh: Mesh,
         #    output domain of each block (local x rows / buffer slots).
         with jax.named_scope("repro.local"):
             if fmt == "ell":
-                ell_t_cols, ell_t_vals = tail
-                contrib = ell_spmm_packed(ell_t_cols, ell_t_vals, (u_loc,))
+                contrib = _ell_product(tail, (u_loc,), compiled.packed_x_len)
                 z = contrib[:cols_pad]
                 c_node = contrib[cols_pad: cols_pad + bnode_pad]
                 c_off = contrib[cols_pad + bnode_pad:]
@@ -1698,7 +1765,8 @@ def nap_transpose_shardmap(compiled: CompiledNAP, mesh: Mesh,
     if ms:
         names.insert(6, "direct_send")
     if fmt == "ell":
-        names += ["ell_t_cols", "ell_t_vals"]
+        names += _ell_names(compiled, "ell_t_cols", "ell_t_vals",
+                            "ell_t_chunk_row")
     else:
         names += ["on_proc_rows", "on_proc_cols", "on_proc_vals",
                   "on_node_rows", "on_node_cols", "on_node_vals",
@@ -1816,24 +1884,28 @@ class CompiledStandard:
         :meth:`recv_domain_map` (no ``buf_gather`` in the ELL program)."""
         if "ell_cols" in self.arrays:
             return
-        e_cols, e_vals, _ = stack_ell([
+        split = _split_width(self.autotune)
+        e_cols, e_vals, _, chunk_row = stack_ell([
             ELL.from_coo(rr, cc, vv, (self.rows_pad, self.n_x),
-                         n_rows_pad=self.rows_pad)
+                         n_rows_pad=self.rows_pad, split=split)
             for rr, cc, vv in self.per_rank_coo])
         self.arrays["ell_cols"] = _compose_cols(e_cols, self.recv_domain_map())
         self.arrays["ell_vals"] = e_vals
+        _set_chunk_row(self.arrays, "ell_chunk_row", chunk_row)
 
     def ensure_ell_t(self) -> None:
         """Transposed ELL over the packed contribution domain
         ``[z(cols_pad) | buf]`` with x = u_loc (rows_pad)."""
         if "ell_t_cols" in self.arrays:
             return
-        e_cols, e_vals, kmax = stack_ell([
+        split = _split_width(self.autotune.get("transpose", {}))
+        e_cols, e_vals, kmax, chunk_row = stack_ell([
             ELL.from_coo(cc, rr, vv, (self.n_x, self.rows_pad),
-                         n_rows_pad=self.n_x)
+                         n_rows_pad=self.n_x, split=split)
             for rr, cc, vv in self.per_rank_coo])
         self.arrays["ell_t_cols"] = e_cols
         self.arrays["ell_t_vals"] = e_vals
+        _set_chunk_row(self.arrays, "ell_t_chunk_row", chunk_row)
         self.ell_t_kmax = kmax
 
     def ensure_fused(self) -> None:
@@ -2062,8 +2134,7 @@ def standard_forward_shardmap(compiled: CompiledStandard, mesh: Mesh,
                         nv_block=nv_block)
                 w = w_tiles.reshape(-1, nv)[:rows_pad]
             elif fmt == "ell":
-                ell_cols, ell_vals = tail
-                w = ell_spmm_packed(ell_cols, ell_vals, (v_loc, buf))
+                w = _ell_product(tail, (v_loc, buf), rows_pad)
             else:
                 A_rows, A_cols, A_vals = tail
                 full = jnp.concatenate([v_loc, buf])
@@ -2082,7 +2153,8 @@ def standard_forward_shardmap(compiled: CompiledStandard, mesh: Mesh,
 
     names = ["send_idx"] if composed else ["send_idx", "buf_gather"]
     names += {"bsr": ["fused_cols", "fused_blocks"],
-              "ell": ["ell_cols", "ell_vals"],
+              "ell": _ell_names(compiled, "ell_cols", "ell_vals",
+                                "ell_chunk_row"),
               "coo": ["A_rows", "A_cols", "A_vals"]}[fmt]
     if integrity:
         names += list(_ABFT_RECV_NAMES if composed
@@ -2145,8 +2217,7 @@ def standard_transpose_shardmap(compiled: CompiledStandard, mesh: Mesh,
         # transposed local SpMV over the packed domain [v_loc | buf]
         with jax.named_scope("repro.local"):
             if fmt == "ell":
-                ell_t_cols, ell_t_vals = tail
-                c = ell_spmm_packed(ell_t_cols, ell_t_vals, (u_loc,))
+                c = _ell_product(tail, (u_loc,), n_x)
             else:
                 A_rows, A_cols, A_vals = tail
                 c = segment_sum(A_vals[:, None] * u_loc[A_rows], A_cols,
@@ -2186,7 +2257,8 @@ def standard_transpose_shardmap(compiled: CompiledStandard, mesh: Mesh,
                 abft.reshape((1, 1) + abft.shape))
 
     names = ["send_idx", "buf_gather"]
-    names += (["ell_t_cols", "ell_t_vals"] if fmt == "ell"
+    names += (_ell_names(compiled, "ell_t_cols", "ell_t_vals",
+                         "ell_t_chunk_row") if fmt == "ell"
               else ["A_rows", "A_cols", "A_vals"])
     if integrity:
         names += ["abft_row", "abft_row_abs"]
@@ -2265,6 +2337,10 @@ def padded_traffic(compiled, integrity: str = "off",
     ``buf`` (standard), padding included, as the SPMD gather runs them.
     It is 0 when ``local_compute`` resolves to ``ell``, whose column ids
     are composed with those gathers at plan compile.
+
+    ``ell_split_width``, ``ell_chunk_rows``, ``ell_slots`` and
+    ``ell_slots_per_nnz`` (forward at the top level, transpose under
+    ``"transpose"``) report the ELL layout the autotuner picked.
     """
     topo = compiled.topo
     pads = getattr(compiled, "pads", None)
@@ -2304,5 +2380,9 @@ def padded_traffic(compiled, integrity: str = "off",
         out["buffer_gather_elems"] = pads["bnode"] + pads["boff"]
     else:
         out["buffer_gather_elems"] = compiled.buf_pad
+    # the ELL layout each direction's autotuner picked (split width,
+    # chunk rows, slots and slots per entry)
+    out.update(compiled.autotune.get("ell", {}))
+    transpose.update(compiled.autotune.get("transpose", {}).get("ell", {}))
     out["transpose"] = transpose
     return out

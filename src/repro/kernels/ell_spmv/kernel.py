@@ -30,33 +30,54 @@ autotuner uses to refuse ELL when it does not fit.
 
 Padding slots (col == -1, val == 0) clamp to x row 0 and multiply by
 zero, so they are mathematically inert.
+
+**Row-split layout** (:mod:`repro.sparse.ell`): with a ``chunk_row`` map
+the arrays are [n_chunks, K] and the slot loop runs over the chunk rows;
+one sorted ``segment_sum`` then adds the [n_chunks, nv] partials into the
+[n_rows, nv] result, under the named scope ``repro.local.combine``.
+Padding chunks point one past the last row and are dropped there.
+Without a map there is no combine and the program is the unsplit one.
 """
 from __future__ import annotations
+
+import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ops import segment_sum
 
 
-@jax.jit
-def ell_spmm_packed(cols: jax.Array, vals: jax.Array, xs) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("n_rows",))
+def ell_spmm_packed(cols: jax.Array, vals: jax.Array, xs,
+                    chunk_row: Optional[jax.Array] = None,
+                    n_rows: Optional[int] = None) -> jax.Array:
     """w = A @ concat(xs) for the ELL layout.
 
-    cols: [n_rows, kmax] int32 column ids in the packed x domain (-1 = pad)
-    vals: [n_rows, kmax] float32 (0 on padding slots)
+    cols: [n_chunks, kmax] int32 column ids in the packed x domain (-1 = pad)
+    vals: [n_chunks, kmax] float32 (0 on padding slots)
     xs:   tuple of [len_i, nv] segments; the column domain is their
           concatenation in order (e.g. (v_loc, full_recv, inter_recv,
           final_recv) in the forward NAP program)
+    chunk_row: [n_chunks] int32 sorted output row of each chunk row, for
+          the row-split layout (None: chunk row i is row i)
+    n_rows: output rows of a split layout (static)
     returns [n_rows, nv] float32
     """
     xs = tuple(jnp.asarray(x, jnp.float32) for x in xs)
     x = xs[0] if len(xs) == 1 else jnp.concatenate(xs)
-    n_rows, kmax = cols.shape
+    n_chunks, kmax = cols.shape
 
     def slot(k, acc):
         c = lax.dynamic_index_in_dim(cols, k, 1, keepdims=False)
         v = lax.dynamic_index_in_dim(vals, k, 1, keepdims=False)
         return acc + v[:, None] * x[jnp.maximum(c, 0)]
 
-    return lax.fori_loop(0, kmax, slot,
-                         jnp.zeros((n_rows, x.shape[1]), jnp.float32))
+    w = lax.fori_loop(0, kmax, slot,
+                      jnp.zeros((n_chunks, x.shape[1]), jnp.float32))
+    if chunk_row is None:
+        return w
+    with jax.named_scope("repro.local.combine"):
+        return segment_sum(w, chunk_row, num_segments=n_rows,
+                           indices_are_sorted=True)

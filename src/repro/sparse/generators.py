@@ -8,6 +8,9 @@
 * :func:`random_fixed_nnz` — random matrices with a constant number of
   non-zeros per row (Figs. 11-12).
 * :mod:`suitesparse_like` generates the Fig. 13-15 surrogates.
+* :func:`graph500_kronecker` — Graph500's Kronecker graphs (power-law
+  degrees, hub rows), and :func:`pagerank_matrix` the PageRank matrix
+  over one (``repro.amg.solve.pagerank`` iterates it).
 """
 from __future__ import annotations
 
@@ -153,3 +156,57 @@ def random_fixed_nnz(n_rows: int, nnz_per_row: int, seed: int = 0,
                          np.concatenate([vals1, vals2]) * 0.5,
                          a.shape)
     return a
+
+
+#: Graph500 initiator probabilities A, B, C (D = 1 - A - B - C = 0.05).
+GRAPH500_INITIATOR = (0.57, 0.19, 0.19)
+
+
+def graph500_kronecker(scale: int, edge_factor: int = 16,
+                       seed: int = 0) -> CSR:
+    """Graph500 Kronecker graph as an undirected 0/1 adjacency matrix.
+
+    The Graph500 specification's generator: ``edge_factor * 2^scale``
+    edges over ``2^scale`` vertices, each edge placed by ``scale``
+    quadrant draws with probabilities A/B/C/D = 0.57/0.19/0.19/0.05 (one
+    uniform per bit, which gives the spec's joint bit distribution), then
+    the vertex labels permuted.  Made undirected as Graph500's kernels
+    and LDBC Graphalytics' Graph500 data sets are: every edge in both
+    directions, self-loops and duplicates dropped.  numpy's generator
+    stands in for the reference code's.
+    """
+    n, m = 1 << scale, edge_factor << scale
+    a, b, c = GRAPH500_INITIATOR
+    rng = np.random.default_rng(seed)
+    src = np.zeros(m, np.int64)
+    dst = np.zeros(m, np.int64)
+    for bit in range(scale):
+        u = rng.random(m, dtype=np.float32)
+        src += (u >= a + b) * (1 << bit)                        # C or D
+        dst += (((u >= a) & (u < a + b)) | (u >= a + b + c)) * (1 << bit)
+    perm = rng.permutation(n)
+    src, dst = perm[src], perm[dst]
+    keep = src != dst
+    key = np.unique(np.concatenate([src[keep] * n + dst[keep],
+                                    dst[keep] * n + src[keep]]))
+    rows, cols = key // n, key % n
+    indptr = np.concatenate([[0], np.cumsum(np.bincount(rows, minlength=n))])
+    return CSR(indptr=indptr.astype(np.int64), indices=cols,
+               data=np.ones(cols.size), shape=(n, n))
+
+
+def pagerank_matrix(adj: CSR, d: float = 0.85) -> CSR:
+    """``B = I - d * adj^T * D_out^-1``, the matrix one PageRank step
+    applies (``adj[u, v] = 1`` for an edge u -> v, ``D_out`` the
+    out-degrees), values rounded to float32.  Every row holds its
+    diagonal; a dangling vertex's column holds only its diagonal, and
+    its mass is spread outside the matrix (``repro.amg.solve.pagerank``).
+    """
+    n = adj.shape[0]
+    src, dst, _ = adj.to_coo()
+    out_deg = np.bincount(src, minlength=n)
+    diag = np.arange(n)
+    vals = np.concatenate([-d / out_deg[src], np.ones(n)])
+    return CSR.from_coo(np.concatenate([dst, diag]),
+                        np.concatenate([src, diag]),
+                        vals.astype(np.float32).astype(np.float64), (n, n))

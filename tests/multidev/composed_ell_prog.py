@@ -4,12 +4,13 @@ The forward ELL programs read the received buffers directly: the plan
 composes each column id with Algorithm 3's buffer gathers
 (``bnode_gather``/``boff_gather``, or ``buf_gather`` for the standard
 plan), so the program runs no ``bnode``/``boff`` gather.  For every case
-(comm ``nap``/``multistep``/``standard`` x nv 1/8 x the random family and
-a 5-point stencil x a square and a rectangular operator) this checks:
+(comm ``nap``/``multistep``/``standard`` x nv 1/8 x the random family, a
+5-point stencil and a Graph500 Kronecker graph's PageRank matrix, whose
+rows are split, x a square and a rectangular operator) this checks:
 
 * the shard_map ELL output is BIT-identical to ``ell_spmm_packed`` run on
   the materialised ``[v_loc | bnode | boff]`` domain (``[v_loc | buf]``
-  for standard) with the packed-domain ids.  That reference is built here
+  for standard) with the packed-domain ids (split and combined alike).  That reference is built here
   from the plan arrays: the exchange is replayed in numpy through the
   send/gather maps, the buffers are gathered, and the packed ids are
   emitted from the plan's local blocks;
@@ -39,13 +40,14 @@ from repro.compat import make_mesh
 from repro.core.integrity import (IntegrityError, abft_tolerance,
                                   build_fault_spec, message_phases)
 from repro.core.partition import contiguous_partition, strided_partition
-from repro.core.spmv_jax import (compile_multistep, compile_nap,
+from repro.core.spmv_jax import (_split_width, compile_multistep, compile_nap,
                                  compile_standard, nap_forward_shardmap,
                                  pack_vector, standard_forward_shardmap,
                                  unpack_vector)
 from repro.core.topology import Topology
 from repro.kernels.ell_spmv import ell_spmm_packed
-from repro.sparse import poisson_2d, random_fixed_nnz
+from repro.sparse import (graph500_kronecker, pagerank_matrix, poisson_2d,
+                          random_fixed_nnz)
 from repro.sparse.ell import ELL, stack_ell
 
 TOPO = Topology(n_nodes=2, ppn=2)
@@ -54,7 +56,9 @@ MESH = make_mesh((NN, PPN), ("node", "proc"))
 
 
 def matrix(fam, shape):
-    a = random_fixed_nnz(150, 7, seed=5) if fam == "random" else poisson_2d(12)
+    a = {"random": lambda: random_fixed_nnz(150, 7, seed=5),
+         "stencil": lambda: poisson_2d(12),
+         "kron": lambda: pagerank_matrix(graph500_kronecker(9, seed=5))}[fam]()
     if shape == "rect":   # every other row: an [n/2, n] restriction-like R A
         a = a.select_rows(np.arange(0, a.shape[0], 2))
     return a
@@ -126,8 +130,9 @@ def replay(c, comm, v_loc, nv):
 
 
 def packed_ell(c, comm):
-    """The packed-domain ELL ids and values, emitted from the plan's local
-    blocks as the product read them before composition."""
+    """The packed-domain ELL ids, values and chunk-row map, emitted from
+    the plan's local blocks, split at the plan's width, as the product
+    read them before composition."""
     if comm == "standard":
         per_rank = c.per_rank_coo
     else:
@@ -139,10 +144,19 @@ def packed_ell(c, comm):
             offs = (0, c.cols_pad, c.cols_pad + bnode_pad)
             per_rank.append(tuple(np.concatenate(z) for z in zip(*[
                 (rr, cc + o, vv) for (rr, cc, vv), o in zip(parts, offs)])))
-    cols, vals, _ = stack_ell([
+    cols, vals, _, chunk_row = stack_ell([
         ELL.from_coo(rr, cc, vv, (c.rows_pad, c.packed_x_len),
-                     n_rows_pad=c.rows_pad) for rr, cc, vv in per_rank])
-    return cols, vals
+                     n_rows_pad=c.rows_pad, split=_split_width(c.autotune))
+        for rr, cc, vv in per_rank])
+    return cols, vals, chunk_row
+
+
+def ell_product(cols, vals, chunk_row, segs, n_rows):
+    """``ell_spmm_packed`` on one rank's arrays, combined where split."""
+    if chunk_row is None:
+        return np.asarray(ell_spmm_packed(cols, vals, segs))
+    return np.asarray(ell_spmm_packed(cols, vals, segs, chunk_row,
+                                      n_rows=n_rows))
 
 
 def forward(c, comm, fmt, **kw):
@@ -165,13 +179,17 @@ def check_case(name):
     got = np.asarray(run(shards)).reshape(NP, c.rows_pad, nv)
     assert c.arrays["ell_cols"].max() < c.recv_x_len
 
-    cols, vals = packed_ell(c, comm)
+    cols, vals, chunk_row = packed_ell(c, comm)
     assert np.array_equal(vals, c.arrays["ell_vals"])
+    if chunk_row is not None:
+        assert np.array_equal(chunk_row, c.arrays["ell_chunk_row"])
     v_loc = shards.reshape(NP, c.cols_pad, nv)
     for r, (recv_segs, packed_segs) in enumerate(replay(c, comm, v_loc, nv)):
-        want = np.asarray(ell_spmm_packed(cols[r], vals[r], packed_segs))
-        composed = np.asarray(ell_spmm_packed(
-            c.arrays["ell_cols"][r], c.arrays["ell_vals"][r], recv_segs))
+        cr = None if chunk_row is None else chunk_row[r]
+        want = ell_product(cols[r], vals[r], cr, packed_segs, c.rows_pad)
+        composed = ell_product(c.arrays["ell_cols"][r],
+                               c.arrays["ell_vals"][r], cr, recv_segs,
+                               c.rows_pad)
         assert np.array_equal(composed, want), f"rank {r}: composed ids"
         assert np.array_equal(got[r], want), f"rank {r}: program"
 

@@ -137,8 +137,9 @@ def test_dense_block_diagonal_selects_bsr():
 @pytest.mark.parametrize("nn,ppn", TOPOS)
 def test_packed_ell_layout_equals_local_blocks(nn, ppn):
     """The ELL arrays, viewed densely per rank over the received domain
-    and read back through the plan's packed -> received column map,
-    reproduce the three column blocks at their packed-domain offsets
+    (through the chunk-row map where the tuner split the rows) and read
+    back through the plan's packed -> received column map, reproduce the
+    three column blocks at their packed-domain offsets
     (v_loc | on-node | off-node)."""
     topo = Topology(n_nodes=nn, ppn=ppn)
     a = random_fixed_nnz(60, 6, seed=11)
@@ -149,9 +150,12 @@ def test_packed_ell_layout_equals_local_blocks(nn, ppn):
     remap = compiled.recv_domain_map()
     assert remap.shape == (topo.n_procs, compiled.packed_x_len)
     for r, blk in enumerate(split_all_blocks(a, part, topo)):
+        chunk_row = compiled.arrays.get("ell_chunk_row")
         ell = ELL(cols=compiled.arrays["ell_cols"][r],
                   vals=compiled.arrays["ell_vals"][r],
-                  shape=(rows_pad, compiled.recv_x_len))
+                  shape=(rows_pad, compiled.recv_x_len),
+                  chunk_row=None if chunk_row is None else chunk_row[r],
+                  n_out=rows_pad)
         dense = ell.to_dense()[:, remap[r]]
         nr = blk.rows.size
         np.testing.assert_allclose(dense[:nr, :nr], blk.on_proc.to_dense(),

@@ -20,7 +20,7 @@ import pytest
 import repro.api as nap
 from repro.core.partition import contiguous_partition
 from repro.core.spmv_jax import (_ensure_abft_recv, _fused_ell_arrays,
-                                 compile_multistep, compile_nap,
+                                 _split_width, compile_multistep, compile_nap,
                                  compile_standard, padded_traffic)
 from repro.core.topology import Topology
 from repro.sparse import CSR, random_fixed_nnz
@@ -29,7 +29,8 @@ from repro.sparse.ell import ELL, stack_ell
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 COMMS = ("nap", "multistep", "standard")
 CASES = [f"{comm}-nv{nv}-{fam}-{shape}" for comm in COMMS for nv in (1, 8)
-         for fam in ("random", "stencil") for shape in ("square", "rect")]
+         for fam in ("random", "stencil", "kron")
+         for shape in ("square", "rect")]
 INTEGRITY_CASES = [f"integrity-{comm}" for comm in COMMS]
 
 
@@ -101,15 +102,16 @@ def test_composed_ids_are_the_packed_ids_through_the_map(comm):
     ``recv_domain_map``; padding stays -1 and values keep their slots."""
     c = _plan(comm, local_compute="ell")
     c.ensure_ell()
+    split = _split_width(c.autotune)
     if comm == "standard":
-        packed, vals, _ = stack_ell([
+        packed, vals, _, _ = stack_ell([
             ELL.from_coo(rr, cc, vv, (c.rows_pad, c.n_x),
-                         n_rows_pad=c.rows_pad)
+                         n_rows_pad=c.rows_pad, split=split)
             for rr, cc, vv in c.per_rank_coo])
     else:
-        packed, vals, _ = _fused_ell_arrays(c.local_blocks, c.rows_pad,
-                                            c.cols_pad, c.pads["bnode"],
-                                            c.pads["boff"])
+        packed, vals, _, _ = _fused_ell_arrays(c.local_blocks, c.rows_pad,
+                                               c.cols_pad, c.pads["bnode"],
+                                               c.pads["boff"], split=split)
     composed, remap = c.arrays["ell_cols"], c.recv_domain_map()
     assert remap.shape == (c.topo.n_procs, c.packed_x_len)
     assert composed.max() < c.recv_x_len
