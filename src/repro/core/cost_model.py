@@ -21,6 +21,7 @@ import dataclasses
 from typing import Dict, List, Tuple
 
 from repro.core.comm_graph import Message, NAPPlan, StandardPlan
+from repro.sparse.ell import slot_loop_rows
 
 SHORT_CUTOFF = 512        # bytes
 EAGER_CUTOFF = 8 * 1024   # bytes
@@ -391,20 +392,25 @@ def choose_ell_split(stats: Dict[str, object],
     """``(width, chunk rows, modeled seconds)`` of the cheapest ELL
     layout: the unsplit one (``ell_kmax`` slots over ``rows_pad`` rows,
     no combine) or a split ``[K, chunk rows]`` of ``stats["ell_splits"]``
-    (the powers of two below ``ell_kmax``).  Ties keep the unsplit
+    (the powers of two below ``ell_kmax``).  Each is priced at the rows
+    its slot loop runs, :func:`repro.sparse.ell.slot_loop_rows` of its
+    count, which is the chunk rows returned.  Ties keep the unsplit
     layout; where none fits the device, the one that holds least wins."""
     rows, kmax = int(stats["rows_pad"]), int(stats["ell_kmax"])
     n_x = int(stats.get("ell_n_x", stats["n_x"]))
-    layouts = [(kmax, rows, 0)] + [(int(k), int(c), int(c))
-                                   for k, c in stats.get("ell_splits", ())]
+    unsplit_rows = slot_loop_rows(rows)
+    layouts = [(kmax, 0)] + [(int(k), slot_loop_rows(int(c)))
+                             for k, c in stats.get("ell_splits", ())]
 
     def cost(layout):
-        t = _ell_time(rows, layout[0], layout[2], n_x, nv, params)
-        return t, (ell_resident_bytes(rows, layout[0], n_x, nv, layout[2])
+        width, chunks = layout
+        out = rows if chunks else unsplit_rows
+        t = _ell_time(out, width, chunks, n_x, nv, params)
+        return t, (ell_resident_bytes(out, width, n_x, nv, chunks)
                    if t == float("inf") else 0)
 
     best = min(layouts, key=cost)
-    return best[0], best[1], cost(best)[0]
+    return best[0], best[1] or unsplit_rows, cost(best)[0]
 
 
 def local_format_times(stats: Dict[str, float],

@@ -117,7 +117,7 @@ from repro.kernels.bsr_spmv.fused import fused_bsr_spmm, fused_bsr_spmm_packed
 from repro.kernels.ell_spmv.kernel import ell_spmm_packed
 from repro.sparse.bsr import BSR
 from repro.sparse.csr import CSR
-from repro.sparse.ell import ELL, padded_chunk_rows, stack_ell
+from repro.sparse.ell import ELL, stack_ell
 
 
 def _pad_to(arrs: List[np.ndarray], pad: int, fill: float = 0) -> np.ndarray:
@@ -538,11 +538,10 @@ def _ell_names(compiled, cols: str, vals: str, chunk_row: str) -> List[str]:
 
 def _ell_product(ell_args, xs, n_rows: int):
     """The ELL local product over the program's ELL arguments
-    ``(cols, vals[, chunk_row])`` (see :func:`_ell_names`)."""
-    if len(ell_args) == 2:
-        return ell_spmm_packed(*ell_args, xs)
-    cols, vals, chunk_row = ell_args
-    return ell_spmm_packed(cols, vals, xs, chunk_row, n_rows=n_rows)
+    ``(cols, vals[, chunk_row])`` (see :func:`_ell_names`), as its
+    ``[n_rows, nv]`` result: the stack's padding rows are dropped."""
+    cols, vals, *chunk_row = ell_args
+    return ell_spmm_packed(cols, vals, xs, *chunk_row, n_rows=n_rows)
 
 
 def _ensure_abft_recv(compiled) -> None:
@@ -716,17 +715,16 @@ def _format_stats_from_coo(per_rank_rc: List[Tuple[np.ndarray, np.ndarray]],
     ke_global = max(e["ell_kmax"] for e in per_rank)
     # chunk rows of each split width K, per rank: ceil(row length / K)
     # summed over rows (a rank whose rows all fit K keeps one chunk row
-    # per row that holds an entry); the stack pads the largest as
-    # stack_ell does
+    # per row that holds an entry); the stack runs the largest, padded
+    # by slot_loop_rows as the tuner prices it
     widths = [1 << i for i in range(max(0, (ke_global - 1).bit_length()))]
     chunks = [[int((-(-c // k)).sum()) for k in widths] for c in row_counts]
     stats = {
         "rows_pad": rows_pad, "n_x": n_x, "nnz_pad": nnz_pad_total,
         "bsr_blocks": n_brows * kb_global, "bm": bm, "bn": bn,
         "ell_kmax": ke_global, "ell_n_x": n_x if ell_n_x is None else ell_n_x,
-        "ell_splits": [
-            [k, padded_chunk_rows(max(1, max(c[i] for c in chunks)))]
-            for i, k in enumerate(widths)],
+        "ell_splits": [[k, max(1, max(c[i] for c in chunks))]
+                       for i, k in enumerate(widths)],
     }
     times = local_format_times(stats, tuner)
     for entry, c in zip(per_rank, chunks):
@@ -751,11 +749,14 @@ def _ell_layout(stats: Dict[str, object], tuner: LocalComputeParams,
     """The ELL layout the tuner picks for one direction, as
     ``autotune_report()`` and ``op.stats()`` report it: the split width
     (``ell_kmax`` where rows are not split), the chunk rows and slots
-    each rank's program runs, and those slots per entry of the fullest
-    rank."""
+    each rank's program runs, of them the rows the stack added
+    (``ell_pad_rows``, :func:`repro.sparse.ell.slot_loop_rows`), and
+    the slots per entry of the fullest rank."""
     width, chunk_rows, _ = choose_ell_split(stats, tuner)
+    counted = dict(stats.get("ell_splits", ())).get(width, stats["rows_pad"])
     slots = width * chunk_rows
     return {"ell_split_width": width, "ell_chunk_rows": chunk_rows,
+            "ell_pad_rows": chunk_rows - counted,
             "ell_slots": slots, "ell_slots_per_nnz": slots / max(nnz, 1)}
 
 
@@ -2338,9 +2339,10 @@ def padded_traffic(compiled, integrity: str = "off",
     It is 0 when ``local_compute`` resolves to ``ell``, whose column ids
     are composed with those gathers at plan compile.
 
-    ``ell_split_width``, ``ell_chunk_rows``, ``ell_slots`` and
-    ``ell_slots_per_nnz`` (forward at the top level, transpose under
-    ``"transpose"``) report the ELL layout the autotuner picked.
+    ``ell_split_width``, ``ell_chunk_rows``, ``ell_pad_rows``,
+    ``ell_slots`` and ``ell_slots_per_nnz`` (forward at the top level,
+    transpose under ``"transpose"``) report the ELL layout the autotuner
+    picked.
     """
     topo = compiled.topo
     pads = getattr(compiled, "pads", None)
@@ -2381,7 +2383,7 @@ def padded_traffic(compiled, integrity: str = "off",
     else:
         out["buffer_gather_elems"] = compiled.buf_pad
     # the ELL layout each direction's autotuner picked (split width,
-    # chunk rows, slots and slots per entry)
+    # chunk rows, padding rows, slots and slots per entry)
     out.update(compiled.autotune.get("ell", {}))
     transpose.update(compiled.autotune.get("transpose", {}).get("ell", {}))
     out["transpose"] = transpose

@@ -29,7 +29,13 @@ product holds in device memory is counted by
 autotuner uses to refuse ELL when it does not fit.
 
 Padding slots (col == -1, val == 0) clamp to x row 0 and multiply by
-zero, so they are mathematically inert.
+zero, so they are mathematically inert.  The stack pads the row axis
+too (:func:`repro.sparse.ell.slot_loop_rows`: 512 past a multiple of
+1024 above 2^17 rows, out of the TPU compiler's slower row-tile class
+at nv 1); there the loop runs over the padded rows and an unsplit
+product returns the first ``n_rows`` of them.  At nv > 1, where the
+other class ran faster, an unsplit product's loop reads the first
+``n_rows`` rows of each slot only.
 
 **Row-split layout** (:mod:`repro.sparse.ell`): with a ``chunk_row`` map
 the arrays are [n_chunks, K] and the slot loop runs over the chunk rows;
@@ -62,22 +68,31 @@ def ell_spmm_packed(cols: jax.Array, vals: jax.Array, xs,
           final_recv) in the forward NAP program)
     chunk_row: [n_chunks] int32 sorted output row of each chunk row, for
           the row-split layout (None: chunk row i is row i)
-    n_rows: output rows of a split layout (static)
+    n_rows: output rows (static); an unsplit layout whose cols hold more
+          rows (the stack's padding rows) drops the rest
     returns [n_rows, nv] float32
     """
     xs = tuple(jnp.asarray(x, jnp.float32) for x in xs)
     x = xs[0] if len(xs) == 1 else jnp.concatenate(xs)
     n_chunks, kmax = cols.shape
+    rows = n_chunks
+    if chunk_row is None and n_rows is not None and x.shape[1] > 1:
+        # the padding rows move an nv 1 loop into the compiler's faster
+        # row-tile class; a wider operand ran slower with them, so its
+        # loop reads the real rows of each slot only
+        rows = min(n_rows, n_chunks)
 
     def slot(k, acc):
         c = lax.dynamic_index_in_dim(cols, k, 1, keepdims=False)
         v = lax.dynamic_index_in_dim(vals, k, 1, keepdims=False)
+        if rows < n_chunks:
+            c, v = c[:rows], v[:rows]
         return acc + v[:, None] * x[jnp.maximum(c, 0)]
 
     w = lax.fori_loop(0, kmax, slot,
-                      jnp.zeros((n_chunks, x.shape[1]), jnp.float32))
+                      jnp.zeros((rows, x.shape[1]), jnp.float32))
     if chunk_row is None:
-        return w
+        return w if n_rows is None or n_rows >= rows else w[:n_rows]
     with jax.named_scope("repro.local.combine"):
         return segment_sum(w, chunk_row, num_segments=n_rows,
                            indices_are_sorted=True)

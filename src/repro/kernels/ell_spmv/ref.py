@@ -12,7 +12,7 @@ def ell_spmm_packed_ref(cols, vals, xs, chunk_row=None,
     valid = (cols >= 0)[..., None]
     w = (vals[..., None] * jnp.where(valid, gathered, 0.0)).sum(axis=1)
     if chunk_row is None:
-        return w
+        return w if n_rows is None else w[:n_rows]
     return jnp.zeros((n_rows, w.shape[1]), w.dtype).at[chunk_row].add(
         w, mode="drop")
 

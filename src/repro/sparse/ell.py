@@ -24,6 +24,12 @@ power-law graph ELL issues far more slots than entries.  A split width
 each row's chunk partials after the slot loop.  A row with no entries
 gets no chunk row.  Without a split there is no ``chunk_row`` and chunk
 row ``i`` is row ``i``.
+
+**Stacking.**  :func:`stack_ell` pads the row axis of every layout, split
+or not, to :func:`slot_loop_rows` (512 past a multiple of 1024 above
+2^17 rows) with all-padding rows, which keeps the slot loop out of the
+TPU compiler's slower row-tile class; the product slices or combines
+them away.
 """
 from __future__ import annotations
 
@@ -150,18 +156,24 @@ def _chunks(e: ELL, width: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     return cols, vals, held.astype(np.int32)
 
 
-def padded_chunk_rows(n: int) -> int:
-    """The chunk-row count a split layout is stacked to.
+def slot_loop_rows(n: int) -> int:
+    """The row count an ELL slot loop runs over ``n`` rows (chunk rows of
+    a split layout, rows of an unsplit one): every stacked ELL is padded
+    to it, and the tuner prices it.
 
     Up to 2^17 the count stays as it is.  Above, it is padded to 512 past
-    a multiple of 1024.  The TPU compiler lays the slot loop's
-    ``[n_chunks]`` vectors in tiles of 1024 rows, and where the last tile
-    is full or nearly so (``n_chunks % 1024`` 0 or above about 800) it
-    tiles the loop in windows that ran it 5 to 6% slower (TPU v5e, K = 8:
-    316 against 298 ms on the scale-20 Graph500 graph, 249.5 against
-    234.8 ms on the transposed 2^20 x 25 random matrix, for the same
-    chunks padded to each count).  One residue also gives every matrix of
-    a size the same program.  The padding is under 1024 chunk rows."""
+    a multiple of 1024.  The TPU compiler lays the slot loop's ``[n]``
+    vectors in tiles of 1024 rows, and where the last tile is full or
+    nearly so (``n % 1024`` 0 or above about 800) it reads each slot's
+    column ids through a ``dynamic-slice`` instead of a ``reduce``, a loop
+    that ran 5 to 7% slower at nv 1 on a TPU v5e (192.1 against 179.0 ms
+    on the 2^20 x 25 random matrix, 316 against 298 ms on the scale-20
+    Graph500 graph at K = 8; PERF.md, where ``scripts/slot_loop_tiles.py``
+    re-measures it).  At nv 8 the ``dynamic-slice`` loop ran faster, so
+    an unsplit product's loop skips the padding rows there
+    (``kernels/ell_spmv``).  One residue also gives every matrix of a
+    size the same program.  The padding is under 1024 rows, and a padded
+    count maps to itself."""
     if n <= 1 << 17:
         return n
     return n + (512 - n) % 1024
@@ -171,19 +183,21 @@ def stack_ell(per_rank: List["ELL"], kmax: Optional[int] = None
               ) -> Tuple[np.ndarray, np.ndarray, int, Optional[np.ndarray]]:
     """Align ranks to one shared kmax and stack into the
     [n_procs, n_rows, kmax] cols/vals arrays the SPMD executor shards;
-    the fourth item is None.
+    the fourth item is None.  ``n_rows`` is :func:`slot_loop_rows` of the
+    largest rank's rows: the rows past it hold padding slots only, and
+    the product drops them.
 
     Where any rank is split, every rank is aligned to its split width
     ``K``: the arrays are [n_procs, n_chunks, K] with the chunk-row count
     padded to the maximum over ranks, and the fourth item is the
     [n_procs, n_chunks] ``chunk_row`` map, whose padding chunks point at
     the padding row ``n_rows`` (one past the last output row, which the
-    product's combine drops); ``n_chunks`` is :func:`padded_chunk_rows`
+    product's combine drops); ``n_chunks`` is :func:`slot_loop_rows`
     of the largest count."""
     n_procs = len(per_rank)
     if all(e.chunk_row is None for e in per_rank):
         kmax = max(kmax or 1, max(e.kmax for e in per_rank))
-        n_rows = max(e.n_rows for e in per_rank)
+        n_rows = slot_loop_rows(max(e.n_rows for e in per_rank))
         cols = np.full((n_procs, n_rows, kmax), -1, dtype=np.int32)
         vals = np.zeros((n_procs, n_rows, kmax), dtype=np.float32)
         for r, e in enumerate(per_rank):
@@ -193,8 +207,7 @@ def stack_ell(per_rank: List["ELL"], kmax: Optional[int] = None
     width = max(e.kmax for e in per_rank if e.chunk_row is not None)
     parts = [_chunks(e, width) for e in per_rank]
     n_rows = max(e.n_rows for e in per_rank)
-    n_chunks = padded_chunk_rows(max(1, max(c.shape[0]
-                                            for c, _, _ in parts)))
+    n_chunks = slot_loop_rows(max(1, max(c.shape[0] for c, _, _ in parts)))
     cols = np.full((n_procs, n_chunks, width), -1, dtype=np.int32)
     vals = np.zeros((n_procs, n_chunks, width), dtype=np.float32)
     chunk_row = np.full((n_procs, n_chunks), n_rows, dtype=np.int32)

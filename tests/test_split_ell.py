@@ -7,7 +7,11 @@ of its own here.  Everything else runs in process: the layout and its
 stacking across ranks, the product against dense and float64 scipy on
 one device, the tuner keeping the unsplit layout (and so the unsplit
 program) on the stencil, the uniform random forward matrix and the
-four-chip plan, the reports, the generator and PageRank.
+four-chip plan, the reports, the generator and PageRank.  Besides, the
+one row-count rule every stacked ELL follows (``slot_loop_rows``): its
+cases, padded products bit for bit against the same operator with the
+rule off, the programs it leaves alone, and each cell's layout and
+``ell_pad_rows`` at its real size.
 """
 import importlib.util
 import os
@@ -29,15 +33,20 @@ from repro.core.cost_model import (TPU_V5E_LOCAL, LocalComputeParams,
                                    choose_ell_split, ell_resident_bytes,
                                    local_format_times)
 from repro.core.partition import contiguous_partition
-from repro.core.spmv_jax import (_compose_cols, _fused_ell_arrays,
-                                 compile_nap, nap_forward_shardmap)
+import repro.core.spmv_jax as spmv_jax
+import repro.sparse.ell as ell_mod
+from repro.core.spmv_jax import (_compose_cols, _ell_layout,
+                                 _fused_ell_arrays, compile_nap,
+                                 nap_forward_shardmap, nap_transpose_shardmap)
 from repro.core.topology import Topology
 from repro.kernels.ell_spmv import ell_spmm_packed, ell_spmm_packed_ref
-from repro.sparse import ELL, graph500_kronecker, pagerank_matrix, stack_ell
-from repro.sparse.ell import padded_chunk_rows
+from repro.sparse import (ELL, graph500_kronecker, pagerank_matrix,
+                          random_fixed_nnz, stack_ell)
+from repro.sparse.ell import slot_loop_rows
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
-MULTIDEV = [f"{comm}-{d}-nv{nv}" for comm in ("nap", "multistep", "standard")
+MULTIDEV = [f"{comm}-{d}-nv{nv}"
+            for comm in ("nap", "multistep", "standard", "pad")
             for d in ("F", "T") for nv in (1, 8)]
 
 
@@ -103,15 +112,20 @@ def test_stack_aligns_split_and_unsplit_ranks_to_one_width():
     assert cr2 is None and k2 == 2 and c2.shape == (2, 40, 2)
 
 
-def test_chunk_rows_pad_to_512_past_a_multiple_of_1024():
-    assert padded_chunk_rows(75) == 75
-    assert padded_chunk_rows(1 << 17) == 1 << 17
-    for n in (131073, 3735140, 4735938, 4736156, 4736253, 4736512):
-        got = padded_chunk_rows(n)
-        assert got % 1024 == 512 and n <= got < n + 1024
-    # the scale-20 graph's seeds all stack to one shape
-    assert {padded_chunk_rows(n) for n in (4735938, 4736156, 4736253)} \
-        == {4736512}
+@pytest.mark.parametrize("n,want", [
+    (75, 75), (1 << 17, 1 << 17),           # up to 2^17 the count stays
+    (131073, 131584),
+    (1 << 18, 262656),                      # paper_random_25 per chip on 4
+    (1 << 20, 1049088),                     # paper_random_25 on one chip
+    (1124864, 1124864),                     # HPCG's 104^3: already at 512
+    (3735140, 3736064),                     # the transposed random matrix
+    (4735938, 4736512), (4736156, 4736512),  # the scale-20 graph's seeds
+    (4736253, 4736512), (4736512, 4736512)])
+def test_chunk_rows_pad_to_512_past_a_multiple_of_1024(n, want):
+    got = slot_loop_rows(n)
+    assert got == want and n <= got < n + 1024
+    assert got <= 1 << 17 or got % 1024 == 512
+    assert slot_loop_rows(got) == got       # a padded count maps to itself
 
 
 # -- the product ----------------------------------------------------------------
@@ -131,6 +145,27 @@ def test_split_product_matches_dense(nv):
     # the padded rank: padding chunks point past the last row, dropped
     w1 = np.asarray(ell_spmm_packed(c[1], v[1], xs, cr[1], n_rows=40))
     assert not w1[rows[8] + 1:].any()
+
+
+@pytest.mark.parametrize("nv", [1, 8])
+def test_unsplit_product_drops_the_stack_padding_rows(nv):
+    rows, cols, vals = _skewed_coo(seed=5)
+    e = ELL.from_coo(rows, cols, vals, (40, 40))
+    pad_c = np.concatenate([e.cols, np.full((24, e.kmax), -1, np.int32)])
+    pad_v = np.concatenate([e.vals, np.zeros((24, e.kmax), np.float32)])
+    x = np.random.default_rng(nv).standard_normal((40, nv)).astype(np.float32)
+    xs = (jnp.asarray(x[:25]), jnp.asarray(x[25:]))
+    plain = np.asarray(ell_spmm_packed(e.cols, e.vals, xs))
+    w = np.asarray(ell_spmm_packed(pad_c, pad_v, xs, n_rows=40))
+    assert w.shape == (40, nv) and np.array_equal(w, plain)
+    np.testing.assert_allclose(
+        np.asarray(ell_spmm_packed_ref(pad_c, pad_v, xs, None, 40)), plain,
+        rtol=1e-6, atol=1e-6)
+    # with nothing to drop, the program is the one without n_rows
+    assert str(jax.make_jaxpr(lambda c, v, *xs: ell_spmm_packed(
+        c, v, xs, n_rows=40))(e.cols, e.vals, *xs)) == str(
+        jax.make_jaxpr(lambda c, v, *xs: ell_spmm_packed(c, v, xs))(
+            e.cols, e.vals, *xs))
 
 
 def test_combine_runs_under_its_scope():
@@ -241,6 +276,159 @@ def test_even_rows_keep_the_unsplit_layout_and_program(name):
             np.zeros((1, 1, c.cols_pad, 1), np.float32), *run.args()))
         # the slot loop (a fori_loop of static length) and no combine
         assert "scatter-add" not in jaxpr and jaxpr.count(" scan[") == 1
+
+
+# -- one row count for every slot loop -------------------------------------------
+
+PAD_ROWS = (1 << 17) + 1024     # a full last 1024-row tile: the rule adds 512
+
+
+@pytest.fixture(scope="module")
+def padded_pair():
+    """One matrix of ``PAD_ROWS`` rows through the operator on one device
+    twice: as it runs (every ELL stacked to ``slot_loop_rows``), and with
+    the stack's rule switched off while its arrays are emitted."""
+    a = random_fixed_nnz(PAD_ROWS, 5, seed=2)
+    ops = {}
+    for name in ("padded", "plain"):
+        with pytest.MonkeyPatch.context() as mp:
+            if name == "plain":
+                mp.setattr(ell_mod, "slot_loop_rows", lambda n: n)
+            op = nap.operator(a, local_compute="ell", cache=False)
+            op.executor.compiled.ensure_ell()
+            op.executor.compiled.ensure_ell_t()
+        ops[name] = op
+    return a, ops
+
+
+@pytest.mark.parametrize("nv", [1, 8])
+@pytest.mark.parametrize("direction", ["forward", "transpose"])
+def test_padded_rows_leave_every_real_row_bit_for_bit(padded_pair,
+                                                       direction, nv):
+    a, ops = padded_pair
+    name, ref = ("ell_cols", sp.csr_matrix(
+        (a.data, a.indices, a.indptr), shape=a.shape))
+    if direction == "transpose":
+        name, ref = "ell_t_cols", ref.T.tocsr()
+    pad = ops["padded"].executor.compiled
+    plain = ops["plain"].executor.compiled
+    run_rows = pad.arrays[name].shape[1]
+    assert run_rows == slot_loop_rows(plain.arrays[name].shape[1])
+    if direction == "forward":      # unsplit: 512 rows of padding slots
+        assert run_rows == pad.rows_pad + 512
+        assert ops["padded"].stats()["ell_pad_rows"] == 512
+    x = np.random.default_rng(nv).standard_normal(
+        (a.shape[1], nv) if nv > 1 else a.shape[1]).astype(np.float32)
+    got = [np.asarray((op if direction == "forward" else op.T) @ x)
+           for op in (ops["padded"], ops["plain"])]
+    assert np.array_equal(got[0], got[1])
+    xd = x.astype(np.float64)
+    err = np.abs(got[0] - ref @ xd) / np.maximum(abs(ref) @ np.abs(xd), 1e-30)
+    assert err.max() <= 1e-5
+
+
+def _parent_ell_product(ell_args, xs, n_rows):
+    """The product as the shard programs called it before the stack
+    padded unsplit layouts: no ``n_rows`` without a ``chunk_row`` map."""
+    if len(ell_args) == 2:
+        return ell_spmm_packed(*ell_args, xs)
+    cols, vals, chunk_row = ell_args
+    return ell_spmm_packed(cols, vals, xs, chunk_row, n_rows=n_rows)
+
+
+def _ell_program_jaxpr(c, direction):
+    from repro.compat import make_mesh
+    build = (nap_forward_shardmap if direction == "forward"
+             else nap_transpose_shardmap)
+    run = build(c, make_mesh((1, 1), ("node", "proc")), local_compute="ell")
+    n_in = c.cols_pad if direction == "forward" else c.rows_pad
+    return str(jax.make_jaxpr(run.jitted)(
+        np.zeros((1, 1, n_in, 1), np.float32), *run.args()))
+
+
+UNPADDED_PLANS = {
+    # HPCG's stencil at 56^3 = 175,616 rows: like 104^3, 512 past a
+    # multiple of 1024, so the rule adds no row above 2^17
+    "stencil_56": lambda: _cell_matrix("hpcg_27pt_104", nx=56, ny=56, nz=56,
+                                       diagonal=26.0, off_diagonal=-1.0),
+    "graph": lambda: pagerank_matrix(graph500_kronecker(10, seed=3)),
+    "random": lambda: _cell_matrix("paper_random_25", n_rows=512,
+                                   nnz_per_row=25),
+}
+
+
+@pytest.mark.parametrize("name,direction", [
+    ("stencil_56", "forward"), ("graph", "forward"), ("graph", "transpose"),
+    ("random", "transpose")])
+def test_layouts_the_rule_adds_no_rows_to_keep_the_parent_program(
+        monkeypatch, name, direction):
+    a = UNPADDED_PLANS[name]()
+    c = compile_nap(a, contiguous_partition(a.shape[0], 1), Topology(1, 1),
+                    cache=False)
+    at = c.autotune if direction == "forward" else c.autotune["transpose"]
+    assert at["ell"]["ell_pad_rows"] == 0
+    (c.ensure_ell if direction == "forward" else c.ensure_ell_t)()
+    cols = c.arrays["ell_cols" if direction == "forward" else "ell_t_cols"]
+    split = at["ell"]["ell_split_width"] < at["stats"]["ell_kmax"]
+    assert split == (name != "stencil_56")
+    counted = (dict(at["stats"]["ell_splits"])[at["ell"]["ell_split_width"]]
+               if split else at["stats"]["rows_pad"])
+    assert cols.shape[1] == counted == at["ell"]["ell_chunk_rows"]
+    if name == "stencil_56":
+        assert c.rows_pad == 175_616 > 1 << 17
+    now = _ell_program_jaxpr(c, direction)
+    monkeypatch.setattr(spmv_jax, "_ell_product", _parent_ell_product)
+    assert now == _ell_program_jaxpr(c, direction)
+
+
+def _cell_stats(rows, kmax, n_x, row_lengths):
+    """The tuner's stats of one benchmark cell's ELL at its real size,
+    with every split width's chunk rows counted from ``row_lengths``."""
+    widths = [1 << i for i in range((kmax - 1).bit_length())]
+    return {"rows_pad": rows, "n_x": n_x, "ell_n_x": n_x, "ell_kmax": kmax,
+            "ell_splits": [[k, int((-(-row_lengths // k)).sum())]
+                           for k in widths]}
+
+
+def _transposed_random_lengths():
+    # the columns of the 2^20 x 25 random matrix: the diagonal and
+    # Poisson(24) more, over the packed domain 2^20 + 256
+    n = (1 << 20) + 256
+    lengths = np.random.default_rng(17).poisson(24, n) + 1
+    lengths[1 << 20:] = 0
+    return lengths
+
+
+CELL_LAYOUTS = {
+    # cell: (stats, split width, chunk rows, pad rows) as the program runs
+    "paper_random_25.spmv": (
+        lambda: _cell_stats(1 << 20, 25, (1 << 20) + 256,
+                            np.full(1 << 20, 25)), 25, 1049088, 512),
+    "paper_random_25.spmv_x4": (
+        lambda: _cell_stats(1 << 18, 25, 1_832_476, np.full(1 << 18, 25)),
+        25, 262656, 512),
+    "hpcg_27pt_104.spmv": (
+        lambda: _cell_stats(1_124_864, 27, 1_124_864 + 256,
+                            np.full(1_124_864, 27)), 27, 1_124_864, 0),
+    "paper_random_25.transpose": (
+        lambda: _cell_stats((1 << 20) + 256, 53, 1 << 20,
+                            _transposed_random_lengths()), 8, None, None),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_LAYOUTS))
+def test_cells_keep_their_layout_and_report_the_pad_rows(cell):
+    make, width, chunk_rows, pad = CELL_LAYOUTS[cell]
+    stats = make()
+    got = _ell_layout(stats, TPU_V5E_LOCAL, 1)
+    assert got["ell_split_width"] == width
+    counted = dict(stats["ell_splits"]).get(width, stats["rows_pad"])
+    assert got["ell_chunk_rows"] == slot_loop_rows(counted)
+    assert got["ell_pad_rows"] == got["ell_chunk_rows"] - counted
+    if chunk_rows is not None:
+        assert (got["ell_chunk_rows"], got["ell_pad_rows"]) == (chunk_rows,
+                                                                pad)
+    assert got["ell_slots"] == width * got["ell_chunk_rows"]
 
 
 # -- the price of a split ------------------------------------------------------------
