@@ -2,7 +2,8 @@
 baseline for BENCH_spmv.json's plan-compile speedup measurement.
 
 This is the dict/per-element-loop implementation of ``split_local_blocks``
-and ``compile_nap`` exactly as shipped in the seed commit; the library path
+and ``compile_nap`` exactly as shipped in the seed commit (returning its
+pads and arrays, no plan object); the library path
 (``repro.core.spmv`` / ``repro.core.spmv_jax``) replaced it with bulk
 ``np.searchsorted`` indexing.  Do not "fix" or speed this file up — its
 slowness is the datum.  (The fused-BSR arrays did not exist in the seed,
@@ -17,7 +18,7 @@ import numpy as np
 from repro.core.comm_graph import Message, NAPPlan, build_nap_plan
 from repro.core.partition import RowPartition
 from repro.core.spmv import LocalBlocks
-from repro.core.spmv_jax import CompiledNAP, _pad_to
+from repro.core.spmv_jax import _pad_to
 from repro.core.topology import Topology
 from repro.sparse.csr import CSR
 
@@ -66,7 +67,8 @@ def legacy_split_all_blocks(a: CSR, part: RowPartition, topo: Topology) -> List[
 
 
 def legacy_compile_nap(a: CSR, part: RowPartition, topo: Topology,
-                plan: Optional[NAPPlan] = None) -> CompiledNAP:
+                plan: Optional[NAPPlan] = None
+                ) -> Tuple[Dict[str, int], Dict[str, np.ndarray]]:
     if plan is None:
         plan = build_nap_plan(a.indptr, a.indices, part, topo, pairing="aligned")
     n_procs, ppn, n_nodes = topo.n_procs, topo.ppn, topo.n_nodes
@@ -205,4 +207,4 @@ def legacy_compile_nap(a: CSR, part: RowPartition, topo: Topology,
 
     pads = dict(full=full_pad, init=init_pad, inter=inter_pad, final=final_pad,
                 bnode=bnode_pad, boff=boff_pad, **{f"nnz_{k}": v for k, v in nnz_pads.items()})
-    return CompiledNAP(topo=topo, part=part, rows_pad=rows_pad, pads=pads, arrays=arrays)
+    return pads, arrays

@@ -3,7 +3,7 @@
 Measures, on a forced 8-device host platform (2 nodes x 4 ppn):
 
 * ``plan_compile`` — wall time of plan compilation (split_all_blocks +
-  compile_nap) on a 20k-row random matrix: the seed dict/per-element
+  compile_plan) on a 20k-row random matrix: the seed dict/per-element
   implementation (``benchmarks/_legacy_plan.py``, kept verbatim) vs the
   vectorised one, plus the cached-recompile time.  ``speedup`` is THE
   claim source for any plan-compile speedup quoted in docs (ROADMAP /
@@ -87,7 +87,7 @@ def bench_plan_compile(n_rows: int, nnz_per_row: int) -> dict:
     from benchmarks._legacy_plan import legacy_compile_nap
     from repro.core.comm_graph import build_nap_plan
     from repro.core.partition import contiguous_partition
-    from repro.core.spmv_jax import clear_compile_cache, compile_nap
+    from repro.core.spmv_jax import clear_compile_cache, compile_plan
     from repro.core.topology import Topology
     from repro.sparse import random_fixed_nnz
 
@@ -99,10 +99,10 @@ def bench_plan_compile(n_rows: int, nnz_per_row: int) -> dict:
     plan = build_nap_plan(a.indptr, a.indices, part, topo, pairing="aligned")
 
     t_legacy = _best_of(lambda: legacy_compile_nap(a, part, topo, plan=plan), 2)
-    t_new = _best_of(lambda: compile_nap(a, part, topo, plan=plan), 5)
+    t_new = _best_of(lambda: compile_plan(a, part, topo, plan=plan), 5)
     clear_compile_cache()
-    compile_nap(a, part, topo)                      # populate cache
-    t_cached = _best_of(lambda: compile_nap(a, part, topo), 3)
+    compile_plan(a, part, topo)                     # populate cache
+    t_cached = _best_of(lambda: compile_plan(a, part, topo), 3)
     clear_compile_cache()
     speedup = round(t_legacy / t_new, 2)
     return {
@@ -121,14 +121,14 @@ def bench_local_emit(n_rows: int, nnz_per_row: int) -> dict:
     what the autotuner chose (all lazy; the compile cache amortises the
     chosen format's emission across repeated SpMVs)."""
     from repro.core.partition import contiguous_partition
-    from repro.core.spmv_jax import compile_nap
+    from repro.core.spmv_jax import compile_plan
     from repro.core.topology import Topology
     from repro.sparse import random_fixed_nnz
 
     topo = Topology(n_nodes=2, ppn=4)
     a = random_fixed_nnz(n_rows, nnz_per_row, seed=0)
     part = contiguous_partition(n_rows, topo.n_procs)
-    compiled = compile_nap(a, part, topo, cache=False)
+    compiled = compile_plan(a, part, topo, cache=False)
     t0 = time.perf_counter()
     compiled.ensure_fused()
     t_bsr = time.perf_counter() - t0
@@ -156,10 +156,8 @@ def bench_spmv_wall(n_rows: int, nnz_per_row: int, quick: bool) -> dict:
     from repro.compat import make_mesh
     from repro.core.comm_graph import build_standard_plan, nap_stats, standard_stats, build_nap_plan
     from repro.core.partition import contiguous_partition
-    from repro.core.spmv_jax import (compile_nap, compile_standard,
-                                     nap_forward_shardmap, pack_vector,
-                                     padded_traffic,
-                                     standard_forward_shardmap)
+    from repro.core.spmv_jax import (compile_plan, forward_program,
+                                     pack_vector, padded_traffic)
     from repro.core.topology import Topology
     from repro.sparse import random_fixed_nnz
 
@@ -167,8 +165,8 @@ def bench_spmv_wall(n_rows: int, nnz_per_row: int, quick: bool) -> dict:
     mesh = make_mesh((topo.n_nodes, topo.ppn), ("node", "proc"))
     a = random_fixed_nnz(n_rows, nnz_per_row, seed=0)
     part = contiguous_partition(n_rows, topo.n_procs)
-    compiled = compile_nap(a, part, topo, cache=False)
-    compiled_std = compile_standard(a, part, topo, cache=False)
+    compiled = compile_plan(a, part, topo, cache=False)
+    compiled_std = compile_plan(a, part, topo, comm="standard", cache=False)
     rng = np.random.default_rng(0)
 
     def timed(fn, *args):
@@ -195,17 +193,17 @@ def bench_spmv_wall(n_rows: int, nnz_per_row: int, quick: bool) -> dict:
     for nv in ((8,) if quick else (1, 8)):
         v = rng.standard_normal((n_rows, nv))
         shards = pack_vector(v, part, topo, compiled.rows_pad)
-        run_auto = nap_forward_shardmap(compiled, mesh, local_compute="auto")
+        run_auto = forward_program(compiled, mesh, local_compute="auto")
         # auto is timed adjacent to the cheap fixed formats it resolves
         # against, not in the heap-churn shadow of the 11 MB BSR variant
         paths = {
-            "standard_bsr": standard_forward_shardmap(compiled_std, mesh,
-                                                      local_compute="bsr"),
-            "nap_coo": nap_forward_shardmap(compiled, mesh, local_compute="coo"),
-            "nap_ell": nap_forward_shardmap(compiled, mesh, local_compute="ell"),
+            "standard_bsr": forward_program(compiled_std, mesh,
+                                            local_compute="bsr"),
+            "nap_coo": forward_program(compiled, mesh, local_compute="coo"),
+            "nap_ell": forward_program(compiled, mesh, local_compute="ell"),
             "nap_auto": run_auto,
-            "nap_fused_bsr": nap_forward_shardmap(compiled, mesh,
-                                                  local_compute="bsr"),
+            "nap_fused_bsr": forward_program(compiled, mesh,
+                                             local_compute="bsr"),
         }
         for name, run in paths.items():
             walls[f"{name}_nv{nv}_s"] = round(timed(run, shards), 5)

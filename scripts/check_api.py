@@ -21,8 +21,7 @@ Checks, on a (2, 2) machine on CPU:
     `op.integrity_report()` carries the retry/strike counters;
   * the one-release deprecation shims are GONE: `nap_spmv_shardmap`,
     `standard_spmv_shardmap` and `DistSpMV.run` no longer exist (their
-    release has passed — migration table: src/repro/kernels/README.md)
-    and no removed shim has resurfaced.
+    release has passed) and no removed shim has resurfaced.
 
     PYTHONPATH=src python scripts/check_api.py
 """

@@ -163,11 +163,6 @@ class StandardPlan:
                 return m.idx
         return np.empty(0, dtype=np.int64)
 
-    def recv_slot_map(self, rank: int, pad: int) -> Tuple[np.ndarray, np.ndarray]:
-        """Slot map into rank's flat recv buffer ([n_procs, pad] by src)."""
-        msgs = self.recvs[rank]
-        return flat_slot_map(msgs, [m.src for m in msgs], pad)
-
 
 def build_standard_plan(indptr: np.ndarray, indices: np.ndarray,
                         part: RowPartition, topo: Topology,
@@ -244,22 +239,6 @@ class NAPPlan:
     def I(self, rank: int, dst: int) -> np.ndarray:
         out = [m.idx for m in self.inter_sends[rank] if m.dst == dst]
         return np.unique(np.concatenate(out)) if out else np.empty(0, dtype=np.int64)
-
-    def recv_slot_map(self, rank: int, phase: str,
-                      pad: int) -> Tuple[np.ndarray, np.ndarray]:
-        """Slot map into rank's flat padded recv buffer for one phase.
-
-        The SPMD executor lays out received values as ``[n_slots, pad]`` per
-        phase — slot = sender's local id for the intra-node phases ("full",
-        "init", "final") and sender's *node* id for "inter" (the buffer the
-        aggregated inter-node all-to-all produces).  This is the block-layout
-        contract the fused BSR compile step builds its gather maps against.
-        """
-        topo = self.topology
-        msgs = {"full": self.local_full_recvs, "init": self.local_init_recvs,
-                "final": self.local_final_recvs, "inter": self.inter_recvs}[phase][rank]
-        slot_of = topo.node_of if phase == "inter" else topo.local_of
-        return flat_slot_map(msgs, [slot_of(m.src) for m in msgs], pad)
 
 
 def _distribute_slots(items: Sequence[Tuple[int, int]], ppn: int) -> List[List[Tuple[int, int]]]:

@@ -53,6 +53,7 @@ from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
+from repro.comm.multistep import multistep_stats
 from repro.core.comm_graph import (build_nap_plan, build_standard_plan,
                                    nap_stats, standard_stats)
 from repro.core.cost_model import (LocalComputeParams, MachineParams,
@@ -70,6 +71,16 @@ from repro.core.topology import Topology
 # NOTE: repro.core.spmv_jax (and thus jax) is imported lazily inside the
 # shardmap executors — the simulate backend stays importable and usable on
 # a jax-free numpy installation (repro.core.integrity is numpy-only).
+
+#: strategy -> (message stats, modeled comm cost) of its comm-graph plan
+_PLAN_MODELS = {"nap": (nap_stats, nap_cost),
+                "multistep": (multistep_stats, multistep_cost),
+                "standard": (standard_stats, standard_cost)}
+
+
+def _plan_stats(method: str, plan) -> Dict[str, object]:
+    return {f"messages_{k}": v
+            for k, v in _PLAN_MODELS[method][0](plan).items()}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,6 +188,26 @@ class _ShardmapExecutor:
         if self._compiled is None:
             self._compiled = self._compile()
         return self._compiled
+
+    def _compile(self):
+        from repro.core.spmv_jax import compile_plan
+        return compile_plan(self.a, self.row_part, self.topo,
+                            comm=self.method,
+                            block_shape=self.spec.block_shape,
+                            cache=self.spec.cache,
+                            local_compute=self.spec.local_compute,
+                            tuner=self.spec.tuner, col_part=self.col_part,
+                            threshold=self.spec.threshold)
+
+    def _build(self, direction: str) -> Callable:
+        from repro.core.spmv_jax import forward_program, transpose_program
+        kw = dict(local_compute=self.spec.local_compute,
+                  nv_block=self.spec.nv_block)
+        if self._integrity is not None:
+            kw.update(integrity=True, fault_fetch=self._integrity.fetch_spec)
+        build = (forward_program if direction == "forward"
+                 else transpose_program)
+        return build(self.compiled, self.mesh, **kw)
 
     def _run(self, direction: str) -> Callable:
         if direction not in self._runs:
@@ -309,121 +340,31 @@ class _ShardmapExecutor:
                     transpose_resolved=self.transpose_local_compute,
                     requested=self.spec.local_compute)
 
+    def stats(self) -> Dict[str, object]:
+        from repro.core.spmv_jax import padded_traffic
+        out = _plan_stats(self.method, self.compiled.plan)
+        out.update(padded_traffic(self.compiled,
+                                  integrity=self.spec.integrity,
+                                  local_compute=self.spec.local_compute))
+        return out
+
+    def cost(self, machine: MachineParams) -> Dict[str, float]:
+        return _PLAN_MODELS[self.method][1](self.compiled.plan, machine)
+
 
 @register_executor("shardmap", "nap")
 class NapShardmapExecutor(_ShardmapExecutor):
     method = "nap"
 
-    def _compile(self):
-        from repro.core.spmv_jax import compile_nap
-        return compile_nap(self.a, self.row_part, self.topo,
-                           block_shape=self.spec.block_shape,
-                           cache=self.spec.cache,
-                           local_compute=self.spec.local_compute,
-                           tuner=self.spec.tuner, col_part=self.col_part)
-
-    def _build(self, direction: str):
-        from repro.core.spmv_jax import (nap_forward_shardmap,
-                                         nap_transpose_shardmap)
-        kw = dict(local_compute=self.spec.local_compute,
-                  nv_block=self.spec.nv_block)
-        if self._integrity is not None:
-            kw.update(integrity=True, fault_fetch=self._integrity.fetch_spec)
-        if direction == "forward":
-            return nap_forward_shardmap(self.compiled, self.mesh, **kw)
-        return nap_transpose_shardmap(self.compiled, self.mesh, **kw)
-
-    def stats(self) -> Dict[str, object]:
-        from repro.core.spmv_jax import padded_traffic
-        out = {f"messages_{k}": v for k, v in
-               nap_stats(self.compiled.plan).items()}
-        out.update(padded_traffic(self.compiled,
-                                  integrity=self.spec.integrity,
-                                  local_compute=self.spec.local_compute))
-        return out
-
-    def cost(self, machine: MachineParams) -> Dict[str, float]:
-        return nap_cost(self.compiled.plan, machine)
-
 
 @register_executor("shardmap", "multistep")
 class MultistepShardmapExecutor(_ShardmapExecutor):
-    """Multi-step plan on the SAME shard_map builders as the nap
-    executor — :func:`nap_forward_shardmap` /
-    :func:`nap_transpose_shardmap` add the fifth "direct" exchange when
-    the compiled plan carries ``comm="multistep"``."""
-
     method = "multistep"
-
-    def _compile(self):
-        from repro.core.spmv_jax import compile_multistep
-        return compile_multistep(self.a, self.row_part, self.topo,
-                                 block_shape=self.spec.block_shape,
-                                 cache=self.spec.cache,
-                                 local_compute=self.spec.local_compute,
-                                 tuner=self.spec.tuner,
-                                 col_part=self.col_part,
-                                 threshold=self.spec.threshold)
-
-    def _build(self, direction: str):
-        from repro.core.spmv_jax import (nap_forward_shardmap,
-                                         nap_transpose_shardmap)
-        kw = dict(local_compute=self.spec.local_compute,
-                  nv_block=self.spec.nv_block)
-        if self._integrity is not None:
-            kw.update(integrity=True, fault_fetch=self._integrity.fetch_spec)
-        if direction == "forward":
-            return nap_forward_shardmap(self.compiled, self.mesh, **kw)
-        return nap_transpose_shardmap(self.compiled, self.mesh, **kw)
-
-    def stats(self) -> Dict[str, object]:
-        from repro.comm.multistep import multistep_stats
-        from repro.core.spmv_jax import padded_traffic
-        out = {f"messages_{k}": v for k, v in
-               multistep_stats(self.compiled.ms_plan).items()}
-        out.update(padded_traffic(self.compiled,
-                                  integrity=self.spec.integrity,
-                                  local_compute=self.spec.local_compute))
-        return out
-
-    def cost(self, machine: MachineParams) -> Dict[str, float]:
-        return multistep_cost(self.compiled.ms_plan, machine)
 
 
 @register_executor("shardmap", "standard")
 class StandardShardmapExecutor(_ShardmapExecutor):
     method = "standard"
-
-    def _compile(self):
-        from repro.core.spmv_jax import compile_standard
-        return compile_standard(self.a, self.row_part, self.topo,
-                                block_shape=self.spec.block_shape,
-                                cache=self.spec.cache,
-                                local_compute=self.spec.local_compute,
-                                tuner=self.spec.tuner, col_part=self.col_part)
-
-    def _build(self, direction: str):
-        from repro.core.spmv_jax import (standard_forward_shardmap,
-                                         standard_transpose_shardmap)
-        kw = dict(local_compute=self.spec.local_compute,
-                  nv_block=self.spec.nv_block)
-        if self._integrity is not None:
-            kw.update(integrity=True, fault_fetch=self._integrity.fetch_spec)
-        if direction == "forward":
-            return standard_forward_shardmap(self.compiled, self.mesh, **kw)
-        return standard_transpose_shardmap(self.compiled, self.mesh, **kw)
-
-    def stats(self) -> Dict[str, object]:
-        from repro.core.spmv_jax import padded_traffic
-        out = {f"messages_{k}": v for k, v in
-               standard_stats(self.compiled.plan).items()}
-        out.update(padded_traffic(self.compiled,
-                                  integrity=self.spec.integrity,
-                                  local_compute=self.spec.local_compute))
-        return out
-
-    def cost(self, machine: MachineParams) -> Dict[str, float]:
-        return standard_cost(self.compiled.plan, machine)
 
 
 # ---------------------------------------------------------------------------
@@ -527,6 +468,12 @@ class _SimulateExecutor:
     def trace_counts(self) -> Dict[str, int]:
         return {}   # nothing is traced: exact numpy execution
 
+    def stats(self) -> Dict[str, object]:
+        return _plan_stats(self.method, self.plan)
+
+    def cost(self, machine: MachineParams) -> Dict[str, float]:
+        return _PLAN_MODELS[self.method][1](self.plan, machine)
+
     def autotune_report(self) -> Dict[str, object]:
         return {"resolved": self.local_compute,
                 "transpose_resolved": self.transpose_local_compute,
@@ -550,12 +497,6 @@ class NapSimulateExecutor(_SimulateExecutor):
     def _transpose(self, u):
         return simulate_nap_spmv_transpose(self.a, u, self.plan)
 
-    def stats(self) -> Dict[str, object]:
-        return {f"messages_{k}": v for k, v in nap_stats(self.plan).items()}
-
-    def cost(self, machine: MachineParams) -> Dict[str, float]:
-        return nap_cost(self.plan, machine)
-
 
 @register_executor("simulate", "multistep")
 class MultistepSimulateExecutor(_SimulateExecutor):
@@ -577,14 +518,6 @@ class MultistepSimulateExecutor(_SimulateExecutor):
         from repro.comm.simulate import simulate_multistep_spmv_transpose
         return simulate_multistep_spmv_transpose(self.a, u, self.plan)
 
-    def stats(self) -> Dict[str, object]:
-        from repro.comm.multistep import multistep_stats
-        return {f"messages_{k}": v for k, v in
-                multistep_stats(self.plan).items()}
-
-    def cost(self, machine: MachineParams) -> Dict[str, float]:
-        return multistep_cost(self.plan, machine)
-
 
 @register_executor("simulate", "standard")
 class StandardSimulateExecutor(_SimulateExecutor):
@@ -600,13 +533,6 @@ class StandardSimulateExecutor(_SimulateExecutor):
 
     def _transpose(self, u):
         return simulate_standard_spmv_transpose(self.a, u, self.plan)
-
-    def stats(self) -> Dict[str, object]:
-        return {f"messages_{k}": v for k, v in
-                standard_stats(self.plan).items()}
-
-    def cost(self, machine: MachineParams) -> Dict[str, float]:
-        return standard_cost(self.plan, machine)
 
 
 # ---------------------------------------------------------------------------

@@ -175,9 +175,8 @@ def measure_spmv(a, part, topo: Topology, method: str,
     v = rng.standard_normal(a.shape[1])
     op @ v                                  # warm-up: compile + trace
     wall = _best_of(lambda: op @ v, repeats)
-    compiled = op.executor.compiled
-    plan = compiled.ms_plan if method == "multistep" else compiled.plan
-    phase_walls = measure_phase_walls(plan, topo, repeats=repeats)
+    phase_walls = measure_phase_walls(op.executor.compiled.plan, topo,
+                                      repeats=repeats)
     comm_wall = sum(w["seconds"] for w in phase_walls)
     return {
         "wall_s": float(wall),

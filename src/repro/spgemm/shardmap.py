@@ -149,7 +149,7 @@ def compile_spgemm(a: CSR, b: CSR, row_part: RowPartition,
     Builds (or accepts) the :class:`SpGemmPlan`, resolves every B row a
     rank consumes to its position in the packed value domain, expands
     the local products and merges C's structure — all bulk numpy, cached
-    like :func:`repro.core.spmv_jax.compile_nap`.
+    like :func:`repro.core.spmv_jax.compile_plan`.
     """
     key = None
     if plan is None and cache:

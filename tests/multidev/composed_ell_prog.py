@@ -40,10 +40,8 @@ from repro.compat import make_mesh
 from repro.core.integrity import (IntegrityError, abft_tolerance,
                                   build_fault_spec, message_phases)
 from repro.core.partition import contiguous_partition, strided_partition
-from repro.core.spmv_jax import (_split_width, compile_multistep, compile_nap,
-                                 compile_standard, nap_forward_shardmap,
-                                 pack_vector, standard_forward_shardmap,
-                                 unpack_vector)
+from repro.core.spmv_jax import (_split_width, compile_plan,
+                                 forward_program, pack_vector, unpack_vector)
 from repro.core.topology import Topology
 from repro.kernels.ell_spmv import ell_spmm_packed
 from repro.sparse import (graph500_kronecker, pagerank_matrix, poisson_2d,
@@ -64,13 +62,11 @@ def matrix(fam, shape):
     return a
 
 
-def compile_plan(comm, a, shape):
+def compile_case(comm, a, shape):
     rp = contiguous_partition(a.shape[0], NP)
     cp = strided_partition(a.shape[1], NP) if shape == "rect" else None
-    build = {"nap": compile_nap, "multistep": compile_multistep,
-             "standard": compile_standard}[comm]
-    return build(a, rp, TOPO, block_shape=(8, 16), cache=False,
-                 col_part=cp), rp, cp or rp
+    return compile_plan(a, rp, TOPO, comm=comm, block_shape=(8, 16),
+                        cache=False, col_part=cp), rp, cp or rp
 
 
 # -- numpy replay of the forward exchange (tiled all_to_alls) ---------------
@@ -134,16 +130,20 @@ def packed_ell(c, comm):
     the plan's local blocks, split at the plan's width, as the product
     read them before composition."""
     if comm == "standard":
-        per_rank = c.per_rank_coo
+        segs = [(0, ("on_proc",)), (c.cols_pad, ("on_node", "off_node"))]
     else:
-        bnode_pad = c.pads["bnode"]
-        per_rank = []
-        for blk in c.local_blocks:
-            parts = [blk.on_proc.to_coo(), blk.on_node.to_coo(),
-                     blk.off_node.to_coo()]
-            offs = (0, c.cols_pad, c.cols_pad + bnode_pad)
-            per_rank.append(tuple(np.concatenate(z) for z in zip(*[
-                (rr, cc + o, vv) for (rr, cc, vv), o in zip(parts, offs)])))
+        segs = [(0, ("on_proc",)), (c.cols_pad, ("on_node",)),
+                (c.cols_pad + c.pads["bnode"], ("off_node",))]
+    per_rank = []
+    for blk in c.local_blocks:
+        parts = []
+        for start, kinds in segs:
+            for k in kinds:
+                rr, cc, vv = getattr(blk, k).to_coo()
+                parts.append((rr, cc + start, vv))
+                if k != "on_proc":
+                    start += getattr(blk, f"{k}_cols").size
+        per_rank.append(tuple(np.concatenate(z) for z in zip(*parts)))
     cols, vals, _, chunk_row = stack_ell([
         ELL.from_coo(rr, cc, vv, (c.rows_pad, c.packed_x_len),
                      n_rows_pad=c.rows_pad, split=_split_width(c.autotune))
@@ -160,16 +160,14 @@ def ell_product(cols, vals, chunk_row, segs, n_rows):
 
 
 def forward(c, comm, fmt, **kw):
-    build = (standard_forward_shardmap if comm == "standard"
-             else nap_forward_shardmap)
-    return build(c, MESH, local_compute=fmt, **kw)
+    return forward_program(c, MESH, local_compute=fmt, **kw)
 
 
 def check_case(name):
     comm, nvs, fam, shape = name.split("-")
     nv = int(nvs[2:])
     a = matrix(fam, shape)
-    c, rp, cp = compile_plan(comm, a, shape)
+    c, rp, cp = compile_case(comm, a, shape)
     rng = np.random.default_rng(len(name))
     v = rng.standard_normal((a.shape[1], nv))
     shards = pack_vector(v, cp, TOPO, c.cols_pad)
@@ -200,7 +198,7 @@ def check_case(name):
     dense = np.stack([a.matvec(v[:, i]) for i in range(nv)], axis=1)
     np.testing.assert_allclose(y, dense, rtol=1e-4, atol=1e-5)
     if comm == "multistep" and fam == "random":
-        assert any(c.ms_plan.direct.sends), "the direct exchange is empty"
+        assert any(c.plan.direct.sends), "the direct exchange is empty"
 
 
 def check_integrity(name):

@@ -34,10 +34,9 @@ from repro.compat import make_mesh
 from repro.core.comm_graph import build_nap_plan
 from repro.core.partition import contiguous_partition, make_partition
 from repro.core.spmv import simulate_nap_spmv, simulate_nap_spmv_transpose
-from repro.core.spmv_jax import (compile_nap, compile_standard,
-                                 nap_forward_shardmap, nap_transpose_shardmap,
-                                 pack_vector, standard_forward_shardmap,
-                                 standard_transpose_shardmap, unpack_vector)
+from repro.core.spmv_jax import (compile_plan, forward_program,
+                                 pack_vector, transpose_program,
+                                 unpack_vector)
 from repro.core.topology import Topology
 from repro.sparse import random_fixed_nnz
 
@@ -58,7 +57,7 @@ def check(topo_shape, kind, block_shape, nv, seed):
     part = make_partition(kind, n, topo.n_procs,
                           indptr=a.indptr, indices=a.indices, seed=seed)
     mesh = make_mesh((nn, ppn), ("node", "proc"))
-    compiled = compile_nap(a, part, topo, block_shape=block_shape, cache=False)
+    compiled = compile_plan(a, part, topo, block_shape=block_shape, cache=False)
     v = rng.standard_normal((n, nv))
     want = dense_oracle(a, v)
 
@@ -70,7 +69,7 @@ def check(topo_shape, kind, block_shape, nv, seed):
     np.testing.assert_allclose(sim, want, rtol=1e-9, atol=1e-11)
 
     # fused Pallas BSR shard_map executor (zero-copy) vs both oracles
-    run = nap_forward_shardmap(compiled, mesh, local_compute="bsr")
+    run = forward_program(compiled, mesh, local_compute="bsr")
     shards = pack_vector(v, part, topo, compiled.rows_pad)
     got_raw = np.asarray(run(shards))
     got = unpack_vector(got_raw, part, topo)
@@ -78,20 +77,20 @@ def check(topo_shape, kind, block_shape, nv, seed):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
     # zero-copy in-kernel gather == materialised HBM concat, bit-for-bit
-    run_mat = nap_forward_shardmap(compiled, mesh, local_compute="bsr",
+    run_mat = forward_program(compiled, mesh, local_compute="bsr",
                                    materialize_x=True)
     assert np.array_equal(np.asarray(run_mat(shards)), got_raw)
 
     if nv == 8:
         for fmt in ("coo", "ell", "auto"):
-            run_f = nap_forward_shardmap(compiled, mesh, local_compute=fmt)
+            run_f = forward_program(compiled, mesh, local_compute=fmt)
             got_f = unpack_vector(np.asarray(run_f(shards)), part, topo)
             np.testing.assert_allclose(got_f, want, rtol=1e-4, atol=1e-5)
         assert run_f.local_compute == compiled.chosen_local_compute
-        cstd = compile_standard(a, part, topo, block_shape=block_shape,
-                                cache=False)
+        cstd = compile_plan(a, part, topo, comm="standard",
+                            block_shape=block_shape, cache=False)
         for fmt in ("bsr", "auto"):
-            run_std = standard_forward_shardmap(cstd, mesh, local_compute=fmt)
+            run_std = forward_program(cstd, mesh, local_compute=fmt)
             got_std = unpack_vector(np.asarray(run_std(shards)), part, topo)
             np.testing.assert_allclose(got_std, want, rtol=1e-4, atol=1e-5)
 
@@ -101,10 +100,10 @@ def check(topo_shape, kind, block_shape, nv, seed):
         sim_t = np.stack([simulate_nap_spmv_transpose(a, v[:, i], nap_plan)
                           for i in range(nv)], axis=1)
         np.testing.assert_allclose(sim_t, want_t, rtol=1e-9, atol=1e-11)
-        run_t = nap_transpose_shardmap(compiled, mesh)
+        run_t = transpose_program(compiled, mesh)
         got_t = unpack_vector(np.asarray(run_t(shards)), part, topo)
         np.testing.assert_allclose(got_t, sim_t, rtol=1e-4, atol=1e-5)
-        run_ts = standard_transpose_shardmap(cstd, mesh)
+        run_ts = transpose_program(cstd, mesh)
         got_ts = unpack_vector(np.asarray(run_ts(shards)), part, topo)
         np.testing.assert_allclose(got_ts, want_t, rtol=1e-4, atol=1e-5)
 
@@ -148,7 +147,7 @@ def check_block_hostile_autotune():
     n, nv = 1024, 8
     a = random_fixed_nnz(n, 8, seed=7)
     part = contiguous_partition(n, topo.n_procs)
-    compiled = compile_nap(a, part, topo, cache=False)
+    compiled = compile_plan(a, part, topo, cache=False)
     assert compiled.chosen_local_compute in ("ell", "coo"), compiled.autotune
     assert all(e["choice"] != "bsr" for e in compiled.autotune["per_rank"])
 
@@ -161,7 +160,7 @@ def check_block_hostile_autotune():
     n_recv = compiled.recv_x_len
     assert n_recv != n_x
     for fmt in ("auto", "ell", "bsr"):
-        run = nap_forward_shardmap(compiled, mesh, local_compute=fmt)
+        run = forward_program(compiled, mesh, local_compute=fmt)
         got = unpack_vector(np.asarray(run(shards)), part, topo)
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
         # neither the zero-copy BSR kernel nor the composed ELL product
@@ -173,7 +172,7 @@ def check_block_hostile_autotune():
         assert n_recv_cat == (run.local_compute == "ell"), (fmt, n_recv_cat)
     # ...while the BSR materialize_x oracle path DOES (differential: proves
     # the scan actually sees the concat when it exists)
-    run_mat = nap_forward_shardmap(compiled, mesh, local_compute="bsr",
+    run_mat = forward_program(compiled, mesh, local_compute="bsr",
                                 materialize_x=True)
     assert _count_packed_x_concats(run_mat.run4, shards, n_x, nv) >= 1
     print(f"block-hostile autotune ok: chose {compiled.chosen_local_compute}, "

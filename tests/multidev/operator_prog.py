@@ -18,7 +18,7 @@ import numpy as np
 import repro.api as nap
 from repro.compat import make_mesh
 from repro.core.partition import make_partition
-from repro.core.spmv_jax import (compile_nap, nap_forward_shardmap,
+from repro.core.spmv_jax import (compile_plan, forward_program,
                                  pack_vector, unpack_vector)
 from repro.core.topology import Topology
 from repro.sparse import random_fixed_nnz
@@ -76,7 +76,7 @@ def check(topo_shape, kind, nv, seed):
 
 def check_operator_equals_builder_path():
     """The operator is plumbing, not math: its forward must equal the raw
-    compile_nap + nap_forward_shardmap + pack/unpack path bit-for-bit."""
+    compile_plan + forward_program + pack/unpack path bit-for-bit."""
     topo = Topology(n_nodes=2, ppn=4)
     mesh = make_mesh((2, 4), ("node", "proc"))
     n, nv = 256, 8
@@ -84,8 +84,8 @@ def check_operator_equals_builder_path():
     part = make_partition("contiguous", n, topo.n_procs)
     v = np.random.default_rng(11).standard_normal((n, nv))
 
-    compiled = compile_nap(a, part, topo)
-    run = nap_forward_shardmap(compiled, mesh)
+    compiled = compile_plan(a, part, topo)
+    run = forward_program(compiled, mesh)
     raw = unpack_vector(
         np.asarray(run(pack_vector(v, part, topo, compiled.rows_pad))),
         part, topo)

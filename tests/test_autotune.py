@@ -2,7 +2,7 @@
 
 Covers the cost-model chooser's three regimes (dense blocks -> bsr,
 flat low-density rows -> ell, skewed rows / too big for HBM -> coo), the
-stats + verdict compile_nap records on CompiledNAP, the packed ELL
+stats + verdict compile_plan records on CompiledPlan, the packed ELL
 emission's layout invariant, and the cache-key extensions that keep
 ``local_compute`` / tuner switches from returning stale plans.
 """
@@ -14,7 +14,7 @@ from repro.core.cost_model import (LOCAL_FORMATS, LocalComputeParams,
                                    ell_resident_bytes, local_format_times)
 from repro.core.partition import contiguous_partition, make_partition
 from repro.core.spmv import split_all_blocks
-from repro.core.spmv_jax import (clear_compile_cache, compile_nap)
+from repro.core.spmv_jax import clear_compile_cache, compile_plan
 from repro.core.topology import Topology
 from repro.sparse import CSR, ELL, random_fixed_nnz
 
@@ -88,7 +88,7 @@ def test_compile_records_autotune_verdict(nn, ppn):
     topo = Topology(n_nodes=nn, ppn=ppn)
     a = random_fixed_nnz(64, 5, seed=1)
     part = make_partition("contiguous", 64, topo.n_procs)
-    compiled = compile_nap(a, part, topo, block_shape=(8, 16), cache=False)
+    compiled = compile_plan(a, part, topo, block_shape=(8, 16), cache=False)
     at = compiled.autotune
     assert at["chosen"] in LOCAL_FORMATS
     assert set(at["times"]) == set(LOCAL_FORMATS)
@@ -110,7 +110,7 @@ def test_block_hostile_low_density_selects_non_bsr():
     for seed, nnz_row in ((0, 8), (1, 12), (2, 4)):
         a = random_fixed_nnz(2048, nnz_row, seed=seed)
         part = contiguous_partition(2048, topo.n_procs)
-        compiled = compile_nap(a, part, topo, cache=False)
+        compiled = compile_plan(a, part, topo, cache=False)
         assert compiled.chosen_local_compute in ("ell", "coo")
         assert all(e["choice"] in ("ell", "coo")
                    for e in compiled.autotune["per_rank"])
@@ -126,7 +126,7 @@ def test_dense_block_diagonal_selects_bsr():
     a = CSR.from_dense(dense)
     topo = Topology(n_nodes=2, ppn=2)
     part = contiguous_partition(n, topo.n_procs)
-    compiled = compile_nap(a, part, topo, block_shape=(8, 8), cache=False)
+    compiled = compile_plan(a, part, topo, block_shape=(8, 8), cache=False)
     assert compiled.chosen_local_compute == "bsr"
 
 
@@ -144,7 +144,7 @@ def test_packed_ell_layout_equals_local_blocks(nn, ppn):
     topo = Topology(n_nodes=nn, ppn=ppn)
     a = random_fixed_nnz(60, 6, seed=11)
     part = make_partition("contiguous", 60, topo.n_procs)
-    compiled = compile_nap(a, part, topo, block_shape=(8, 16), cache=False)
+    compiled = compile_plan(a, part, topo, block_shape=(8, 16), cache=False)
     compiled.ensure_ell()
     rows_pad, pads = compiled.rows_pad, compiled.pads
     remap = compiled.recv_domain_map()
@@ -176,7 +176,7 @@ def test_packed_segments_are_lane_aligned():
     a = random_fixed_nnz(50, 5, seed=2)      # 50 rows -> ragged per-rank counts
     part = make_partition("contiguous", 50, topo.n_procs)
     for bn in (8, 16, 128):
-        compiled = compile_nap(a, part, topo, block_shape=(8, bn), cache=False)
+        compiled = compile_plan(a, part, topo, block_shape=(8, bn), cache=False)
         assert compiled.rows_pad % bn == 0
         assert compiled.pads["bnode"] % bn == 0
         assert compiled.pads["boff"] % bn == 0
@@ -191,11 +191,11 @@ def test_cache_distinguishes_local_compute_and_tuner():
     topo = Topology(n_nodes=2, ppn=2)
     a = random_fixed_nnz(60, 6, seed=9)
     part = make_partition("contiguous", 60, topo.n_procs)
-    c_auto = compile_nap(a, part, topo)
-    assert compile_nap(a, part, topo) is c_auto
-    c_ell = compile_nap(a, part, topo, local_compute="ell")
+    c_auto = compile_plan(a, part, topo)
+    assert compile_plan(a, part, topo) is c_auto
+    c_ell = compile_plan(a, part, topo, local_compute="ell")
     assert c_ell is not c_auto
-    assert compile_nap(a, part, topo, local_compute="ell") is c_ell
+    assert compile_plan(a, part, topo, local_compute="ell") is c_ell
     # a compile-time format request is an override that "auto" executors
     # resolve to (explicit executor requests still win)
     assert c_ell.resolve_local_compute("auto") == "ell"
@@ -203,9 +203,9 @@ def test_cache_distinguishes_local_compute_and_tuner():
     assert c_auto.resolve_local_compute("auto") == c_auto.autotune["chosen"]
     # autotuner inputs (rate model) are part of the key too
     slow_scatter = LocalComputeParams(scatter_flops=1.0)
-    c_tuned = compile_nap(a, part, topo, tuner=slow_scatter)
+    c_tuned = compile_plan(a, part, topo, tuner=slow_scatter)
     assert c_tuned is not c_auto
     assert c_tuned.autotune["times"]["coo"] > c_auto.autotune["times"]["coo"]
     with pytest.raises(ValueError):
-        compile_nap(a, part, topo, local_compute="csr")
+        compile_plan(a, part, topo, local_compute="csr")
     clear_compile_cache()

@@ -19,12 +19,9 @@ import pytest
 
 import repro.api as nap
 from repro.core.partition import contiguous_partition
-from repro.core.spmv_jax import (_ensure_abft_recv, _fused_ell_arrays,
-                                 _split_width, compile_multistep, compile_nap,
-                                 compile_standard, padded_traffic)
+from repro.core.spmv_jax import compile_plan, padded_traffic
 from repro.core.topology import Topology
 from repro.sparse import CSR, random_fixed_nnz
-from repro.sparse.ell import ELL, stack_ell
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 COMMS = ("nap", "multistep", "standard")
@@ -69,16 +66,15 @@ def _plan(comm, local_compute="auto"):
     topo = Topology(2, 2)
     a = random_fixed_nnz(200, 9, seed=4)
     part = contiguous_partition(200, topo.n_procs)
-    build = {"nap": compile_nap, "multistep": compile_multistep,
-             "standard": compile_standard}[comm]
-    return build(a, part, topo, cache=False, local_compute=local_compute)
+    return compile_plan(a, part, topo, comm=comm, cache=False,
+                        local_compute=local_compute)
 
 
 @pytest.mark.parametrize("comm", COMMS)
 def test_buffer_gather_elems_counts_the_buffer_step(comm):
     c = _plan(comm)
     gathered = (c.pads["bnode"] + c.pads["boff"] if comm != "standard"
-                else c.buf_pad)
+                else c.pads["buf"])
     for fmt, want in (("ell", 0), ("bsr", gathered), ("coo", gathered)):
         assert padded_traffic(c, local_compute=fmt)["buffer_gather_elems"] \
             == want, fmt
@@ -102,16 +98,7 @@ def test_composed_ids_are_the_packed_ids_through_the_map(comm):
     ``recv_domain_map``; padding stays -1 and values keep their slots."""
     c = _plan(comm, local_compute="ell")
     c.ensure_ell()
-    split = _split_width(c.autotune)
-    if comm == "standard":
-        packed, vals, _, _ = stack_ell([
-            ELL.from_coo(rr, cc, vv, (c.rows_pad, c.n_x),
-                         n_rows_pad=c.rows_pad, split=split)
-            for rr, cc, vv in c.per_rank_coo])
-    else:
-        packed, vals, _, _ = _fused_ell_arrays(c.local_blocks, c.rows_pad,
-                                               c.cols_pad, c.pads["bnode"],
-                                               c.pads["boff"], split=split)
+    packed, vals, _, _ = c.packed_ell()
     composed, remap = c.arrays["ell_cols"], c.recv_domain_map()
     assert remap.shape == (c.topo.n_procs, c.packed_x_len)
     assert composed.max() < c.recv_x_len
@@ -125,7 +112,7 @@ def test_composed_ids_are_the_packed_ids_through_the_map(comm):
 @pytest.mark.parametrize("comm", COMMS)
 def test_swap_values_refreshes_the_received_domain_abft(comm):
     c = _plan(comm, local_compute="ell")
-    _ensure_abft_recv(c)
+    c.ensure_abft_recv()
     before = c.arrays["abft_col_recv"].copy()
     a = c.a_ref
     changed = c.swap_values(CSR(indptr=a.indptr, indices=a.indices,

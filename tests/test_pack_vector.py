@@ -66,7 +66,7 @@ def test_non_contiguous_partitions_roundtrip(kind):
 
 @pytest.mark.parametrize("bn", [8, 16, 128])
 def test_bn_aligned_padding_roundtrip(bn):
-    """rows_pad rounded up to the kernel lane width (what compile_nap
+    """rows_pad rounded up to the kernel lane width (what compile_plan
     does): padding slots never leak values and unpack still recovers v."""
     topo = Topology(n_nodes=2, ppn=2)
     n = 30

@@ -5,6 +5,8 @@ the block splitter's reconstruction invariant, the slot-map lookup tables,
 the compile cache, effective-vs-padded traffic accounting, the fused BSR
 layout, and the mailbox's duplicate-post guard.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -12,8 +14,8 @@ from repro.core.comm_graph import (build_nap_plan, flat_slot_map, lookup_slots,
                                    Message)
 from repro.core.partition import make_partition
 from repro.core.spmv import _MailBox, split_all_blocks
-from repro.core.spmv_jax import (CompiledNAP, clear_compile_cache, compile_nap,
-                                 padded_traffic)
+from repro.core.spmv_jax import (STRATEGIES, clear_compile_cache,
+                                 compile_plan, padded_traffic)
 from repro.core.topology import Topology
 from repro.sparse import random_fixed_nnz
 from repro.sparse.bsr import BSR
@@ -67,8 +69,11 @@ def test_flat_slot_map_rejects_duplicate_delivery():
 def test_recv_slot_map_matches_messages(nn, ppn):
     topo, a, part = problem(nn, ppn, seed=5)
     plan = build_nap_plan(a.indptr, a.indices, part, topo, pairing="aligned")
+    inter, = [p for p in STRATEGIES["nap"].schedule(plan, topo).phases
+              if p.name == "inter"]
+    inter = dataclasses.replace(inter, pad=100)
     for r in range(topo.n_procs):
-        idx, pos = plan.recv_slot_map(r, "inter", pad=100)
+        idx, pos = inter.recv_slot_map(topo, r)
         for m in plan.inter_recvs[r]:
             want = topo.node_of(m.src) * 100 + np.arange(m.size)
             got = lookup_slots((idx, pos), m.idx)
@@ -78,7 +83,7 @@ def test_recv_slot_map_matches_messages(nn, ppn):
 @pytest.mark.parametrize("nn,ppn", TOPOS)
 def test_padded_traffic_effective_le_padded(nn, ppn):
     topo, a, part = problem(nn, ppn, seed=7)
-    t = padded_traffic(compile_nap(a, part, topo, cache=False))
+    t = padded_traffic(compile_plan(a, part, topo, cache=False))
     for phase in ("inter", "full", "init", "final"):
         assert t[f"{phase}_effective"] <= t[f"{phase}_padded"], (phase, t)
     # effective inter bytes must equal the plan's true payload
@@ -90,15 +95,15 @@ def test_padded_traffic_effective_le_padded(nn, ppn):
 def test_compile_cache_hits_and_distinguishes():
     clear_compile_cache()
     topo, a, part = problem(2, 2, seed=9)
-    c1 = compile_nap(a, part, topo)
-    assert compile_nap(a, part, topo) is c1                     # pure cache hit
-    assert compile_nap(a, part, topo, block_shape=(8, 8)) is not c1
+    c1 = compile_plan(a, part, topo)
+    assert compile_plan(a, part, topo) is c1                     # pure cache hit
+    assert compile_plan(a, part, topo, block_shape=(8, 8)) is not c1
     a2 = random_fixed_nnz(60, 6, seed=10)                        # new structure
-    assert compile_nap(a2, part, topo) is not c1
+    assert compile_plan(a2, part, topo) is not c1
     a3 = random_fixed_nnz(60, 6, seed=9)
     a3.data = a3.data * 2.0                                      # same structure, new values
-    assert compile_nap(a3, part, topo) is not c1
-    assert compile_nap(a, part, topo, cache=False) is not c1
+    assert compile_plan(a3, part, topo) is not c1
+    assert compile_plan(a, part, topo, cache=False) is not c1
     clear_compile_cache()
 
 
@@ -107,15 +112,14 @@ def test_fused_bsr_layout_equals_local_blocks(nn, ppn):
     """The fused blocks, viewed densely per rank, reproduce the three
     column blocks at their layout offsets."""
     topo, a, part = problem(nn, ppn, seed=11)
-    compiled = compile_nap(a, part, topo, block_shape=(8, 16), cache=False)
+    compiled = compile_plan(a, part, topo, block_shape=(8, 16), cache=False)
     compiled.ensure_fused()
-    lay = compiled.bsr_layout
     bm, bn = compiled.block_shape
     blocks = split_all_blocks(a, part, topo)
     for r, blk in enumerate(blocks):
         cols = compiled.arrays["fused_cols"][r]
         data = compiled.arrays["fused_blocks"][r]
-        n_bcols = (lay["vblk"] + lay["nblk"] + lay["oblk"]) // bn
+        n_bcols = compiled.packed_x_len // bn
         dense = np.zeros((cols.shape[0] * bm, n_bcols * bn))
         for i in range(cols.shape[0]):
             for k in range(cols.shape[1]):
@@ -125,11 +129,11 @@ def test_fused_bsr_layout_equals_local_blocks(nn, ppn):
         nr = blk.rows.size
         np.testing.assert_allclose(
             dense[:nr, :nr], blk.on_proc.to_dense(), atol=1e-6)
-        o = lay["vblk"]
+        o = compiled.cols_pad
         np.testing.assert_allclose(
             dense[:nr, o:o + blk.on_node.shape[1]], blk.on_node.to_dense(),
             atol=1e-6)
-        o += lay["nblk"]
+        o += compiled.pads["bnode"]
         np.testing.assert_allclose(
             dense[:nr, o:o + blk.off_node.shape[1]], blk.off_node.to_dense(),
             atol=1e-6)

@@ -35,9 +35,8 @@ from repro.core.cost_model import (TPU_V5E_LOCAL, LocalComputeParams,
 from repro.core.partition import contiguous_partition
 import repro.core.spmv_jax as spmv_jax
 import repro.sparse.ell as ell_mod
-from repro.core.spmv_jax import (_compose_cols, _ell_layout,
-                                 _fused_ell_arrays, compile_nap,
-                                 nap_forward_shardmap, nap_transpose_shardmap)
+from repro.core.spmv_jax import (_compose_cols, _ell_layout, compile_plan,
+                                 forward_program, transpose_program)
 from repro.core.topology import Topology
 from repro.kernels.ell_spmv import ell_spmm_packed, ell_spmm_packed_ref
 from repro.sparse import (ELL, graph500_kronecker, pagerank_matrix,
@@ -249,8 +248,8 @@ CELL_PLANS = {
 def test_even_rows_keep_the_unsplit_layout_and_program(name):
     make, shape = CELL_PLANS[name]
     a, topo = make(), Topology(*shape)
-    c = compile_nap(a, contiguous_partition(a.shape[0], topo.n_procs), topo,
-                    cache=False)
+    c = compile_plan(a, contiguous_partition(a.shape[0], topo.n_procs), topo,
+                     cache=False)
     assert c.chosen_local_compute == "ell"
     at = c.autotune
     assert at["ell"]["ell_split_width"] == at["stats"]["ell_kmax"]
@@ -261,17 +260,15 @@ def test_even_rows_keep_the_unsplit_layout_and_program(name):
         assert at["ell"]["ell_split_width"] == at["stats"]["ell_kmax"]
         c.ensure_ell_t()
         assert "ell_t_chunk_row" not in c.arrays
-    cols, vals, kmax, chunk_row = _fused_ell_arrays(
-        c.local_blocks, c.rows_pad, c.cols_pad, c.pads["bnode"],
-        c.pads["boff"])
+    cols, vals, kmax, chunk_row = c.packed_ell()
     assert chunk_row is None and kmax == c.ell_kmax
     assert np.array_equal(_compose_cols(cols, c.recv_domain_map()),
                           c.arrays["ell_cols"])
     assert np.array_equal(vals, c.arrays["ell_vals"])
     if topo.n_procs == 1:
         from repro.compat import make_mesh
-        run = nap_forward_shardmap(c, make_mesh((1, 1), ("node", "proc")),
-                                   local_compute="ell")
+        run = forward_program(c, make_mesh((1, 1), ("node", "proc")),
+                              local_compute="ell")
         jaxpr = str(jax.make_jaxpr(run.jitted)(
             np.zeros((1, 1, c.cols_pad, 1), np.float32), *run.args()))
         # the slot loop (a fori_loop of static length) and no combine
@@ -337,9 +334,10 @@ def _parent_ell_product(ell_args, xs, n_rows):
 
 
 def _ell_program_jaxpr(c, direction):
+    """The jaxpr of ``c``'s ELL program in ``direction``, whichever
+    strategy's schedule ``c`` was compiled for."""
     from repro.compat import make_mesh
-    build = (nap_forward_shardmap if direction == "forward"
-             else nap_transpose_shardmap)
+    build = forward_program if direction == "forward" else transpose_program
     run = build(c, make_mesh((1, 1), ("node", "proc")), local_compute="ell")
     n_in = c.cols_pad if direction == "forward" else c.rows_pad
     return str(jax.make_jaxpr(run.jitted)(
@@ -357,14 +355,15 @@ UNPADDED_PLANS = {
 }
 
 
+@pytest.mark.parametrize("comm", ["nap", "multistep", "standard"])
 @pytest.mark.parametrize("name,direction", [
     ("stencil_56", "forward"), ("graph", "forward"), ("graph", "transpose"),
     ("random", "transpose")])
 def test_layouts_the_rule_adds_no_rows_to_keep_the_parent_program(
-        monkeypatch, name, direction):
+        monkeypatch, name, direction, comm):
     a = UNPADDED_PLANS[name]()
-    c = compile_nap(a, contiguous_partition(a.shape[0], 1), Topology(1, 1),
-                    cache=False)
+    c = compile_plan(a, contiguous_partition(a.shape[0], 1), Topology(1, 1),
+                     comm=comm, cache=False)
     at = c.autotune if direction == "forward" else c.autotune["transpose"]
     assert at["ell"]["ell_pad_rows"] == 0
     (c.ensure_ell if direction == "forward" else c.ensure_ell_t)()
